@@ -8,9 +8,9 @@ tile written back, XLA-side masking + top-k) on synthetic packed heads:
   * XLA bytes-accessed from HLO cost analysis, plus a structural check that
     the fused executable contains NO (B, K·V_BLK) f32 buffer
 
-Interpret-mode runnable (the default here — this container is CPU-only, so
-wall times measure the EMULATED kernels and only the bytes/buffer columns
-reflect the TPU story; pass --no-interpret on real TPUs for honest timing).
+The kernels run compiled on a TPU and interpreted elsewhere
+(repro.kernels.mode); off the chip the wall times measure the EMULATED
+kernels and only the bytes/buffer columns reflect the TPU story.
 
     PYTHONPATH=src python benchmarks/kernel_fused.py              # full
     PYTHONPATH=src python benchmarks/kernel_fused.py --reduced    # CI smoke
@@ -27,6 +27,7 @@ import numpy as np
 from repro.kernels.ops import (pack_head_blocks, screened_fused_topk_tpu,
                                screened_topk_tpu)
 from repro.launch.hlo_cost import materializes_f32_buffer, xla_bytes_accessed
+from repro.utils.compile_cache import enable_compile_cache
 
 try:
     from benchmarks.common import csv_row
@@ -48,7 +49,7 @@ def _time(fn, *args, reps: int, **kw) -> float:
     return float(np.median(times)) * 1e6            # µs
 
 
-def run(reduced: bool = False, interpret: bool = True):
+def run(reduced: bool = False):
     if reduced:
         cases = [(16, 8, 128, 5, 1500)]             # (B, K, d, k, L)
         reps = 3
@@ -71,7 +72,7 @@ def run(reduced: bool = False, interpret: bool = True):
                            jnp.int32)
         h = jnp.asarray(rng.standard_normal((B, d)), jnp.float32)
         args = (Wb, bb, v, cand, h)
-        kw = dict(k=k, interpret=interpret)
+        kw = dict(k=k)
 
         iu, vu = screened_topk_tpu(*args, **kw)
         if_, vf, _ = screened_fused_topk_tpu(*args, **kw)
@@ -101,10 +102,9 @@ def run(reduced: bool = False, interpret: bool = True):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--reduced", action="store_true",
                     help="one small case, few reps (CI smoke)")
-    ap.add_argument("--no-interpret", action="store_true",
-                    help="compile the Pallas kernels for the real backend")
     a = ap.parse_args()
-    run(reduced=a.reduced, interpret=not a.no_interpret)
+    run(reduced=a.reduced)

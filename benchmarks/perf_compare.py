@@ -9,6 +9,7 @@ import os
 import numpy as np
 
 from benchmarks.common import csv_row
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _load(f):
@@ -50,4 +51,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

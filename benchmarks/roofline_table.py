@@ -11,6 +11,7 @@ import numpy as np
 
 from benchmarks.common import csv_row
 from repro.configs import INPUT_SHAPES, get_config
+from repro.utils.compile_cache import enable_compile_cache
 
 FILES = {
     "16x16": "results/baselines_16x16.jsonl",
@@ -86,4 +87,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -8,6 +8,8 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     from benchmarks import (ablation_loss, perf_compare, roofline_table,
@@ -33,4 +35,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

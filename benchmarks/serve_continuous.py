@@ -45,6 +45,7 @@ from repro.serving import (BudgetAdmission, CircuitBreaker,
                            StreamWatchdog, TierPolicy, Tracer,
                            audit_cost_drift)
 from repro.serving.scheduler import TIER_DEADLINES, AdmissionRejected
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _export_trace(tracer, path, label):
@@ -469,4 +470,5 @@ def _shared_prefix(args, cfg, corpus, engine, n_req, rate):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

@@ -44,6 +44,7 @@ from repro.models import build_model
 from repro.optim import adamw_init
 from repro.serving import DecodeEngine, ServeRequest, TierPolicy
 from repro.utils.timing import LatencyTracker
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def build_engine(reduced: bool, seed: int):
@@ -173,4 +174,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

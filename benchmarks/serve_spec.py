@@ -47,6 +47,7 @@ except ImportError:                      # script's own dir is sys.path[0]
 
 from repro.serving import (ContinuousScheduler, ServeRequest, ServeResult,
                            SpecPolicy, StaticPolicy)
+from repro.utils.compile_cache import enable_compile_cache
 
 DRAFTS = ("screened", "screened-pallas", "adaptive")
 
@@ -162,4 +163,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

@@ -25,6 +25,7 @@ from repro.core import fit_l2s, precision_at_k
 from repro.core.evaluate import (avg_candidate_size, exact_topk,
                                  full_softmax_topk_numpy)
 from repro.core.train_l2s import kmeans_only_screen
+from repro.utils.compile_cache import enable_compile_cache
 
 N_EVAL = 2048
 N_TIME = 400
@@ -116,4 +117,5 @@ def run(k: int = 5):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -14,6 +14,7 @@ from repro.configs import L2SConfig
 from repro.core import fit_l2s
 from repro.data import ZipfMarkovCorpus
 from repro.serving import DecodeEngine
+from repro.utils.compile_cache import enable_compile_cache
 
 N_PROMPTS = 12
 PROMPT_LEN = 12
@@ -52,4 +53,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -10,6 +10,7 @@ from repro.configs import L2SConfig
 from repro.core import fit_l2s, precision_at_k
 from repro.core.evaluate import (PerQueryScreen, avg_candidate_size,
                                  exact_topk)
+from repro.utils.compile_cache import enable_compile_cache
 import time
 
 
@@ -38,4 +39,5 @@ def run(k: int = 5):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

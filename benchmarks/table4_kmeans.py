@@ -11,6 +11,7 @@ from repro.core import fit_l2s, precision_at_k
 from repro.core.evaluate import (PerQueryScreen, avg_candidate_size,
                                  exact_topk)
 from repro.core.train_l2s import kmeans_only_screen
+from repro.utils.compile_cache import enable_compile_cache
 import time
 
 
@@ -43,4 +44,5 @@ def run(k: int = 5):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

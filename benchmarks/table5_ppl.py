@@ -12,6 +12,7 @@ from benchmarks.common import csv_row, get_artifacts
 from repro.configs import L2SConfig
 from repro.core import fit_l2s
 from repro.core.lowrank import (build_lowrank, exact_perplexity, perplexity)
+from repro.utils.compile_cache import enable_compile_cache
 
 RANK = 32          # paper uses 20 for PTB-Small; 32 here — the
                    # synthetic corpus has fatter tails (see notes)
@@ -59,4 +60,5 @@ def _empty_screen(screen):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

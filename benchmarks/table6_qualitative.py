@@ -11,6 +11,7 @@ from repro.configs import L2SConfig
 from repro.core import fit_l2s
 from repro.data import ZipfMarkovCorpus
 from repro.serving import DecodeEngine
+from repro.utils.compile_cache import enable_compile_cache
 
 N_SAMPLES = 6
 MAX_NEW = 16
@@ -44,4 +45,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

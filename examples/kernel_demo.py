@@ -1,7 +1,7 @@
 """Pallas kernel demo: the TPU-adapted screened softmax hot path
 (cluster_route → scalar-prefetch block gather-matmul → subset top-k) behind
 the ``SoftmaxHead`` protocol, validated against the pure-jnp reference head
-in interpret mode.
+(kernels compiled on a TPU, interpreted elsewhere).
 
 Run: PYTHONPATH=src python examples/kernel_demo.py
 """
@@ -27,7 +27,7 @@ sp = ScreenParams(v=v, cand_idx=cand,
                   block=128)
 
 # one registry, two backends over the same screen
-kern = heads.get("screened-pallas", W=W, b=b, screen=sp)   # interpret on CPU
+kern = heads.get("screened-pallas", W=W, b=b, screen=sp)   # compiled on TPU
 ref = heads.get("screened", W=W, b=b, screen=sp)           # pure jnp
 print(f"packed head: {kern.packed_shape} — {kern.packed_nbytes/1e6:.0f} MB "
       "in MXU vocab blocks (prepare() ran once)")

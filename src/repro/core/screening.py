@@ -87,8 +87,15 @@ def candidates_to_padded(mask: np.ndarray, vocab_size: int, block: int = 1,
 
 
 def assign_clusters(v: jnp.ndarray, h: jnp.ndarray) -> jnp.ndarray:
-    """z(h) = argmax_t v_t·h. h: (..., d) → (...,) int32. Paper Eq.(2)."""
-    scores = jnp.einsum("...d,rd->...r", h, v)
+    """z(h) = argmax_t v_t·h. h: (..., d) → (...,) int32. Paper Eq.(2).
+
+    Scored at full float32 precision: the argmax is a discrete choice, and
+    the TPU's default (bfloat16-operand) matmul flips it between clusters
+    whose scores are that close — so heads that route here and the route
+    kernel (kernels/route.py) would send the same h to different candidate
+    sets. r·d is small next to the candidate matmul it selects."""
+    scores = jnp.einsum("...d,rd->...r", h, v,
+                        precision=jax.lax.Precision.HIGHEST)
     return jnp.argmax(scores, axis=-1).astype(jnp.int32)
 
 

@@ -43,11 +43,12 @@ def collect_contexts(model, params, token_batches, max_vectors: int = 200_000,
 
     y_i = exact softmax top-k at each position (paper Algorithm 1 line 2).
     """
-    W, b = model.softmax_weights(params)
-
+    # params enter as an argument: closed over, the whole model would be
+    # baked into the executable as constants
     @jax.jit
-    def fwd(tokens):
+    def fwd(params, tokens):
         h, _ = model.forward(params, {"tokens": tokens})
+        W, b = model.softmax_weights(params)
         logits = jnp.einsum("btd,vd->btv", h, W) + b
         _, top = jax.lax.top_k(logits, k)
         return h, top
@@ -55,7 +56,7 @@ def collect_contexts(model, params, token_batches, max_vectors: int = 200_000,
     Hs, ys = [], []
     n = 0
     for tokens in token_batches:
-        h, top = fwd(tokens)
+        h, top = fwd(params, tokens)
         d = h.shape[-1]
         Hs.append(np.asarray(h.reshape(-1, d), np.float32))
         ys.append(np.asarray(top.reshape(-1, k), np.int32))

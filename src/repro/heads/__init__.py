@@ -52,26 +52,24 @@ register("exact-sharded",
          ExactShardedHead(W, b, mesh=mesh, n_shards=n_shards))
 register("screened", lambda W, b, screen, **_: ScreenedHead(W, b, screen))
 register("screened-sharded",
-         lambda W, b, screen, mesh=None, n_shards=None, local="jnp",
-         interpret=True, **_:
+         lambda W, b, screen, mesh=None, n_shards=None, local="jnp", **_:
          ScreenedShardedHead(W, b, screen, mesh=mesh, n_shards=n_shards,
-                             local=local, interpret=interpret))
+                             local=local))
 register("screened-pallas",
-         lambda W, b, screen, interpret=True, fused=True, **_:
-         ScreenedPallasHead(W, b, screen, interpret=interpret, fused=fused))
+         lambda W, b, screen, fused=True, **_:
+         ScreenedPallasHead(W, b, screen, fused=fused))
 register("screened-cpu",
          lambda W, b, screen, **_: ScreenedNumpyHead(W, b, screen))
 register("adaptive",
-         lambda W, b, counts=None, shortlist=None, n_tails=4,
-         interpret=True, fused=True, **_:
+         lambda W, b, counts=None, shortlist=None, n_tails=4, fused=True,
+         **_:
          AdaptiveHead(W, b, counts=counts, shortlist=shortlist,
-                      n_tails=n_tails, interpret=interpret, fused=fused))
+                      n_tails=n_tails, fused=fused))
 register("adaptive-sharded",
          lambda W, b, counts=None, shortlist=None, n_tails=4, mesh=None,
-         n_shards=None, interpret=True, **_:
+         n_shards=None, **_:
          AdaptiveShardedHead(W, b, counts=counts, shortlist=shortlist,
-                             n_tails=n_tails, mesh=mesh, n_shards=n_shards,
-                             interpret=interpret))
+                             n_tails=n_tails, mesh=mesh, n_shards=n_shards))
 register("svd", lambda W, b, rho=16, n_top=None, **_:
          SVDHead(W, b, rho=rho, n_top=n_top))
 register("shortlist",

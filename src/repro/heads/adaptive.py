@@ -44,7 +44,6 @@ from types import SimpleNamespace
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.heads.base import (NEG_INF, SoftmaxHead, sample_from_logits,
@@ -180,7 +179,7 @@ def _masked_lse(logits):
 # -- shared tier bodies (plain/traceable; jitted entries below and in the
 #    shard_map closures — composition stays flat, kernels/ops.py idiom) ------
 
-def _short_topk_body(Wb, bb, gid, short_blocks, h, k, L, interpret):
+def _short_topk_body(Wb, bb, gid, short_blocks, h, k, L):
     """Fused short-list tier: kernel over the short blocks, packed rows →
     vocab ids, pad to k. Works on the full packed tiles (unsharded) or the
     replicated short slice (sharded) — the kernel sentinel is
@@ -189,8 +188,7 @@ def _short_topk_body(Wb, bb, gid, short_blocks, h, k, L, interpret):
     nb0 = short_blocks.shape[0]
     ks = min(k, nb0 * V_BLK)
     sb = jnp.broadcast_to(short_blocks[None, :], (B, nb0))
-    srows, svals, logz = fused_screened_topk(Wb, bb, h, sb, k=ks,
-                                             interpret=interpret)
+    srows, svals, logz = fused_screened_topk(Wb, bb, h, sb, k=ks)
     return gid[srows], svals, logz
 
 
@@ -218,18 +216,18 @@ def _descend_mask(gate, svals, ks, k):
     return jnp.max(gate, axis=-1) >= svals[:, -1]
 
 
-@partial(jax.jit, static_argnames=("k", "L", "interpret"))
-def _fused_short_topk(Wb, bb, gid, short_blocks, h, *, k, L, interpret):
+@partial(jax.jit, static_argnames=("k", "L"))
+def _fused_short_topk(Wb, bb, gid, short_blocks, h, *, k, L):
     """No-tails (shortlist = L) fused path: the short tier IS the head."""
     sgids, svals, logz = _short_topk_body(Wb, bb, gid, short_blocks, h,
-                                          k, L, interpret)
+                                          k, L)
     ids, vals = merge_shard_topk(svals, sgids, k, sentinel=L)
     return ids, vals, logz
 
 
-@partial(jax.jit, static_argnames=("k", "L", "interpret"))
+@partial(jax.jit, static_argnames=("k", "L"))
 def _fused_tiered_topk(Wb, bb, gid, short_blocks, tail_tab, g, gb, h, *,
-                       k, L, interpret):
+                       k, L):
     """Fused two-tier top-k: short-list kernel for every query, tail kernel
     with the non-descending rows' block ids MASKED TO THE SENTINEL — those
     rows ride the kernel's proven all-sentinel path (NEG_INF vals, sentinel
@@ -241,23 +239,21 @@ def _fused_tiered_topk(Wb, bb, gid, short_blocks, tail_tab, g, gb, h, *,
     n_blk = Wb.shape[0]
     ks = min(k, nb0 * V_BLK)
     sgids, svals, slogz = _short_topk_body(Wb, bb, gid, short_blocks, h,
-                                           k, L, interpret)
+                                           k, L)
     gate = _gate(g, gb, h)
     cluster = jnp.argmax(gate, axis=-1)
     descend = _descend_mask(gate, svals, ks, k)
     tb = jnp.where(descend[:, None], tail_tab[cluster], n_blk)
     kt = min(k, tail_tab.shape[-1] * V_BLK)
-    trows, tvals, tlogz = fused_screened_topk(Wb, bb, h, tb, k=kt,
-                                              interpret=interpret)
+    trows, tvals, tlogz = fused_screened_topk(Wb, bb, h, tb, k=kt)
     ids, vals = merge_shard_topk(
         jnp.concatenate([svals, tvals], axis=-1),
         jnp.concatenate([sgids, gid[trows]], axis=-1), k, sentinel=L)
     return ids, vals, combine_tier_logz(slogz, tlogz)
 
 
-@partial(jax.jit, static_argnames=("k", "L", "interpret"))
-def _unfused_short_topk(Wb, bb, gid, short_blocks, h, *, k, L,
-                        interpret=True):
+@partial(jax.jit, static_argnames=("k", "L"))
+def _unfused_short_topk(Wb, bb, gid, short_blocks, h, *, k, L):
     slog, sids = _short_row_body(Wb, bb, gid, short_blocks, h)
     ks = min(k, slog.shape[-1])
     svals, pos = jax.lax.top_k(slog, ks)
@@ -290,9 +286,9 @@ def _tail_row_body(Wb, bb, gid, tail_tab, cluster, descend):
     return logits, gid[rows].reshape(B, -1)
 
 
-@partial(jax.jit, static_argnames=("k", "L", "interpret"))
+@partial(jax.jit, static_argnames=("k", "L"))
 def _unfused_tiered_topk(Wb, bb, gid, short_blocks, tail_tab, g, gb, h, *,
-                         k, L, interpret=True):
+                         k, L):
     """jnp escape hatch for the two-tier path — identical ids/vals to the
     fused kernel (same flattened-position tie order), identical empty-row
     convention (NEG_INF, never NaN) via ``_masked_lse``."""
@@ -344,7 +340,7 @@ class AdaptiveHead(SoftmaxHead):
     name = "adaptive"
 
     def __init__(self, W, b, counts=None, shortlist=None, n_tails: int = 4,
-                 interpret: bool = True, fused: bool = True):
+                 fused: bool = True):
         if n_tails < 1:
             raise ValueError(f"n_tails must be >= 1, got {n_tails}")
         self.W = jnp.asarray(W)
@@ -352,7 +348,6 @@ class AdaptiveHead(SoftmaxHead):
         self.counts = None if counts is None else np.asarray(counts)
         self.shortlist = shortlist
         self.n_tails = int(n_tails)
-        self.interpret = bool(interpret)
         self.fused = bool(fused)
         self._Wb = None
 
@@ -378,11 +373,10 @@ class AdaptiveHead(SoftmaxHead):
         if self._tail_tab is None:
             fn = _fused_short_topk if self.fused else _unfused_short_topk
             return fn(self._Wb, self._bb, self._gid, self._short_blocks, h,
-                      k=k, L=self.L, interpret=self.interpret)
+                      k=k, L=self.L)
         fn = _fused_tiered_topk if self.fused else _unfused_tiered_topk
         return fn(self._Wb, self._bb, self._gid, self._short_blocks,
-                  self._tail_tab, self._g, self._gb, h, k=k, L=self.L,
-                  interpret=self.interpret)
+                  self._tail_tab, self._g, self._gb, h, k=k, L=self.L)
 
     def topk(self, h, k: int):
         ids, vals, _ = self._run(h, k)
@@ -446,7 +440,7 @@ class AdaptiveHead(SoftmaxHead):
 # -- adaptive-sharded --------------------------------------------------------
 
 @lru_cache(maxsize=None)
-def _sharded_short_impl(mesh, L: int, interpret: bool):
+def _sharded_short_impl(mesh, L: int):
     """shard_map closures for the degenerate no-tails geometry: the
     replicated short tier is the whole head, every shard computes it
     locally (no collective) — kept inside shard_map so the Pallas call
@@ -455,13 +449,13 @@ def _sharded_short_impl(mesh, L: int, interpret: bool):
 
     def run_body(Wb, bb, gid_s, short_blocks, h, k):
         sgids, svals, logz = _short_topk_body(Wb, bb, gid_s, short_blocks,
-                                              h, k, L, interpret)
+                                              h, k, L)
         ids, vals = merge_shard_topk(svals, sgids, k, sentinel=L)
         return ids, vals, logz
 
     def smap(body, outs):
-        return shard_map(body, mesh=mesh, in_specs=(r3, r2, r1, r1, r2),
-                         out_specs=outs, check_rep=False)
+        return jax.shard_map(body, mesh=mesh, in_specs=(r3, r2, r1, r1, r2),
+                             out_specs=outs, check_vma=False)
 
     @partial(jax.jit, static_argnames="k")
     def run(Wb, bb, gid_s, short_blocks, h, k):
@@ -477,7 +471,7 @@ def _sharded_short_impl(mesh, L: int, interpret: bool):
 
 
 @lru_cache(maxsize=None)
-def _adaptive_sharded_impl(mesh, L: int, Ls_t: int, interpret: bool):
+def _adaptive_sharded_impl(mesh, L: int, Ls_t: int):
     """shard_map closures for one (mesh, vocab, tail-shard-width) geometry —
     cached at module level so instances sharing a mesh share compilations.
 
@@ -498,7 +492,7 @@ def _adaptive_sharded_impl(mesh, L: int, Ls_t: int, interpret: bool):
         nb0 = short_blocks.shape[0]
         ks = min(k, nb0 * V_BLK)
         sgids, svals, slogz = _short_topk_body(Wb, bb, gid_s, short_blocks,
-                                               h, k, L, interpret)
+                                               h, k, L)
         gate = _gate(g, gb, h)
         cluster = jnp.argmax(gate, axis=-1)
         descend = _descend_mask(gate, svals, ks, k)
@@ -507,7 +501,7 @@ def _adaptive_sharded_impl(mesh, L: int, Ls_t: int, interpret: bool):
         kt = min(k, tb.shape[-1] * V_BLK)
         lrows, tvals, tlz = fused_screened_topk(
             Wt.reshape(nbs, V_BLK, d), bt.reshape(nbs, V_BLK), h, tb,
-            k=kt, interpret=interpret)
+            k=kt)
         offset = jax.lax.axis_index("model") * Ls_t
         safe = jnp.where(lrows < Ls_t, lrows + offset, 0)
         tg = jnp.where(lrows < Ls_t, gid_t[safe], L)
@@ -545,10 +539,10 @@ def _adaptive_sharded_impl(mesh, L: int, Ls_t: int, interpret: bool):
                 jnp.concatenate([sids, tg], axis=-1))
 
     def smap(body, outs):
-        return shard_map(body, mesh=mesh,
-                         in_specs=(r3, r2, r1, r1, wspec, bspec, cspec, r1,
-                                   r2, r1, r2),
-                         out_specs=outs, check_rep=False)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(r3, r2, r1, r1, wspec, bspec, cspec, r1,
+                                       r2, r1, r2),
+                             out_specs=outs, check_vma=False)
 
     @partial(jax.jit, static_argnames="k")
     def run(Wb, bb, gid_s, short_blocks, Wt, bt, btab, gid_t, g, gb, h, k):
@@ -572,7 +566,7 @@ class AdaptiveShardedHead(SoftmaxHead):
     name = "adaptive-sharded"
 
     def __init__(self, W, b, counts=None, shortlist=None, n_tails: int = 4,
-                 mesh=None, n_shards: int = None, interpret: bool = True):
+                 mesh=None, n_shards: int = None):
         if n_tails < 1:
             raise ValueError(f"n_tails must be >= 1, got {n_tails}")
         self._W0 = np.asarray(W, np.float32)
@@ -581,7 +575,6 @@ class AdaptiveShardedHead(SoftmaxHead):
         self.counts = None if counts is None else np.asarray(counts)
         self.shortlist = shortlist
         self.n_tails = int(n_tails)
-        self.interpret = bool(interpret)
         self._mesh_arg, self._n_shards_arg = mesh, n_shards
         self.mesh = None
 
@@ -611,7 +604,7 @@ class AdaptiveShardedHead(SoftmaxHead):
         if lay.C == 0:
             self.Wp = self.bp = self.cand_blocks = None
             self._g = self._gb = self._gid_t = None
-            self._fns = _sharded_short_impl(mesh, L, self.interpret)
+            self._fns = _sharded_short_impl(mesh, L)
             self._W0 = self._b0 = None
             return self
 
@@ -648,7 +641,7 @@ class AdaptiveShardedHead(SoftmaxHead):
         self._g = jax.device_put(jnp.asarray(lay.g), repl)
         self._gb = jax.device_put(jnp.asarray(lay.gb), repl)
         self.Ls_t = Ls_t
-        self._fns = _adaptive_sharded_impl(mesh, L, Ls_t, self.interpret)
+        self._fns = _adaptive_sharded_impl(mesh, L, Ls_t)
         self._W0 = self._b0 = None      # only the placed copies stay resident
         return self
 

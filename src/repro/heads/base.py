@@ -28,6 +28,7 @@ Metadata drives engine behavior and benchmark reporting:
 """
 from __future__ import annotations
 
+import copy
 from typing import Tuple
 
 import jax
@@ -130,6 +131,24 @@ class SoftmaxHead:
         """One-time packing / table builds. Idempotent."""
         return self
 
+    def device_state(self) -> dict:
+        """The head's device arrays by attribute name: every attribute that
+        is a jax Array or a pytree of them (a ScreenParams). The serving
+        engine passes this dict into its compiled steps as an argument, so
+        the weights are never baked into an executable as constants."""
+        def on_device(v):
+            leaves = jax.tree_util.tree_leaves(v)
+            return bool(leaves) and all(isinstance(x, jax.Array)
+                                        for x in leaves)
+        return {k: v for k, v in vars(self).items() if on_device(v)}
+
+    def with_state(self, state: dict) -> "SoftmaxHead":
+        """A shallow copy of this head over ``state`` (the traced
+        ``device_state()`` inside a compiled step); the head is unchanged."""
+        head = copy.copy(self)
+        head.__dict__.update(state)
+        return head
+
     # -- core queries -------------------------------------------------------
     def topk(self, h, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
         raise NotImplementedError
@@ -220,7 +239,7 @@ class SoftmaxHead:
         bands, ...) that the arrays alone don't."""
         parts = [self.name, type(self)]
         for attr in ("W", "b", "Wp", "bp", "_Wb", "_bb", "screen", "mesh",
-                     "interpret", "impl", "fused", "local"):
+                     "impl", "fused", "local"):
             v = getattr(self, attr, None)
             if v is not None:
                 parts.append(v if isinstance(v, (str, int, float, bool))

@@ -22,8 +22,8 @@ tiles. ``prepare()`` does the one-time MXU packing of (W, b) into
 (n_blk, V_BLK, d) tiles; rows past the vocab are padded with −inf bias so
 they can never win top-k.
 
-``interpret=True`` (default) runs the kernels in interpret mode — this
-container is CPU-only; pass False on real TPUs."""
+The kernels run compiled on a TPU backend and interpreted elsewhere; the
+choice is made once, in repro.kernels.mode, never by the head."""
 from __future__ import annotations
 
 import jax
@@ -40,8 +40,7 @@ class ScreenedPallasHead(SoftmaxHead):
     name = "screened-pallas"
     supports_dist = True
 
-    def __init__(self, W, b, screen: ScreenParams, interpret: bool = True,
-                 fused: bool = True):
+    def __init__(self, W, b, screen: ScreenParams, fused: bool = True):
         require_screen(screen, "ScreenedPallasHead")
         assert screen.block == V_BLK, (
             f"Pallas head needs a {V_BLK}-word block-candidate screen "
@@ -50,7 +49,6 @@ class ScreenedPallasHead(SoftmaxHead):
         self.W = jnp.asarray(W)
         self.b = jnp.asarray(b)
         self.screen = screen
-        self.interpret = interpret
         self.fused = fused
         self._Wb = None
         self._bb = None
@@ -76,15 +74,14 @@ class ScreenedPallasHead(SoftmaxHead):
         from repro.kernels.ops import screened_candidate_logits_tpu
         self.prepare()
         return screened_candidate_logits_tpu(
-            self._Wb, self._bb, self.screen.v, self.screen.cand_idx, h,
-            interpret=self.interpret)
+            self._Wb, self._bb, self.screen.v, self.screen.cand_idx, h)
 
     def _fused_topk(self, h, k: int):
         from repro.kernels.ops import screened_fused_topk_tpu
         self.prepare()
         return screened_fused_topk_tpu(
             self._Wb, self._bb, self.screen.v, self.screen.cand_idx, h,
-            k=k, interpret=self.interpret)
+            k=k)
 
     def topk(self, h, k: int):
         if self.fused:
@@ -93,8 +90,7 @@ class ScreenedPallasHead(SoftmaxHead):
         from repro.kernels.ops import screened_topk_tpu
         self.prepare()
         ids, vals = screened_topk_tpu(self._Wb, self._bb, self.screen.v,
-                                      self.screen.cand_idx, h, k=k,
-                                      interpret=self.interpret)
+                                      self.screen.cand_idx, h, k=k)
         return ids.astype(jnp.int32), vals
 
     def topk_logprobs(self, h, k: int):
@@ -129,8 +125,7 @@ class ScreenedPallasHead(SoftmaxHead):
             self.prepare()
             return screened_fused_sample_tpu(
                 self._Wb, self._bb, self.screen.v, self.screen.cand_idx, h,
-                key, temperature=temperature,
-                interpret=self.interpret).astype(jnp.int32)
+                key, temperature=temperature).astype(jnp.int32)
         # nucleus sampling (and fused=False) needs the full candidate
         # distribution — unfused gather path
         logits, word_ids = self._candidate_logits(h)

@@ -36,7 +36,6 @@ from types import SimpleNamespace
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.screening import ScreenParams, assign_clusters
@@ -159,8 +158,8 @@ def _exact_impl(mesh, L: int):
 
     def smap(body, n_out=2):
         outs = tuple([rspec] * n_out) if n_out > 1 else rspec
-        return shard_map(body, mesh=mesh, in_specs=(wspec, bspec, rspec),
-                         out_specs=outs, check_rep=False)
+        return jax.shard_map(body, mesh=mesh, in_specs=(wspec, bspec, rspec),
+                             out_specs=outs, check_vma=False)
 
     @partial(jax.jit, static_argnames="k")
     def topk(W, b, h, k):
@@ -301,9 +300,9 @@ def _screened_impl(mesh, L: int):
                 jax.lax.all_gather(gids, "model", axis=1, tiled=True))
 
     def smap(body):
-        return shard_map(body, mesh=mesh,
-                         in_specs=(wspec, bspec, rspec, cspec, rspec),
-                         out_specs=(rspec, rspec), check_rep=False)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(wspec, bspec, rspec, cspec, rspec),
+                             out_specs=(rspec, rspec), check_vma=False)
 
     @partial(jax.jit, static_argnames="k")
     def topk(W, b, v, cand, h, k):
@@ -322,7 +321,7 @@ def _screened_impl(mesh, L: int):
 
 
 @lru_cache(maxsize=None)
-def _screened_pallas_impl(mesh, L: int, Ls: int, interpret: bool):
+def _screened_pallas_impl(mesh, L: int, Ls: int):
     """Jitted shard_map closures for the FUSED-Pallas local candidate path
     (``local="pallas"``): each shard reshapes its (Ls, d) weight rows into
     MXU tiles — zero-copy, Ls is a V_BLK multiple by construction — and
@@ -344,7 +343,7 @@ def _screened_pallas_impl(mesh, L: int, Ls: int, interpret: bool):
         kk = min(k, block_ids.shape[-1] * V_BLK)
         lids, vals, logz = fused_screened_topk(
             W.reshape(nb, V_BLK, d), b.reshape(nb, V_BLK), h, block_ids,
-            k=kk, interpret=interpret)
+            k=kk)
         offset = jax.lax.axis_index("model") * Ls
         gids = jnp.where(lids < Ls, lids + offset, L)    # kernel sentinel = Ls
         return vals, gids, logz
@@ -366,9 +365,9 @@ def _screened_pallas_impl(mesh, L: int, Ls: int, interpret: bool):
         return mids, lp
 
     def smap(body):
-        return shard_map(body, mesh=mesh,
-                         in_specs=(wspec, bspec, rspec, cspec, rspec),
-                         out_specs=(rspec, rspec), check_rep=False)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(wspec, bspec, rspec, cspec, rspec),
+                             out_specs=(rspec, rspec), check_vma=False)
 
     @partial(jax.jit, static_argnames="k")
     def topk(W, b, v, candb, h, k):
@@ -397,8 +396,7 @@ class ScreenedShardedHead(SoftmaxHead):
     name = "screened-sharded"
 
     def __init__(self, W, b, screen: ScreenParams, mesh=None,
-                 n_shards: int = None, local: str = "jnp",
-                 interpret: bool = True):
+                 n_shards: int = None, local: str = "jnp"):
         require_screen(screen, "ScreenedShardedHead")
         if local not in ("jnp", "pallas"):
             raise ValueError(f"local must be 'jnp' or 'pallas', got {local!r}")
@@ -412,7 +410,6 @@ class ScreenedShardedHead(SoftmaxHead):
         self._shape = self._W0.shape
         self.screen = screen
         self.local = local
-        self.interpret = interpret
         self._mesh_arg, self._n_shards_arg = mesh, n_shards
         self.mesh = None
 
@@ -486,8 +483,7 @@ class ScreenedShardedHead(SoftmaxHead):
                     btab[s, t, :len(loc)] = loc
             self.cand_blocks = jax.device_put(jnp.asarray(btab), sh["cand"])
             self.kb_shard_max = kb
-            self._pallas_fns = _screened_pallas_impl(mesh, L, Ls,
-                                                     self.interpret)
+            self._pallas_fns = _screened_pallas_impl(mesh, L, Ls)
         return self
 
     def topk(self, h, k: int):

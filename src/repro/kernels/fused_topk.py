@@ -23,8 +23,10 @@ back-to-back and VMEM scratch carries state across them:
                                convention over the unfused (B, K·V_BLK) row,
                                so ids AND vals are bit-identical to the
                                unfused path
-  lse scratch      (2,) SMEM   running (max, sum-exp) for the §4.2 log-Z,
-                               online-softmax style
+  max/sum scratch  (1, V_BLK)  running (max, sum-exp) for the §4.2 log-Z,
+                               online-softmax style, every lane holding the
+                               same value (the TPU keeps vectors, not
+                               scalars, in VMEM)
 
 Each slot program DMAs its (V_BLK, d) weight tile (scalar-prefetch gather,
 same as kernels/screen.py), computes the V_BLK tile logits on the MXU,
@@ -40,6 +42,11 @@ Sampling rides the same reduction: with ``noise`` (temperature-scaled
 Gumbel, (B, K, V_BLK)) the perturbed top-1 IS a categorical draw over the
 candidate softmax (the Gumbel-max trick), so sampling also never
 materializes the logit tile.
+
+TPU tiling follows kernels/screen.py: single rows (h, bias, noise, and the
+per-row outputs) carry a unit axis of their own so each block's trailing
+pair spans its array's. Outputs are lane-dense — ids/vals (B, 1, k_pad) and
+logZ (B, 1, V_BLK) broadcast across lanes — and the wrapper slices them.
 """
 from __future__ import annotations
 
@@ -51,61 +58,61 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import interpret_mode
 from repro.kernels.ref import NEG_INF
-from repro.kernels.screen import V_BLK
+from repro.kernels.screen import V_BLK, tile_logits
 
 
-def _merge_topk(vals, ids, k: int):
-    """Top-k of a (1, C) pool by (value desc, position asc).
+def _merge_topk(vals, ids, k: int, width: int, fill_id: int):
+    """Top-k of a (1, C) pool by (value desc, position asc), written into
+    lanes 0..k-1 of a (1, width) row padded with (−inf, ``fill_id``).
 
     Selection by iterated first-position argmax reproduces
     ``jax.lax.top_k``'s lowest-index tie-break as long as the pool is laid
     out in flattened-position order — which the caller guarantees by
     concatenating [running list (earlier positions), new tile (lane
-    order)]. Returns ((1, k) vals, (1, k) ids)."""
+    order)]. Results are placed by lane select, not sliced stores, so every
+    store stays lane-dense."""
     C = vals.shape[-1]
     pos = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
-    out_v, out_i = [], []
-    for _ in range(k):
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    out_v = jnp.full((1, width), -jnp.inf, jnp.float32)
+    out_i = jnp.full((1, width), fill_id, jnp.int32)
+    for t in range(k):
         m = jnp.max(vals, axis=-1, keepdims=True)               # (1, 1)
         first = jnp.min(jnp.where(vals == m, pos, C), axis=-1,
                         keepdims=True)                          # first max
         take = pos == first
-        out_v.append(m)
-        out_i.append(jnp.sum(jnp.where(take, ids, 0), axis=-1,
-                             keepdims=True))
+        pick = jnp.sum(jnp.where(take, ids, 0), axis=-1, keepdims=True)
+        out_v = jnp.where(slot == t, m, out_v)
+        out_i = jnp.where(slot == t, pick, out_i)
         vals = jnp.where(take, -jnp.inf, vals)
-    return jnp.concatenate(out_v, -1), jnp.concatenate(out_i, -1)
+    return out_v, out_i
 
 
 def _fused_topk_kernel(ids_ref, w_ref, h_ref, b_ref, *rest,
-                       k: int, k_pad: int, n_blk: int, v_blk: int,
-                       with_noise: bool):
+                       k: int, n_blk: int, v_blk: int, with_noise: bool):
     if with_noise:
         (noise_ref, vals_out, ids_out, logz_out,
-         vals_scr, ids_scr, lse_scr) = rest
+         vals_scr, ids_scr, m_scr, s_scr) = rest
     else:
         noise_ref = None
-        vals_out, ids_out, logz_out, vals_scr, ids_scr, lse_scr = rest
+        (vals_out, ids_out, logz_out,
+         vals_scr, ids_scr, m_scr, s_scr) = rest
     i, j = pl.program_id(0), pl.program_id(1)
     sentinel = n_blk * v_blk
 
     @pl.when(j == 0)
     def _init():
-        vals_scr[...] = jnp.full((1, k_pad), -jnp.inf, jnp.float32)
-        ids_scr[...] = jnp.full((1, k_pad), sentinel, jnp.int32)
-        lse_scr[0] = -jnp.inf
-        lse_scr[1] = 0.0
+        vals_scr[...] = jnp.full(vals_scr.shape, -jnp.inf, jnp.float32)
+        ids_scr[...] = jnp.full(ids_scr.shape, sentinel, jnp.int32)
+        m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
+        s_scr[...] = jnp.zeros(s_scr.shape, jnp.float32)
 
     blk = ids_ref[i, j]
     valid = blk < n_blk
-    acc = jax.lax.dot_general(
-        w_ref[0], h_ref[0][:, None],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]                                                     # (V_BLK,)
-    tile = (acc + b_ref[0].astype(jnp.float32))[None, :]        # (1, V_BLK)
-    tile = jnp.where(valid, tile, NEG_INF)
+    tile = jnp.where(valid, tile_logits(w_ref, h_ref, b_ref),
+                     NEG_INF)                                   # (1, V_BLK)
     lane = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
     tile_ids = jnp.where(valid, blk * v_blk + lane, sentinel)
 
@@ -113,36 +120,37 @@ def _fused_topk_kernel(ids_ref, w_ref, h_ref, b_ref, *rest,
     # Padded vocab rows carry exactly NEG_INF bias, so exp underflows to 0.
     @pl.when(valid)
     def _lse():
-        m_old, s_old = lse_scr[0], lse_scr[1]
-        m_new = jnp.maximum(m_old, jnp.max(tile))
-        lse_scr[0] = m_new
-        lse_scr[1] = (s_old * jnp.exp(m_old - m_new) +
-                      jnp.sum(jnp.exp(tile - m_new)))
+        m_old = m_scr[...]
+        m_new = jnp.maximum(m_old, jnp.max(tile, axis=-1, keepdims=True))
+        m_scr[...] = m_new
+        s_scr[...] = (s_scr[...] * jnp.exp(m_old - m_new) +
+                      jnp.sum(jnp.exp(tile - m_new), axis=-1, keepdims=True))
 
     if with_noise:
         # Gumbel-max sampling: perturb AFTER the LSE so logZ stays exact;
         # sentinel slots keep NEG_INF (never drawn vs any real candidate)
-        tile = jnp.where(valid, tile + noise_ref[0, 0][None, :], NEG_INF)
+        tile = jnp.where(valid, tile + noise_ref[0, 0], NEG_INF)
 
     # running top-k merge: scratch first (earlier flattened positions win
     # ties), tile second — scratch lanes past k hold −inf and never win
     pool_v = jnp.concatenate([vals_scr[...], tile], axis=-1)
     pool_i = jnp.concatenate([ids_scr[...], tile_ids], axis=-1)
-    new_v, new_i = _merge_topk(pool_v, pool_i, k)
-    vals_scr[0, :k] = new_v[0]
-    ids_scr[0, :k] = new_i[0]
+    new_v, new_i = _merge_topk(pool_v, pool_i, k, vals_scr.shape[-1],
+                               sentinel)
+    vals_scr[...] = new_v
+    ids_scr[...] = new_i
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _emit():
-        vals_out[0, :] = vals_scr[0, :k]
-        ids_out[0, :] = ids_scr[0, :k]
-        logz_out[0, 0] = lse_scr[0] + jnp.log(lse_scr[1])
+        vals_out[0] = vals_scr[...]
+        ids_out[0] = ids_scr[...]
+        logz_out[0] = m_scr[...] + jnp.log(s_scr[...])
 
 
 def fused_screened_topk(W_blocks: jnp.ndarray, b_blocks: jnp.ndarray,
                         h: jnp.ndarray, block_ids: jnp.ndarray, k: int,
                         noise: Optional[jnp.ndarray] = None,
-                        interpret: bool = True
+                        interpret: Optional[bool] = None
                         ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """In-VMEM screened softmax reduction (plain/traceable; jitted entry
     points live in kernels/ops.py).
@@ -159,46 +167,45 @@ def fused_screened_topk(W_blocks: jnp.ndarray, b_blocks: jnp.ndarray,
     k_pad = -(-k // v_blk) * v_blk
     block_ids = block_ids.astype(jnp.int32)
 
-    def w_idx(i, j, ids):
+    def tile_idx(i, j, ids):
         return (jnp.where(ids[i, j] < n_blk, ids[i, j], 0), 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, v_blk, d), w_idx),                 # gathered W tile
-        pl.BlockSpec((1, d), lambda i, j, ids: (i, 0)),     # h row
-        pl.BlockSpec((1, v_blk),                            # bias tile
-                     lambda i, j, ids: (jnp.where(ids[i, j] < n_blk,
-                                                  ids[i, j], 0), 0)),
+        pl.BlockSpec((1, v_blk, d), tile_idx),                  # W tile
+        pl.BlockSpec((1, 1, d), lambda i, j, ids: (i, 0, 0)),   # h row
+        pl.BlockSpec((1, 1, v_blk), tile_idx),                  # bias tile
     ]
-    inputs = [block_ids, W_blocks, h, b_blocks]
+    inputs = [block_ids, W_blocks, h.reshape(B, 1, d),
+              b_blocks.reshape(n_blk, 1, v_blk)]
     if noise is not None:
-        in_specs.append(pl.BlockSpec((1, 1, v_blk),
-                                     lambda i, j, ids: (i, j, 0)))
-        inputs.append(noise.astype(jnp.float32))
+        in_specs.append(pl.BlockSpec((1, 1, 1, v_blk),
+                                     lambda i, j, ids: (i, j, 0, 0)))
+        inputs.append(noise.astype(jnp.float32).reshape(B, K, 1, v_blk))
+
+    def row_out(width):
+        return pl.BlockSpec((1, 1, width), lambda i, j, ids: (i, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, K),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, k), lambda i, j, ids: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j, ids: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, ids: (i, 0)),
-        ],
+        out_specs=[row_out(k_pad), row_out(k_pad), row_out(v_blk)],
         scratch_shapes=[
             pltpu.VMEM((1, k_pad), jnp.float32),
             pltpu.VMEM((1, k_pad), jnp.int32),
-            pltpu.SMEM((2,), jnp.float32),
+            pltpu.VMEM((1, v_blk), jnp.float32),
+            pltpu.VMEM((1, v_blk), jnp.float32),
         ],
     )
     vals, ids, logz = pl.pallas_call(
-        functools.partial(_fused_topk_kernel, k=k, k_pad=k_pad, n_blk=n_blk,
-                          v_blk=v_blk, with_noise=noise is not None),
+        functools.partial(_fused_topk_kernel, k=k, n_blk=n_blk, v_blk=v_blk,
+                          with_noise=noise is not None),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, k), jnp.float32),
-            jax.ShapeDtypeStruct((B, k), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, k_pad), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, k_pad), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, v_blk), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(*inputs)
-    return ids, vals, logz[:, 0]
+    return ids[:, 0, :k], vals[:, 0, :k], logz[:, 0, 0]

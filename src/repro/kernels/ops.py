@@ -20,14 +20,15 @@ Composition is flat: the inner pieces (``cluster_route``,
 functions; only the public entry points here (and the standalone
 per-kernel wrappers they re-export) are jitted — no jit-inside-jit.
 
-``interpret`` defaults to True (this container is CPU-only; on TPU pass
-False). The wrappers handle all padding/masking so callers see the same
+``interpret`` defaults to None: the kernels run compiled on a TPU backend
+and interpreted elsewhere (repro.kernels.mode); an explicit bool is a test
+override. The wrappers handle all padding/masking so callers see the same
 contract as the pure-jnp reference path in repro.core.screening.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,14 +50,16 @@ def pack_head_blocks(W: jnp.ndarray, b: jnp.ndarray, v_blk: int = V_BLK):
     return Wp.reshape(n_blk, v_blk, d), bp.reshape(n_blk, v_blk)
 
 
-def _route_block_ids(v, cand_blocks, h, interpret: bool) -> jnp.ndarray:
+def _route_block_ids(v, cand_blocks, h,
+                     interpret: Optional[bool]) -> jnp.ndarray:
     """Kernelized routing → per-row candidate block ids (B, K)."""
     cluster = cluster_route(h, v, interpret=interpret)               # (B,)
     return cand_blocks[cluster]
 
 
 def _candidate_logits(W_blocks, b_blocks, v, cand_blocks, h,
-                      interpret: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                      interpret: Optional[bool]
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Plain body of ``screened_candidate_logits_tpu``."""
     n_blk, v_blk, d = W_blocks.shape
     block_ids = _route_block_ids(v, cand_blocks, h, interpret)       # (B, K)
@@ -73,7 +76,7 @@ def _candidate_logits(W_blocks, b_blocks, v, cand_blocks, h,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def screened_candidate_logits_tpu(W_blocks, b_blocks, v, cand_blocks, h,
-                                  interpret: bool = True
+                                  interpret: Optional[bool] = None
                                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Kernelized route + gather-matmul over the routed candidate blocks.
 
@@ -89,7 +92,8 @@ def screened_candidate_logits_tpu(W_blocks, b_blocks, v, cand_blocks, h,
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def screened_topk_tpu(W_blocks, b_blocks, v, cand_blocks, h, k: int = 5,
-                      interpret: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                      interpret: Optional[bool] = None
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Unfused kernelized L2S prediction: candidate logits → top-k.
 
     Same inputs as ``screened_candidate_logits_tpu``;
@@ -104,7 +108,7 @@ def screened_topk_tpu(W_blocks, b_blocks, v, cand_blocks, h, k: int = 5,
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def screened_fused_topk_tpu(W_blocks, b_blocks, v, cand_blocks, h,
-                            k: int = 5, interpret: bool = True
+                            k: int = 5, interpret: Optional[bool] = None
                             ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fully fused L2S prediction: route → in-VMEM subset softmax + top-k.
 
@@ -121,7 +125,8 @@ def screened_fused_topk_tpu(W_blocks, b_blocks, v, cand_blocks, h,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def screened_fused_sample_tpu(W_blocks, b_blocks, v, cand_blocks, h, key,
                               temperature: float = 1.0,
-                              interpret: bool = True) -> jnp.ndarray:
+                              interpret: Optional[bool] = None
+                              ) -> jnp.ndarray:
     """Fused categorical draw from the candidate softmax (Gumbel-max).
 
     argmax(logits/T + G) ≡ argmax(logits + T·G) for T > 0, so the fused
@@ -141,7 +146,7 @@ def screened_fused_sample_tpu(W_blocks, b_blocks, v, cand_blocks, h, key,
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def tier_fused_topk_tpu(W_blocks, b_blocks, h, block_ids, k: int = 5,
-                        interpret: bool = True
+                        interpret: Optional[bool] = None
                         ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Per-TIER fused entry for the adaptive softmax heads
     (repro.heads.adaptive): the same in-VMEM subset reduction as

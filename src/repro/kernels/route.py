@@ -11,10 +11,13 @@ argmax in VREGs. r is padded to a lane multiple (128) with −inf columns.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.mode import interpret_mode
 
 NEG_INF = -1e30
 B_TILE = 128
@@ -24,17 +27,22 @@ LANE = 128
 def _route_kernel(h_ref, vt_ref, out_ref, *, r_true: int):
     h = h_ref[...]                      # (B_TILE, d)
     vt = vt_ref[...]                    # (d, r_pad)
+    # full f32: the argmax must match core.screening.assign_clusters
     scores = jax.lax.dot_general(
         h, vt, dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)          # (B_TILE, r_pad)
     r_pad = scores.shape[-1]
     col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     scores = jnp.where(col < r_true, scores, NEG_INF)
-    out_ref[...] = jnp.argmax(scores, axis=-1).astype(jnp.int32)
+    # argmax with jnp.argmax's tie-break (lowest index), spelled out so the
+    # compiled kernel cannot break ties between equal clusters differently
+    best = jnp.max(scores, axis=-1, keepdims=True)
+    out_ref[...] = jnp.min(jnp.where(scores == best, col, r_pad), axis=-1)
 
 
 def cluster_route(h: jnp.ndarray, v: jnp.ndarray,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: Optional[bool] = None) -> jnp.ndarray:
     """h (B, d); v (r, d) → (B,) int32 cluster ids.
 
     Plain/traceable — compose inside an outer jit (kernels/ops.py does);
@@ -55,7 +63,7 @@ def cluster_route(h: jnp.ndarray, v: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((B_TILE,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((b_pad,), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(hp, vt)
     return out[:B]
 

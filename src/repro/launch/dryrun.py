@@ -39,6 +39,9 @@ from repro.launch.steps import (abstract_cache, abstract_opt_state,
 from repro.models.model import build_model
 from repro.configs.base import TrainConfig
 
+# the chip the production meshes are made of (a key of mesh.CHIP_PEAKS)
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 # long_500k on pure full-attention dense archs runs the documented
 # sliding-window DECODE VARIANT (DESIGN §5) — ring-buffer cache of this size.
 SWA_VARIANT_WINDOW = 4096
@@ -155,7 +158,7 @@ def lower_combo(cfg: ModelConfig, shape: ShapeConfig, mesh, head: str = "full",
     except Exception as e:  # CPU backend may not implement it
         rec["memory"] = {"error": str(e)[:120]}
     try:
-        rl = roofline_from_compiled(compiled)
+        rl = roofline_from_compiled(compiled, TARGET_DEVICE_KIND)
         rec["roofline"] = rl.as_dict()
     except Exception as e:
         rec["roofline"] = {"error": str(e)[:120]}

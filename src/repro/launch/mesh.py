@@ -9,10 +9,23 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-# TPU v5e hardware constants used by the roofline analysis (per chip)
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # bytes/s
-ICI_BW = 50e9                 # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+# interconnect — 50 GB/s on each of a chip's four ICI links.
+CHIP_PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``. A device with no published
+    entry is an error: a roofline against another chip's peaks is wrong."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r} "
+                       f"(known: {sorted(CHIP_PEAKS)})") from None
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,9 +1,10 @@
 """Roofline analysis from compiled dry-run artifacts (no real hardware).
 
-Three terms per (arch × shape × mesh), in seconds:
-  compute    = HLO_FLOPs_per_device / PEAK_FLOPS_BF16
-  memory     = HLO_bytes_per_device / HBM_BW
-  collective = collective_bytes_per_device / ICI_BW
+Three terms per (arch × shape × mesh), in seconds, against the published
+peaks of the target chip (``repro.launch.mesh.chip_peaks(device_kind)``):
+  compute    = HLO_FLOPs_per_device / flops_bf16
+  memory     = HLO_bytes_per_device / hbm_bw
+  collective = collective_bytes_per_device / ici_bw
 
 FLOPs/bytes/collective-bytes come from the while-aware HLO cost model
 (repro.launch.hlo_cost) over ``compiled.as_text()`` — the partitioned,
@@ -21,7 +22,7 @@ from dataclasses import dataclass, field
 from typing import Dict
 
 from repro.launch.hlo_cost import analyze_hlo
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import chip_peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
@@ -75,19 +76,23 @@ class Roofline:
     flops: float                 # per-device HLO FLOPs
     bytes_accessed: float        # per-device HLO bytes
     collective_bytes: float      # per-device collective result bytes
+    device_kind: str             # target chip, a key of mesh.CHIP_PEAKS
     collectives: dict = field(default_factory=dict)
+
+    def __post_init__(self):
+        self.peaks = chip_peaks(self.device_kind)
 
     @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS_BF16
+        return self.flops / self.peaks["flops_bf16"]
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_accessed / HBM_BW
+        return self.bytes_accessed / self.peaks["hbm_bw"]
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / ICI_BW
+        return self.collective_bytes / self.peaks["ici_bw"]
 
     @property
     def dominant(self) -> str:
@@ -101,6 +106,7 @@ class Roofline:
 
     def as_dict(self) -> dict:
         return {
+            "device_kind": self.device_kind,
             "flops_per_dev": self.flops,
             "bytes_per_dev": self.bytes_accessed,
             "collective_bytes_per_dev": self.collective_bytes,
@@ -112,12 +118,13 @@ class Roofline:
         }
 
 
-def roofline_from_compiled(compiled, lowered_text: str | None = None) -> Roofline:
+def roofline_from_compiled(compiled, device_kind: str,
+                           lowered_text: str | None = None) -> Roofline:
     text = lowered_text if lowered_text is not None else compiled.as_text()
     cost = analyze_hlo(text)
     return Roofline(flops=cost.flops, bytes_accessed=cost.bytes_accessed,
                     collective_bytes=cost.collective_bytes,
-                    collectives=cost.collectives)
+                    device_kind=device_kind, collectives=cost.collectives)
 
 
 def model_flops_per_token(n_active_params: int) -> float:

@@ -14,12 +14,20 @@ latency).
 
 A fast head that needs a screen (``--head screened`` without ``--l2s``)
 fails BEFORE training with exit code 2 and the fix-it message — the
-screening factories raise a typed ``MissingScreenError``.
+screening factories raise a typed ``MissingScreenError``. A head named by
+``--head`` or ``--draft-head`` that cannot be built after the screen is fit
+is also exit code 2: it is never quietly replaced by ``exact``. The screen
+is fit at the block its head needs — ``V_BLK`` (128) words when a requested
+head runs the Pallas kernels, single words otherwise.
+
+``prepare`` (train + fit + engine) and ``serve_scheduler`` are the pieces
+``chip_smoke.py`` drives; ``main`` is the command line around them.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -30,14 +38,36 @@ from repro.configs import L2SConfig, get_config
 from repro.core import collect_contexts, fit_l2s
 from repro.data import ZipfMarkovCorpus, make_lm_batches
 from repro.heads import MissingScreenError
+from repro.kernels.screen import V_BLK
 from repro.launch.steps import make_train_step
 from repro.models import build_model
 from repro.optim import adamw_init
 from repro.serving import DecodeEngine, ServeRequest, StaticPolicy
 from repro.configs import TrainConfig
+from repro.utils.compile_cache import enable_compile_cache
+
+MAX_SLOTS = 4           # decode width of every scheduler stream
+
+# heads that run the Pallas block kernels and so need a V_BLK-word screen
+_BLOCK_HEADS = ("screened-pallas",)
 
 
-def main(argv=None):
+def screen_block(*head_names) -> int:
+    """Vocab block the screen is fit at for the requested heads."""
+    return V_BLK if any(n in _BLOCK_HEADS for n in head_names) else 1
+
+
+def screen_budget(block: int, budget=None) -> int:
+    """Average candidate words per cluster: ``budget`` when given, else 100
+    words for a word screen and 8 blocks for a block screen. A budget under
+    one block leaves most clusters without a candidate block, and a query
+    routed to such a cluster has no word to emit."""
+    if budget is not None:
+        return budget
+    return 100 if block == 1 else 8 * block
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="ptb-small-lstm")
     ap.add_argument("--reduced", action="store_true")
@@ -67,9 +97,15 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=32)
     ap.add_argument("--clusters", type=int, default=50)
-    ap.add_argument("--budget", type=int, default=100)
+    ap.add_argument("--budget", type=int, default=None,
+                    help="L2S average candidate words per cluster "
+                         "(default: 100, or 8 blocks for a block screen)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -135,6 +171,45 @@ def main(argv=None):
               "come from the ContinuousScheduler's tick loop")
         return 2
 
+    enable_compile_cache()
+    setup = prepare(args, cfg, model, params, head_name)
+    engine, requests = setup.engine, setup.requests
+    # a head asked for by name is served or refused — never swapped for exact
+    for name in dict.fromkeys(filter(None, (head_name, args.draft_head))):
+        if name == "exact":
+            continue
+        try:
+            engine.resolve_head(name)
+        except (MissingScreenError, AssertionError, ValueError) as e:
+            print(f"[serve] cannot build head {name!r}: {e}")
+            return 2
+
+    if args.scheduler:
+        serve_scheduler(engine, requests, head_name,
+                        draft=args.draft_head, log_jsonl=args.log_jsonl)
+        return 0
+
+    t0 = time.time()
+    exact = engine.serve_batch(requests, policy=StaticPolicy("exact"))
+    t_exact = time.time() - t0
+    print(f"[serve] exact decode: {args.requests}×{args.max_new} tokens "
+          f"in {t_exact:.2f}s")
+    if head_name is not None and head_name != "exact":
+        t0 = time.time()
+        fast = engine.serve_batch(requests, policy=StaticPolicy(head_name))
+        t_fast = time.time() - t0
+        agree = float(np.mean([
+            (f.tokens == e.tokens).mean() for f, e in zip(fast, exact)]))
+        print(f"[serve] {head_name} decode:  {t_fast:.2f}s  "
+              f"token agreement {agree:.3f}")
+    return 0
+
+
+def prepare(args, cfg, model, params, head_name=None) -> SimpleNamespace:
+    """Train ``model`` for ``args.train_steps`` from ``params``, fit the L2S
+    screen when ``args.l2s`` (at the block ``head_name`` and
+    ``args.draft_head`` need), and build the engine and the request set.
+    → namespace(params, screen, engine, requests)."""
     corpus = ZipfMarkovCorpus(cfg.vocab_size, branching=min(64, cfg.vocab_size // 4),
                               seed=args.seed)
 
@@ -154,12 +229,14 @@ def main(argv=None):
         batches = [jnp.asarray(b["tokens"])
                    for b in make_lm_batches(corpus, 16, 16, 64, seed=7)]
         H, y = collect_contexts(model, params, batches, max_vectors=15_000)
+        block = screen_block(head_name, args.draft_head)
+        budget = screen_budget(block, args.budget)
         state = fit_l2s(H, y, cfg.vocab_size,
-                        L2SConfig(num_clusters=args.clusters,
-                                  budget=args.budget, outer_iters=2,
-                                  sgd_steps=100))
+                        L2SConfig(num_clusters=args.clusters, budget=budget,
+                                  outer_iters=2, sgd_steps=100,
+                                  vocab_block=block))
         screen = state.screen
-        print(f"[serve] L2S fitted: r={args.clusters} "
+        print(f"[serve] L2S fitted: r={args.clusters} budget={budget} "
               f"C_max={screen.c_max} block={screen.block}")
 
     # spec decode can transiently write draft_len − 1 rejected positions
@@ -171,32 +248,8 @@ def main(argv=None):
     prompts = corpus.sample_batch(args.requests, args.prompt_len, seed=42)
     requests = [ServeRequest(prompt=p, max_new=args.max_new)
                 for p in prompts]
-
-    if args.scheduler:
-        return _serve_scheduler(engine, requests, head_name,
-                                draft=args.draft_head,
-                                log_jsonl=args.log_jsonl)
-
-    t0 = time.time()
-    exact = engine.serve_batch(requests, policy=StaticPolicy("exact"))
-    t_exact = time.time() - t0
-    print(f"[serve] exact decode: {args.requests}×{args.max_new} tokens "
-          f"in {t_exact:.2f}s")
-    if head_name is not None and head_name != "exact":
-        try:
-            engine.resolve_head(head_name)
-        except MissingScreenError as e:       # safety net — probed above
-            print(f"[serve] cannot build head {head_name!r}: {e} "
-                  f"(pass --l2s to fit one)")
-            return 2
-        t0 = time.time()
-        fast = engine.serve_batch(requests, policy=StaticPolicy(head_name))
-        t_fast = time.time() - t0
-        agree = float(np.mean([
-            (f.tokens == e.tokens).mean() for f, e in zip(fast, exact)]))
-        print(f"[serve] {head_name} decode:  {t_fast:.2f}s  "
-              f"token agreement {agree:.3f}")
-    return 0
+    return SimpleNamespace(params=params, screen=screen, engine=engine,
+                           requests=requests)
 
 
 def _tick_delta(prev: dict, cur: dict) -> dict:
@@ -217,20 +270,22 @@ def _tick_delta(prev: dict, cur: dict) -> dict:
     return out
 
 
-def _serve_scheduler(engine, requests, head_name, draft=None,
-                     log_jsonl=None):
+def serve_scheduler(engine, requests, head_name, draft=None,
+                    log_jsonl=None):
     """--scheduler mode: continuous batching with admission control.
 
     Traffic is the launcher's request set re-tiered round-robin
-    (realtime / standard / batch); the fast head (when available) serves
-    the realtime tier, "exact" everything else. The flops budget is sized
+    (realtime / standard / batch); the fast head serves the realtime
+    tier, "exact" everything else. The flops budget is sized
     to the catalog so a burst sheds load through the typed reject path.
     Families the paged KV pool supports additionally serve over a
     ``PagePool`` (shared-prefix radix cache + COW pages) and report pool
     utilization in the log. With ``draft`` set (--draft-head) every
     request carries it explicitly and exact-routed traffic decodes
     speculatively on ``SpecDecodeStream`` lanes — same tokens, fewer
-    exact-head weight streams."""
+    exact-head weight streams. The caller has already resolved ``head_name``
+    and ``draft`` (``main`` exits 2 on a head it cannot build). Returns the
+    drained ``ContinuousScheduler`` (``.stats``, ``.results()``)."""
     import dataclasses
 
     from repro.serving import (BudgetAdmission, ContinuousScheduler,
@@ -239,12 +294,6 @@ def _serve_scheduler(engine, requests, head_name, draft=None,
     fast = head_name if head_name not in (None, "exact") else None
     candidates = tuple(dict.fromkeys(filter(None, (fast, draft, "exact"))))
     catalog = engine.head_catalog(candidates)
-    if fast is not None and fast not in catalog:
-        fast = None                      # unbuildable in this engine
-    if draft is not None and draft not in catalog:
-        print(f"[serve] draft head {draft!r} is not buildable in this "
-              f"engine (no fitted screen?) — serving plain")
-        draft = None
     policy = TierPolicy({"realtime": fast or "exact"}, default="exact")
     budget = 4.0 * max(m["flops_per_query"] for m in catalog.values())
     tiers = ["realtime", "standard", "batch"]
@@ -263,7 +312,8 @@ def _serve_scheduler(engine, requests, head_name, draft=None,
                            page_size=page)
     sched = ContinuousScheduler(engine, policy=policy,
                                 admission=BudgetAdmission(flops_budget=budget),
-                                max_slots=4, kv_pool=kv_pool, spec=spec)
+                                max_slots=MAX_SLOTS, kv_pool=kv_pool,
+                                spec=spec)
     t0 = time.time()
     if log_jsonl is None:
         results = sched.serve(traffic)
@@ -326,7 +376,7 @@ def _serve_scheduler(engine, requests, head_name, draft=None,
               f"prefix hit rate {p['prefix']['hit_rate']:.3f} | "
               f"cow {p['cow_copies']} ({p['cow_copies_per_tick']:.2f}/tick) "
               f"| hbm resident {p['hbm_resident_bytes']} B")
-    return 0
+    return sched
 
 
 if __name__ == "__main__":

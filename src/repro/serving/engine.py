@@ -78,7 +78,7 @@ class DecodeEngine:
         """``head``: default decode head — a registry name or an instance.
         ``screen``: L2S screen handed to screening heads resolved by name.
         ``head_kwargs``: extra construction kwargs for name resolution
-        (e.g. ``{"interpret": False}`` on real TPUs, ``{"rho": 32}``)."""
+        (e.g. ``{"rho": 32}``, ``{"local": "pallas"}``)."""
         self.model = model
         self.params = params
         self.screen = screen
@@ -125,23 +125,53 @@ class DecodeEngine:
         return head.prepare()
 
     # -- per-head jitted steps (built once, cached) --------------------------
-    def _mesh_aware_jit(self, head: SoftmaxHead, step, n_placed: int):
-        """jit a composed decode step for a vocab-SHARDED head: the head's
-        weights live across ``head.mesh``, so the step's other inputs (params,
-        token, cache — the first ``n_placed`` positional args) must join that
-        device set. ``in_shardings`` pins them replicated over the mesh, and
-        the wrapper device_puts each call so committed single-device arrays
-        (e.g. the prefill cache) reshard instead of erroring; once outputs
-        come back mesh-placed, the device_put is a no-op. The jitted callable
-        is built ONCE here and cached like every other step — no per-step
-        re-jitting."""
-        from jax.sharding import NamedSharding, PartitionSpec
-        repl = NamedSharding(head.mesh, PartitionSpec())
-        jitted = jax.jit(step, in_shardings=repl)
+    def _head_jit(self, heads: Sequence[SoftmaxHead], step, n_placed: int):
+        """jit a composed step ``step(*heads, *args)`` → ``fn(*args)``.
+
+        The heads' arrays (``device_state()``) enter the executable as
+        arguments: a head closed over by the step would have its weights
+        baked into every compiled step as constants — a second copy of W in
+        device memory per step, the full W on every device of a sharded
+        head's mesh, and executables too large for a compile cache.
+
+        A vocab-SHARDED head (``head.mesh`` set) keeps its arrays where they
+        live, and the step's other inputs (params, token, cache — the first
+        ``n_placed`` args) join the mesh: ``in_shardings`` pins them
+        replicated, and ``fn`` device_puts each call so committed
+        single-device arrays (e.g. the prefill cache) reshard instead of
+        erroring; once outputs come back mesh-placed, the device_put is a
+        no-op. The jitted callable is built ONCE here and cached like every
+        other step — no per-step re-jitting. ``fn.lower(*args)`` lowers it."""
+        heads = tuple(h.prepare() for h in heads)
+        state = tuple(h.device_state() for h in heads)
+
+        def traced(state, args):
+            return step(*(h.with_state(s) for h, s in zip(heads, state)),
+                        *args)
+
+        mesh = next((h.mesh for h in heads if h.mesh is not None), None)
+        if mesh is None:
+            jitted = jax.jit(traced)
+
+            def place(args):
+                return args
+        else:
+            from jax.sharding import NamedSharding, PartitionSpec
+            repl = NamedSharding(mesh, PartitionSpec())
+            # arrays off the mesh (a host-built screen) are replicated once
+            state = jax.tree.map(
+                lambda a: a if a.sharding.device_set == repl.device_set
+                else jax.device_put(a, repl), state)
+            jitted = jax.jit(traced, in_shardings=(
+                jax.tree.map(lambda a: a.sharding, state), repl))
+
+            def place(args):
+                return (*jax.device_put(args[:n_placed], repl),
+                        *args[n_placed:])
 
         def fn(*args):
-            placed = jax.device_put(args[:n_placed], repl)
-            return jitted(*placed, *args[n_placed:])
+            return jitted(state, place(args))
+        fn.lower = lambda *args: jitted.lower(state, place(args))
         fn._inner_jit = jitted
         return fn
 
@@ -149,13 +179,10 @@ class DecodeEngine:
         key = (head.step_key(), "greedy")
         if key not in self._step_cache:
             if head.is_jittable:
-                def step(params, tok, cache, pos):
+                def step(hd, params, tok, cache, pos):
                     h, cache = self.model.decode_step(params, tok, cache, pos)
-                    return head.next(h), h, cache
-                if head.mesh is not None:
-                    fn = self._mesh_aware_jit(head, step, n_placed=3)
-                else:
-                    fn = jax.jit(step)
+                    return hd.next(h), h, cache
+                fn = self._head_jit((head,), step, n_placed=3)
             else:
                 def fn(params, tok, cache, pos):
                     h, cache = self._jit_decode(params, tok, cache, pos)
@@ -197,13 +224,10 @@ class DecodeEngine:
             self._step_cache.move_to_end(key)       # LRU hit → most recent
         if key not in self._step_cache:
             if head.is_jittable:
-                def step(params, rkey, tok, cache, pos):
+                def step(hd, params, rkey, tok, cache, pos):
                     h, cache = self.model.decode_step(params, tok, cache, pos)
-                    return head.sample(rkey, h, temperature, top_p), h, cache
-                if head.mesh is not None:
-                    fn = self._mesh_aware_jit(head, step, n_placed=4)
-                else:
-                    fn = jax.jit(step)
+                    return hd.sample(rkey, h, temperature, top_p), h, cache
+                fn = self._head_jit((head,), step, n_placed=4)
             else:
                 def fn(params, rkey, tok, cache, pos):
                     h, cache = self._jit_decode(params, tok, cache, pos)
@@ -229,14 +253,11 @@ class DecodeEngine:
         key = (head.step_key(), "spec-verify", int(n_max))
         if key not in self._step_cache:
             if head.is_jittable:
-                def step(*hs):
+                def step(hd, *hs):
                     H = jnp.concatenate(hs, axis=0)        # (n_max·W, d)
-                    return head.next(H).reshape(len(hs), hs[0].shape[0])
-                if head.mesh is not None:
-                    # exact-SHARDED verify: every hidden joins the mesh
-                    fn = self._mesh_aware_jit(head, step, n_placed=n_max)
-                else:
-                    fn = jax.jit(step)
+                    return hd.next(H).reshape(len(hs), hs[0].shape[0])
+                # exact-SHARDED verify: every hidden joins the mesh
+                fn = self._head_jit((head,), step, n_placed=n_max)
             else:
                 def fn(*hs):
                     H = np.concatenate([np.asarray(h) for h in hs], axis=0)
@@ -263,14 +284,15 @@ class DecodeEngine:
         if key in self._step_cache:
             self._step_cache.move_to_end(key)       # LRU hit → most recent
         if key not in self._step_cache:
-            def step(*hs):
+            def step(dr, ve, *hs):
                 H = jnp.concatenate(hs, axis=0)            # (n_max·W, d)
                 W = hs[0].shape[0]
-                q = adjust_logits(draft.dist_logits(H), temperature, top_p)
-                p = adjust_logits(verify.dist_logits(H), temperature, top_p)
+                q = adjust_logits(dr.dist_logits(H), temperature, top_p)
+                p = adjust_logits(ve.dist_logits(H), temperature, top_p)
                 return (q.reshape(len(hs), W, -1),
                         p.reshape(len(hs), W, -1))
-            self._put_step(key, jax.jit(step))
+            self._put_step(key, self._head_jit((draft, verify), step,
+                                               n_placed=0))
         return self._step_cache[key]
 
     # -- paged decode steps (attention families; see repro.serving.kvpool) ---
@@ -282,14 +304,11 @@ class DecodeEngine:
         key = (head.step_key(), "greedy-paged")
         if key not in self._step_cache:
             if head.is_jittable:
-                def step(params, tok, pk, pv, table, pos):
+                def step(hd, params, tok, pk, pv, table, pos):
                     h, pool = self.model.decode_step_paged(
                         params, tok, {"k": pk, "v": pv}, table, pos)
-                    return head.next(h), h, pool["k"], pool["v"]
-                if head.mesh is not None:
-                    fn = self._mesh_aware_jit(head, step, n_placed=4)
-                else:
-                    fn = jax.jit(step)
+                    return hd.next(h), h, pool["k"], pool["v"]
+                fn = self._head_jit((head,), step, n_placed=4)
             else:
                 def fn(params, tok, pk, pv, table, pos):
                     h, pool = self._jit_decode_paged(params, tok, pk, pv,
@@ -312,15 +331,12 @@ class DecodeEngine:
             self._step_cache.move_to_end(key)       # LRU hit → most recent
         if key not in self._step_cache:
             if head.is_jittable:
-                def step(params, rkey, tok, pk, pv, table, pos):
+                def step(hd, params, rkey, tok, pk, pv, table, pos):
                     h, pool = self.model.decode_step_paged(
                         params, tok, {"k": pk, "v": pv}, table, pos)
-                    return (head.sample(rkey, h, temperature, top_p), h,
+                    return (hd.sample(rkey, h, temperature, top_p), h,
                             pool["k"], pool["v"])
-                if head.mesh is not None:
-                    fn = self._mesh_aware_jit(head, step, n_placed=5)
-                else:
-                    fn = jax.jit(step)
+                fn = self._head_jit((head,), step, n_placed=5)
             else:
                 def fn(params, rkey, tok, pk, pv, table, pos):
                     h, pool = self._jit_decode_paged(params, tok, pk, pv,

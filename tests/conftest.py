@@ -59,3 +59,19 @@ def rng():
 @pytest.fixture(scope="session")
 def key():
     return jax.random.key(0)
+
+
+@pytest.fixture
+def isolated_compile_cache(monkeypatch, tmp_path):
+    """For tests that call an entry point (``enable_compile_cache``): point
+    ``JAX_COMPILATION_CACHE_DIR`` at a temp dir so the helper places no
+    cache, and restore the settings it changes afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    saved = {name: getattr(jax.config, name) for name in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs")}
+    yield tmp_path / "cache"
+    for name, value in saved.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()

@@ -227,7 +227,13 @@ def sharded_fixture():
 @pytest.mark.parametrize("k", [K, 40, 120])
 def test_exact_sharded_bit_identical(sharded_fixture, n_shards, k):
     """exact-sharded == exact: ids bit-identical, scores/logprobs equal to
-    float tolerance, at every shard count, k above and below L/n_shards."""
+    float tolerance, at every shard count, k above and below L/n_shards.
+
+    Scores are not bit-identical: each shard's (B, L/n)·(L/n, D) matmul may
+    be blocked differently by XLA than the one (B, L)·(L, D) matmul, so
+    the D-term dot products round in a different order. The bound on that
+    is float32's summation error, D·2⁻²⁴·Σ|hᵢ||wᵢ| per logit (twice it
+    between two orders); the ids stay exactly equal."""
     _require_devices(n_shards)
     fx = sharded_fixture
     head = heads.get("exact-sharded", W=fx["W"], b=fx["b"],
@@ -236,8 +242,10 @@ def test_exact_sharded_bit_identical(sharded_fixture, n_shards, k):
     eids, evals = fx["exact"].topk(fx["h"], k)
     ids, vals = head.topk(fx["h"], k)
     np.testing.assert_array_equal(np.asarray(ids), np.asarray(eids))
-    np.testing.assert_allclose(np.asarray(vals), np.asarray(evals),
-                               rtol=1e-6, atol=1e-6)
+    mag = np.abs(np.asarray(fx["h"])) @ np.abs(np.asarray(fx["W"])).T
+    bound = 2 * D * 2.0 ** -24 * np.take_along_axis(mag, np.asarray(eids),
+                                                    axis=-1)
+    assert np.all(np.abs(np.asarray(vals) - np.asarray(evals)) <= bound)
     elids, elp = fx["exact"].topk_logprobs(fx["h"], k)
     lids, lp = head.topk_logprobs(fx["h"], k)
     np.testing.assert_array_equal(np.asarray(lids), np.asarray(elids))
@@ -324,7 +332,7 @@ def test_adaptive_short_tier_materializes_no_full_vocab_buffer(
     ad = heads.get("adaptive", W=fx["W"], b=fx["b"], shortlist=50, n_tails=3)
     args = (ad._Wb, ad._bb, ad._gid, ad._short_blocks, ad._tail_tab,
             ad._g, ad._gb, fx["h"])
-    text = _fused_tiered_topk.lower(*args, k=K, L=LS, interpret=True) \
+    text = _fused_tiered_topk.lower(*args, k=K, L=LS) \
         .compile().as_text()
     n_blk = ad._Wb.shape[0]
     assert not materializes_f32_buffer(text, N, LS)
@@ -333,9 +341,9 @@ def test_adaptive_short_tier_materializes_no_full_vocab_buffer(
     # (N, n_blk·V_BLK) packed logit row, fused must not
     full = heads.get("adaptive", W=fx["W"], b=fx["b"], shortlist=LS)
     fargs = (full._Wb, full._bb, full._gid, full._short_blocks, fx["h"])
-    utext = _unfused_short_topk.lower(*fargs, k=K, L=LS, interpret=True) \
+    utext = _unfused_short_topk.lower(*fargs, k=K, L=LS) \
         .compile().as_text()
-    ftext = _fused_short_topk.lower(*fargs, k=K, L=LS, interpret=True) \
+    ftext = _fused_short_topk.lower(*fargs, k=K, L=LS) \
         .compile().as_text()
     nb = full._Wb.shape[0]
     assert materializes_f32_buffer(utext, N, nb * 128)

@@ -4,6 +4,7 @@ fused-kernel memory contract (no (B, K·V_BLK) candidate-logit buffer)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.launch.hlo_cost import (analyze_hlo, materializes_f32_buffer,
                                    xla_bytes_accessed)
@@ -121,9 +122,22 @@ def test_fused_kernel_materializes_no_candidate_logit_buffer():
 
 def test_roofline_terms():
     r = Roofline(flops=197e12, bytes_accessed=819e9,
-                 collective_bytes=50e9)
+                 collective_bytes=50e9, device_kind="TPU v5 lite")
     assert abs(r.compute_s - 1.0) < 1e-9
     assert abs(r.memory_s - 1.0) < 1e-9
     assert abs(r.collective_s - 1.0) < 1e-9
-    r2 = Roofline(flops=1, bytes_accessed=819e9 * 5, collective_bytes=1)
+    r2 = Roofline(flops=1, bytes_accessed=819e9 * 5, collective_bytes=1,
+                  device_kind="TPU v5 lite")
     assert r2.dominant == "memory"
+
+
+def test_roofline_unknown_device_is_an_error():
+    """Peaks come from one table keyed by device_kind; a chip with no
+    published entry must fail, not borrow another chip's peaks."""
+    from repro.launch.mesh import CHIP_PEAKS, chip_peaks
+    assert chip_peaks("TPU v5 lite") is CHIP_PEAKS["TPU v5 lite"]
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_peaks("cpu")
+    with pytest.raises(KeyError, match="TPU v9000"):
+        Roofline(flops=1, bytes_accessed=1, collective_bytes=1,
+                 device_kind="TPU v9000")

@@ -167,3 +167,16 @@ def test_ssd_kernel_plus_scan_equals_ssd_chunked():
     np.testing.assert_allclose(y, np.asarray(y_ref), atol=1e-3, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(Hst), np.asarray(h_ref),
                                atol=1e-3, rtol=1e-3)
+
+
+def test_interpret_mode_follows_backend(monkeypatch):
+    """One place picks the Pallas mode: interpreted off the TPU, compiled
+    on it, and an explicit override wins either way."""
+    from repro.kernels import mode
+    assert mode.interpret_mode() is (jax.default_backend() != "tpu")
+    monkeypatch.setattr(mode.jax, "default_backend", lambda: "tpu")
+    assert mode.interpret_mode() is False
+    assert mode.interpret_mode(True) is True
+    monkeypatch.setattr(mode.jax, "default_backend", lambda: "cpu")
+    assert mode.interpret_mode() is True
+    assert mode.interpret_mode(False) is False

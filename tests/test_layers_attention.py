@@ -87,10 +87,20 @@ def test_decode_matches_forward():
     np.testing.assert_allclose(np.asarray(full), np.asarray(dec), atol=1e-5)
 
 
+# The vector-position branch builds its mask and cache writes per row, so
+# XLA fuses and orders the softmax·V reduction differently from the scalar
+# branch: outputs agree to float32 rounding of a reduction over the 8 cached
+# positions (a few ulps; 8·2⁻²³ relative bounds it), not bit for bit. The
+# caches themselves are written from identical projections and stay
+# bit-identical.
+_VEC_POS_TOL = dict(rtol=8 * 2.0 ** -23, atol=8 * 2.0 ** -23)
+
+
 def test_vector_pos_decode_bit_identical_to_scalar():
     """The per-row position branch (continuous batching): aligned rows give
-    BIT-identical outputs/caches to the scalar path, and rows at DIFFERENT
-    depths each match their own scalar-pos decode."""
+    outputs equal to float32 rounding (see ``_VEC_POS_TOL``) and
+    BIT-identical caches to the scalar path, and rows at DIFFERENT depths
+    each match their own scalar-pos decode."""
     p, x, pos = _setup(T=8)
     cache_s = init_cache(CFG, 2, 8, dtype=jnp.float32)
     cache_v = init_cache(CFG, 2, 8, dtype=jnp.float32)
@@ -98,7 +108,8 @@ def test_vector_pos_decode_bit_identical_to_scalar():
         o_s, cache_s = attn_decode(p, x[:, t:t + 1], cache_s, t, CFG)
         o_v, cache_v = attn_decode(p, x[:, t:t + 1], cache_v,
                                    jnp.full((2,), t, jnp.int32), CFG)
-        np.testing.assert_array_equal(np.asarray(o_s), np.asarray(o_v))
+        np.testing.assert_allclose(np.asarray(o_v), np.asarray(o_s),
+                                   **_VEC_POS_TOL)
     np.testing.assert_array_equal(np.asarray(cache_s["k"]),
                                   np.asarray(cache_v["k"]))
     # divergent depths: row 0 at t, row 1 at t+3 — each row equals a solo
@@ -117,8 +128,10 @@ def test_vector_pos_decode_bit_identical_to_scalar():
         ov, cv = attn_decode(p, x[jnp.asarray([0, 1]),
                                jnp.asarray([t, t + 3])][:, None], cv,
                              jnp.asarray([t, t + 3], jnp.int32), CFG)
-        np.testing.assert_array_equal(np.asarray(ov[0]), np.asarray(o0[0]))
-        np.testing.assert_array_equal(np.asarray(ov[1]), np.asarray(o1[0]))
+        np.testing.assert_allclose(np.asarray(ov[0]), np.asarray(o0[0]),
+                                   **_VEC_POS_TOL)
+        np.testing.assert_allclose(np.asarray(ov[1]), np.asarray(o1[0]),
+                                   **_VEC_POS_TOL)
 
 
 def test_vector_pos_ring_buffer_decode():

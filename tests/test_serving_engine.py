@@ -1,6 +1,8 @@
 """Serving engine on the SoftmaxHead API: greedy == teacher-forced argmax;
 beam ≥ greedy score; screened decode; kernel-head decode; per-request head
 switching; cache reordering under beam search; deprecated sampling shims."""
+import warnings
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,6 +31,36 @@ def test_greedy_matches_teacher_forcing(arch):
     logits = m.logits(params, h)
     ref = np.asarray(jnp.argmax(logits, -1))[:, 5:-1]
     np.testing.assert_array_equal(ref, r.tokens)
+
+
+@pytest.mark.parametrize("head", ["exact", "screened-pallas"])
+def test_composed_step_takes_head_weights_as_arguments(head):
+    """The cached greedy step lowers with W as an executable argument: a
+    head closed over by the step would embed its weights as constants (a
+    second copy of W per compiled step)."""
+    cfg = get_config("ptb-small-lstm").reduced()
+    m = build_model(cfg)
+    params = m.init(jax.random.key(0), dtype=jnp.float32)
+    W, b = m.softmax_weights(params)
+    H = np.random.default_rng(0).standard_normal((64, cfg.d_model))
+    y = np.asarray(jnp.argsort(-(jnp.asarray(H, jnp.float32) @ W.T + b),
+                               axis=-1)[:, :5])
+    screen = fit_l2s(H.astype(np.float32), y, cfg.vocab_size,
+                     L2SConfig(num_clusters=4, budget=256, outer_iters=1,
+                               sgd_steps=5, vocab_block=128)).screen
+    eng = DecodeEngine(m, params, screen=screen, max_len=16)
+    fn = eng._greedy_step(eng.resolve_head(head))
+    tok = jnp.zeros((2,), jnp.int32)
+    cache = m.init_cache(2, 16, dtype=jnp.float32)
+    was = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", 1024)
+    try:
+        with warnings.catch_warnings():
+            warnings.filterwarnings("error", message=".*constants were "
+                                    "captured")
+            fn.lower(params, tok, cache, tok)
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", was)
 
 
 def test_beam_score_at_least_greedy():

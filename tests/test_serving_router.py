@@ -505,3 +505,39 @@ def test_serve_launcher_exits_2_without_screen(capsys):
     out = capsys.readouterr().out
     assert "cannot build head 'screened'" in out
     assert "--l2s" in out and "fit_l2s" in out
+
+
+def test_serve_launcher_refuses_unbuildable_named_head(
+        capsys, monkeypatch, isolated_compile_cache):
+    """A --head that cannot be built once the screen is fit exits 2 instead
+    of serving its tier on exact. Forced here by fitting screened-pallas a
+    word-granular screen, which its constructor refuses."""
+    from repro.launch import serve as serve_mod
+    monkeypatch.setattr(serve_mod, "screen_block", lambda *names: 1)
+    rc = serve_mod.main(["--arch", "ptb-small-lstm", "--reduced", "--l2s",
+                         "--head", "screened-pallas", "--scheduler",
+                         "--train-steps", "2", "--requests", "2",
+                         "--max-new", "2", "--clusters", "4",
+                         "--budget", "40"])
+    out = capsys.readouterr().out
+    assert rc == 2
+    assert "cannot build head 'screened-pallas'" in out
+    assert "vocab_block=128" in out
+    assert "scheduler:" not in out                 # nothing was served
+
+
+def test_serve_launcher_serves_named_pallas_head(capsys,
+                                                 isolated_compile_cache):
+    """--head screened-pallas fits its 128-word block screen, at a default
+    budget of whole blocks, and serves the realtime tier on it — not on
+    exact, and not through a fault's fallback."""
+    from repro.launch import serve as serve_mod
+    rc = serve_mod.main(["--arch", "ptb-small-lstm", "--reduced", "--l2s",
+                         "--head", "screened-pallas", "--scheduler",
+                         "--train-steps", "2", "--requests", "3",
+                         "--max-new", "2", "--clusters", "4"])
+    out = capsys.readouterr().out
+    assert rc == 0
+    assert "budget=1024" in out and "block=128" in out
+    assert "screened-pallas: 1 req" in out
+    assert "resilience" not in out
