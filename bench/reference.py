@@ -1,0 +1,251 @@
+"""The plain reference: a float32 LSTM language model and both head
+semantics, written from the equations alone.
+
+    gates = x·Wx + h·Wh + b,  (i, f, g, o) = split(gates, 4)
+    c' = σ(f)·c + σ(i)·tanh(g),  h' = σ(o)·tanh(c')
+    logits = h·Woutᵀ + bout
+
+Exact head: the served token is the arg-max of the logits over the whole
+vocabulary. Screened head (L2S, paper Eq. 2): the context routes to the
+cluster t = argmax_t v_t·h, and the served token is the arg-max of the
+logits over that cluster's candidate words.
+
+Nothing here imports the program. The weights are regenerated from the
+seed by ``init_weights`` (the same function the harness used to make the
+weights it handed the program); the fitted screen (v, candidate blocks) is
+the served model's own parameter set and is copied to the host at set-up.
+Every matrix product runs at ``Precision.HIGHEST`` so that a TPU does not
+round its float32 operands to bfloat16.
+
+The controls (``CONTROLS``) are the same model at a lower precision, put in
+the program's place: ``float8``, every weight matrix rounded to float8
+e4m3 (3 mantissa bits) with a per-tensor scale, activations and state in
+float32; ``bfloat16``, weights, activations and state in bfloat16.
+"""
+from __future__ import annotations
+
+from functools import partial
+from typing import Dict, List, Optional, Sequence
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def init_weights(key, vocab: int, d: int, layers: int,
+                 dtype=jnp.float32) -> Dict[str, object]:
+    """Random weights from one key, at PyTorch's default initialisation:
+    embedding N(0, 1); every LSTM and output weight and bias uniform in
+    ±1/√d; the forget-gate bias raised by 1 (Jozefowicz et al. 2015, and
+    the program's own LSTM initialiser). → {"emb" (V, d), "layers":
+    [{"wx" (d, 4d), "wh" (d, 4d), "b" (4d,)}], "w_out" (V, d), "b_out" (V,)}.
+    """
+    ks = jax.random.split(key, 3 + 3 * layers)
+    a = 1.0 / float(np.sqrt(d))
+
+    def u(k, shape):
+        return jax.random.uniform(k, shape, dtype, -a, a)
+
+    return {"emb": jax.random.normal(ks[0], (vocab, d), dtype),
+            "layers": [{"wx": u(ks[3 + 3 * i], (d, 4 * d)),
+                        "wh": u(ks[4 + 3 * i], (d, 4 * d)),
+                        "b": u(ks[5 + 3 * i], (4 * d,)).at[d:2 * d].add(1.0)}
+                       for i in range(layers)],
+            "w_out": u(ks[1], (vocab, d)),
+            "b_out": u(ks[2], (vocab,))}
+
+
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=HIGHEST)
+
+
+# float8 e4m3 with a per-tensor scale: the largest magnitude maps to the
+# largest finite value of a 4-bit exponent, 3-bit mantissa format
+E4M3_MAX = 240.0
+
+
+def float8_weights(a):
+    """``a`` rounded as float8 e4m3 with a per-tensor scale (float32)."""
+    scale = jnp.max(jnp.abs(a)) / E4M3_MAX
+    q = jax.lax.reduce_precision(a / scale, exponent_bits=4, mantissa_bits=3)
+    return q * scale
+
+
+def lower(w, v, control: str):
+    """Weights and route vectors at the control's precision."""
+    if control == "bfloat16":
+        return (jax.tree.map(lambda a: a.astype(jnp.bfloat16), w),
+                v.astype(jnp.bfloat16))
+    if control == "float8":
+        low = {"emb": float8_weights(w["emb"]),
+               "layers": [{"wx": float8_weights(p["wx"]),
+                           "wh": float8_weights(p["wh"]), "b": p["b"]}
+                          for p in w["layers"]],
+               "w_out": float8_weights(w["w_out"]), "b_out": w["b_out"]}
+        return low, float8_weights(v)
+    raise ValueError(f"unknown control {control!r} (known: {CONTROLS})")
+
+
+CONTROLS = ("float8", "bfloat16")
+
+
+def trunk(w, tokens):
+    """Teacher-forced hidden states: tokens (B, T) → h (B, T, d)."""
+    x = w["emb"][tokens]
+    B = tokens.shape[0]
+    for p in w["layers"]:
+        d = p["wh"].shape[0]
+
+        def cell(carry, xt, p=p):
+            h, c = carry
+            gates = _mm(xt, p["wx"]) + _mm(h, p["wh"]) + p["b"]
+            i, f, g, o = jnp.split(gates, 4, axis=-1)
+            c = jax.nn.sigmoid(f) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
+            h = jax.nn.sigmoid(o) * jnp.tanh(c)
+            return (h, c), h
+
+        zero = jnp.zeros((B, d), x.dtype)
+        _, ys = jax.lax.scan(cell, (zero, zero), jnp.moveaxis(x, 0, 1))
+        x = jnp.moveaxis(ys, 0, 1)
+    return x
+
+
+def _head(w, v, cand_words, h):
+    """Logits (N, V+1) with a −inf column for padded candidate slots, route
+    scores (N, r), and each cluster's best candidate logit (N, r)."""
+    z = _mm(h, w["w_out"].T) + w["b_out"]
+    z = jnp.concatenate([z, jnp.full((z.shape[0], 1), -jnp.inf, z.dtype)], 1)
+    s = _mm(h, v.T)
+    best_c = jax.lax.map(lambda words: jnp.max(z[:, words], axis=-1),
+                         cand_words).T
+    return z, s, best_c
+
+
+@partial(jax.jit, static_argnames=("screened", "control"))
+def _block(w, v, cand_words, tokens, targets, screened: bool,
+           control: Optional[str]):
+    """One block of sequences. → dict of per-position arrays (B·T,)."""
+    f32 = jax.tree.map(lambda a: a.astype(jnp.float32), w)
+    h = trunk(f32, tokens).reshape(-1, f32["w_out"].shape[1])
+    z, s, best_c = _head(f32, v.astype(jnp.float32), cand_words, h)
+    tgt = targets.reshape(-1)
+    rows = jnp.arange(z.shape[0])
+    out = {"zmax": jnp.max(z, -1), "zarg": jnp.argmax(z, -1),
+           "z_served": z[rows, tgt], "s": s, "best_c": best_c}
+    if control:
+        low, vl = lower(w, v, control)
+        hl = trunk(low, tokens).reshape(h.shape)
+        zl, sl, _ = _head(low, vl, cand_words, hl)
+        if screened:
+            t = jnp.argmax(sl, -1)
+            words = cand_words[t]                          # (N, Cw)
+            pick = jnp.argmax(jnp.take_along_axis(zl, words, 1), -1)
+            tok = jnp.take_along_axis(words, pick[:, None], 1)[:, 0]
+        else:
+            tok = jnp.argmax(zl[:, :-1], -1)
+        out["ctl_tok"] = tok
+        out["z_ctl"] = z[rows, tok]
+    return out
+
+
+def candidate_words(cand_idx: np.ndarray, cand_len: np.ndarray, block: int,
+                    vocab: int) -> np.ndarray:
+    """(r, C_max·block) word ids of each cluster's candidate blocks; padded
+    slots and words past the vocabulary point at ``vocab`` (the −inf
+    column)."""
+    r, c_max = cand_idx.shape
+    lane = np.arange(block)
+    words = cand_idx[:, :, None].astype(np.int64) * block + lane
+    valid = np.arange(c_max)[None, :, None] < cand_len[:, None, None]
+    words = np.where(valid & (words < vocab), words, vocab)
+    return words.reshape(r, -1).astype(np.int32)
+
+
+class Reference:
+    """Teacher-forced reference over served requests.
+
+    ``weights``: ``init_weights``' dict; ``screen``: None for the exact
+    head, else host copies {"v", "cand_idx", "cand_len", "block"}."""
+
+    def __init__(self, weights, screen, vocab: int, block_rows: int = 32,
+                 seq_len: int = 0):
+        self.w = weights
+        self.vocab = int(vocab)
+        self.block_rows = int(block_rows)
+        self.seq_len = int(seq_len)
+        self.screened = screen is not None
+        if self.screened:
+            self.v = jnp.asarray(screen["v"], jnp.float32)
+            self.words = candidate_words(np.asarray(screen["cand_idx"]),
+                                         np.asarray(screen["cand_len"]),
+                                         int(screen["block"]), self.vocab)
+        else:
+            d = weights["w_out"].shape[1]
+            self.v = jnp.zeros((1, d), jnp.float32)
+            self.words = np.full((1, 1), self.vocab, np.int32)
+        self.member = np.zeros((self.words.shape[0], self.vocab + 1), bool)
+        self.member[np.arange(self.words.shape[0])[:, None], self.words] = True
+        self.member[:, self.vocab] = False
+
+    def run(self, prompts: Sequence[np.ndarray], served: Sequence[np.ndarray],
+            control: Optional[str] = None) -> Dict[str, np.ndarray]:
+        """Per checked position (every served token of every request):
+        reference quantities, and the pick of the control named by
+        ``control`` (one of ``CONTROLS``)."""
+        seqs, tgts, masks = [], [], []
+        for p, t in zip(prompts, served):
+            p, t = np.asarray(p), np.asarray(t)
+            x = np.concatenate([p, t[:-1]])
+            y = np.zeros_like(x)
+            m = np.zeros(len(x), bool)
+            y[len(p) - 1:] = t
+            m[len(p) - 1:] = True
+            seqs.append(x), tgts.append(y), masks.append(m)
+        T = max(self.seq_len, max(len(s) for s in seqs))
+        cw = jnp.asarray(self.words)
+        parts: List[Dict[str, np.ndarray]] = []
+        for i in range(0, len(seqs), self.block_rows):
+            blk = range(i, min(i + self.block_rows, len(seqs)))
+            tok = np.zeros((self.block_rows, T), np.int32)
+            tgt = np.zeros((self.block_rows, T), np.int32)
+            msk = np.zeros((self.block_rows, T), bool)
+            for j, k in enumerate(blk):
+                n = len(seqs[k])
+                tok[j, :n], tgt[j, :n], msk[j, :n] = seqs[k], tgts[k], masks[k]
+            out = _block(self.w, self.v, cw, jnp.asarray(tok),
+                         jnp.asarray(tgt), screened=self.screened,
+                         control=control)
+            keep = msk.reshape(-1)
+            part = {k: np.asarray(a)[keep] for k, a in out.items()}
+            part["served"] = tgt.reshape(-1)[keep]
+            parts.append(part)
+        return {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
+
+    def gaps(self, ref: Dict[str, np.ndarray], tokens: np.ndarray,
+             z_tok: np.ndarray) -> Dict[str, np.ndarray]:
+        """For chosen ``tokens`` (with their reference logits ``z_tok``):
+        ``token_gap``, how far each token's logit lies below the best the
+        reference allows. Screened head: a token is the head's answer for
+        a cluster c that holds it when c routes first and the token is
+        c's best candidate; c's ``route_gap`` is the reference's best route
+        score minus c's, its ``token_gap`` c's best candidate logit minus
+        the token's. Both are read at the cluster holding the token whose
+        larger gap is least, so a near-tie in routing that sends the
+        context to another cluster holding the token reads as the small
+        route gap it is (inf on both when no cluster holds the token)."""
+        if not self.screened:
+            return {"token_gap": ref["zmax"] - z_tok}
+        s, best_c = ref["s"], ref["best_c"]
+        n = len(tokens)
+        member = self.member[:, tokens].T                  # (n, r)
+        rg = s.max(axis=1, keepdims=True) - s              # (n, r)
+        tg = best_c - z_tok[:, None]
+        worst = np.where(member, np.maximum(rg, tg), np.inf)
+        c = np.argmin(worst, axis=1)
+        rows = np.arange(n)
+        has = member.any(axis=1)
+        route = np.where(has, rg[rows, c], np.inf)
+        token = np.where(has, tg[rows, c], np.inf)
+        return {"token_gap": token, "route_gap": route}

@@ -1,0 +1,51 @@
+"""Operations and bytes the served model needs, from shapes and the screen.
+
+Counted from the configuration and the fitted screen, never from the
+implementation, so a PR that does the same work in fewer operations shows
+as a gain and one that skips work cannot hide it.
+
+Trunk: each LSTM layer computes gates = x·Wx + h·Wh + b with Wx, Wh of
+shape (d, 4d): 2·4d² multiply-adds = 16·d² operations per layer and token.
+Head: the exact softmax scores every word, L·d multiply-adds; the screened
+head scores the r cluster centres and the L̄ words of the routed candidate
+set, (r + L̄)·d.
+"""
+from __future__ import annotations
+
+import numpy as np
+
+
+def trunk_flops_per_token(d: int, layers: int) -> float:
+    return 2.0 * 8 * d * d * layers
+
+
+def head_macs(head: str, vocab: int, d: int, r: int = 0,
+              lbar: float = 0.0) -> float:
+    """Multiply-adds per query of ``head``: L·d exact, (r + L̄)·d screened."""
+    if head == "exact":
+        return float(vocab) * d
+    return (float(r) + float(lbar)) * d
+
+
+def mean_candidate_words(cand_len, block: int) -> float:
+    """L̄: candidate words per cluster, averaged over the r clusters."""
+    return float(np.mean(np.asarray(cand_len, np.float64))) * block
+
+
+def model_flops(d: int, layers: int, head_macs_per_query: float,
+                prompt_tokens: int, output_tokens: int, joins: int) -> float:
+    """Operations the served work required: every prompt token runs the
+    trunk once; every output token runs the head once; every output token
+    after a request's first runs one more trunk step (the first comes
+    straight from the prefill's last state)."""
+    trunk = trunk_flops_per_token(d, layers)
+    decode_steps = max(output_tokens - joins, 0)
+    return (prompt_tokens + decode_steps) * trunk \
+        + output_tokens * 2.0 * head_macs_per_query
+
+
+def fused_topk_work(rows: int, lbar: float, d: int) -> tuple:
+    """(FLOPs, bytes) the fused subset top-k needs for ``rows`` active query
+    rows: each scores its L̄ candidate words (2·L̄·d operations) from their
+    float32 weight rows (4·L̄·d bytes)."""
+    return 2.0 * rows * lbar * d, 4.0 * rows * lbar * d
