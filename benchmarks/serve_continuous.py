@@ -42,8 +42,7 @@ except ImportError:                        # script's own dir is sys.path[0]
 from repro.serving import (BudgetAdmission, CircuitBreaker,
                            ContinuousScheduler, FaultInjector, LogicalClock,
                            PagePool, ServeRequest, ServeResult,
-                           StreamWatchdog, TierPolicy, Tracer,
-                           audit_cost_drift)
+                           StreamWatchdog, TierPolicy, Tracer)
 from repro.serving.scheduler import TIER_DEADLINES, AdmissionRejected
 from repro.utils.compile_cache import enable_compile_cache
 
@@ -198,21 +197,6 @@ def main(argv=None):
     for head, d in snap["per_head"].items():
         print(f"{head:<18}{d['requests']:>9}{d['tokens']:>8}"
               f"{d['tokens_per_s']:>10.0f}")
-    # cost-model drift audit: cataloged flops/bytes per query vs the HLO-
-    # measured executables and wall-clock timing, per active head — the
-    # numbers CostAwarePolicy / BudgetAdmission priced this run with
-    drift = audit_cost_drift(engine, tuple(policy.candidates))
-    print(f"{'head':<18}{'pred flops':>12}{'hlo flops':>12}{'ratio':>7}")
-    for head, d in drift.items():
-        if "error" in d:
-            print(f"{head:<18}  audit error: {d['error']}")
-            continue
-        pf = d["predicted"]["flops_per_query"]
-        mf = d["measured"].get("hlo_flops")
-        rf = d["ratio"]["flops"]
-        print(f"{head:<18}{pf:>12.3g}"
-              f"{mf if mf is not None else float('nan'):>12.3g}"
-              f"{rf if rf is not None else float('nan'):>7.2f}")
     if args.json:
         path = update_bench_json("serve_continuous", {
             "devices": jax.device_count(), "vocab": cfg.vocab_size,
@@ -221,10 +205,6 @@ def main(argv=None):
             "wall_s": wall, "completed_tokens": completed_tokens,
             "tokens_per_s": completed_tokens / wall,
             "recompiles": recompiles, "trace": trace_info, **snap,
-        }, path=args.json)
-        update_bench_json("cost_drift", {
-            "devices": jax.device_count(), "vocab": cfg.vocab_size,
-            "reduced": args.reduced, "per_head": drift,
         }, path=args.json)
         print(f"[serve_continuous] wrote {path}")
     return 0

@@ -197,15 +197,17 @@ def fused_screened_topk(W_blocks: jnp.ndarray, b_blocks: jnp.ndarray,
             pltpu.VMEM((1, v_blk), jnp.float32),
         ],
     )
-    vals, ids, logz = pl.pallas_call(
-        functools.partial(_fused_topk_kernel, k=k, n_blk=n_blk, v_blk=v_blk,
-                          with_noise=noise is not None),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, 1, k_pad), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1, k_pad), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1, v_blk), jnp.float32),
-        ],
-        interpret=interpret_mode(interpret),
-    )(*inputs)
-    return ids[:, 0, :k], vals[:, 0, :k], logz[:, 0, 0]
+    with jax.named_scope("fused_topk"):
+        vals, ids, logz = pl.pallas_call(
+            functools.partial(_fused_topk_kernel, k=k, n_blk=n_blk,
+                              v_blk=v_blk, with_noise=noise is not None),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((B, 1, k_pad), jnp.float32),
+                jax.ShapeDtypeStruct((B, 1, k_pad), jnp.int32),
+                jax.ShapeDtypeStruct((B, 1, v_blk), jnp.float32),
+            ],
+            interpret=interpret_mode(interpret),
+            name="fused_topk",
+        )(*inputs)
+        return ids[:, 0, :k], vals[:, 0, :k], logz[:, 0, 0]

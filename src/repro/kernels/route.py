@@ -51,21 +51,22 @@ def cluster_route(h: jnp.ndarray, v: jnp.ndarray,
     r = v.shape[0]
     r_pad = -(-r // LANE) * LANE
     b_pad = -(-B // B_TILE) * B_TILE
-    vt = jnp.zeros((d, r_pad), v.dtype).at[:, :r].set(v.T)
-    hp = jnp.zeros((b_pad, d), h.dtype).at[:B].set(h)
-
-    out = pl.pallas_call(
-        functools.partial(_route_kernel, r_true=r),
-        grid=(b_pad // B_TILE,),
-        in_specs=[
-            pl.BlockSpec((B_TILE, d), lambda i: (i, 0)),
-            pl.BlockSpec((d, r_pad), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((B_TILE,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((b_pad,), jnp.int32),
-        interpret=interpret_mode(interpret),
-    )(hp, vt)
-    return out[:B]
+    with jax.named_scope("route"):
+        vt = jnp.zeros((d, r_pad), v.dtype).at[:, :r].set(v.T)
+        hp = jnp.zeros((b_pad, d), h.dtype).at[:B].set(h)
+        out = pl.pallas_call(
+            functools.partial(_route_kernel, r_true=r),
+            grid=(b_pad // B_TILE,),
+            in_specs=[
+                pl.BlockSpec((B_TILE, d), lambda i: (i, 0)),
+                pl.BlockSpec((d, r_pad), lambda i: (0, 0)),
+            ],
+            out_specs=pl.BlockSpec((B_TILE,), lambda i: (i,)),
+            out_shape=jax.ShapeDtypeStruct((b_pad,), jnp.int32),
+            interpret=interpret_mode(interpret),
+            name="route",
+        )(hp, vt)
+        return out[:B]
 
 
 cluster_route_pallas = jax.jit(cluster_route, static_argnames=("interpret",))

@@ -103,6 +103,10 @@ class Model:
         prefix-cache compute skip: a scan restarted from a snapshot runs
         the identical cell sequence, so resumed prefill over a suffix is
         bit-identical to one-shot prefill over the full prompt."""
+        with jax.named_scope("prefill"):
+            return self._prefill(params, batch, cache, window, resume)
+
+    def _prefill(self, params, batch, cache, window, resume):
         cfg = self.cfg
         if cfg.family == "lstm":
             x = embed_tokens(params["embed"], batch["tokens"], cfg)
@@ -130,13 +134,15 @@ class Model:
         vector of per-row positions (continuous batching — rows decoding at
         different depths; see attn_decode). → (h (B, d), cache)."""
         cfg = self.cfg
-        x1 = embed_tokens(params["embed"], token[:, None], cfg)     # (B, 1, d)
-        if cfg.family == "lstm":
-            h, new_state = lstm_decode_step(params["lstm"], x1[:, 0],
-                                            cache["lstm"], cfg)
-            return h, {"lstm": new_state}
-        h, new_cache = stack_decode(params["stack"], x1, cache, pos, cfg, window)
-        return h[:, 0], new_cache
+        with jax.named_scope("decode_step"):
+            x1 = embed_tokens(params["embed"], token[:, None], cfg)  # (B,1,d)
+            if cfg.family == "lstm":
+                h, new_state = lstm_decode_step(params["lstm"], x1[:, 0],
+                                                cache["lstm"], cfg)
+                return h, {"lstm": new_state}
+            h, new_cache = stack_decode(params["stack"], x1, cache, pos, cfg,
+                                        window)
+            return h[:, 0], new_cache
 
     def decode_step_paged(self, params, token, pool, page_table, pos):
         """Paged decode step (attention families): K/V live in a shared
@@ -147,10 +153,11 @@ class Model:
             raise NotImplementedError(
                 "LSTM decode carries no per-token KV — paged LSTM streams "
                 "use the ordinary decode_step with logical page accounting")
-        x1 = embed_tokens(params["embed"], token[:, None], cfg)     # (B, 1, d)
-        h, new_pool = stack_decode_paged(params["stack"], x1, pool,
-                                         page_table, pos, cfg)
-        return h[:, 0], new_pool
+        with jax.named_scope("decode_step"):
+            x1 = embed_tokens(params["embed"], token[:, None], cfg)  # (B,1,d)
+            h, new_pool = stack_decode_paged(params["stack"], x1, pool,
+                                             page_table, pos, cfg)
+            return h[:, 0], new_pool
 
 
 def _text_positions(x):

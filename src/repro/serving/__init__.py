@@ -2,8 +2,7 @@ from repro.serving.engine import DecodeEngine, DecodeStream, GenerationResult
 from repro.serving.kvpool import (PagedDecodeStream, PagePool, PoolExhausted,
                                   RadixCache)
 from repro.serving.observe import (NULL_TRACER, Counter, Gauge, Histogram,
-                                   MetricsRegistry, NullTracer, Tracer,
-                                   audit_cost_drift)
+                                   MetricsRegistry, NullTracer, Tracer)
 from repro.serving.request import ServeRequest, ServeResult
 from repro.serving.resilience import (CircuitBreaker, FaultInjector,
                                       FaultSpec, HeadFault, LogicalClock,
@@ -29,5 +28,4 @@ __all__ = ["DecodeEngine", "DecodeStream", "GenerationResult",
            "FaultInjector", "FaultSpec", "HeadFault", "LogicalClock",
            "CircuitBreaker", "StreamWatchdog",
            "Tracer", "NullTracer", "NULL_TRACER",
-           "Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "audit_cost_drift"]
+           "Counter", "Gauge", "Histogram", "MetricsRegistry"]

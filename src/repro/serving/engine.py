@@ -638,9 +638,8 @@ class DecodeStream:
         # emitting sentinel/NaN ids raises a typed HeadFault instead of
         # feeding garbage back into the decode)
         self.fault_injector = None
-        # observability: the scheduler arms its tracer here too; kernel
-        # spans time the host-side dispatch+guard window around the cached
-        # jitted step (the guard forces the device sync)
+        # observability: the scheduler arms its tracer here too; join and
+        # step scopes split host work from the one explicit device fetch
         self.tracer = NULL_TRACER
         self.vocab = int(engine.W.shape[0])
         self.width = int(width)
@@ -706,26 +705,37 @@ class DecodeStream:
                 f"request needs {Tp + request.max_new} cache slots, stream "
                 f"max_len is {eng.max_len}")
         slot = self._first_free()
-        cache1 = eng.model.init_cache(1, eng.max_len, dtype=eng.cache_dtype)
-        h, cache1 = eng._jit_prefill(
-            eng.params, {"tokens": jnp.asarray(request.prompt[None])}, cache1)
-        h_last = h[:, -1]
+        tr = self.tracer
+        with tr.scope("serve.join.prefill", "join"):
+            cache1 = eng.model.init_cache(1, eng.max_len,
+                                          dtype=eng.cache_dtype)
+            h, cache1 = eng._jit_prefill(
+                eng.params, {"tokens": jnp.asarray(request.prompt[None])},
+                cache1)
+            h_last = h[:, -1]
+        if tr.enabled:
+            tr.note(cached_tokens=0, chunks=1)
         hd = self.head
-        h_in = h_last if hd.is_jittable else np.asarray(h_last)
-        if self.sampled:
-            self._key, k0 = jax.random.split(self._key)
-            first = hd.sample(k0, h_in, self.temperature, self.top_p)
-        else:
-            first = hd.next(h_in)
+        with tr.scope("serve.join.first", "join"):
+            h_in = h_last if hd.is_jittable else np.asarray(h_last)
+            if self.sampled:
+                self._key, k0 = jax.random.split(self._key)
+                first = hd.sample(k0, h_in, self.temperature, self.top_p)
+            else:
+                first = hd.next(h_in)
+        with tr.scope("serve.join.wait", "join"):
+            first = np.asarray(first)
         # guard BEFORE any stream state mutates: a join-boundary fault
         # (injected or an honestly degenerate first token) leaves the
         # stream exactly as it was, so the scheduler can retry or re-route
         first = int(guard_tokens(self.fault_injector, "join",
                                  self.head_name, first,
                                  self.vocab).ravel()[0])
-        if self._repl is not None:
-            cache1 = jax.device_put(cache1, self._repl)
-        self.cache = _splice_cache(self.cache, cache1, slot, eng.model.cfg)
+        with tr.scope("serve.join.splice", "join"):
+            if self._repl is not None:
+                cache1 = jax.device_put(cache1, self._repl)
+            self.cache = _splice_cache(self.cache, cache1, slot,
+                                       eng.model.cfg)
         self.tok[slot] = first
         self.pos[slot] = Tp
         entry = _StreamSlot(tag=tag, request=request, tokens=[first],
@@ -749,42 +759,45 @@ class DecodeStream:
         idx = [i for i, s in enumerate(self.slots) if s is not None]
         if not idx:
             return out
-        eng = self.engine
-        tok = jnp.asarray(self.tok)
-        pos = jnp.asarray(self.pos)
-        # compute into locals and commit (cache, PRNG) only after the
-        # guard: a step-boundary fault leaves the stream untouched, so the
-        # scheduler's retry re-runs the identical step bit-for-bit (jax
-        # caches are immutable pytrees — holding the old reference IS the
-        # rollback, recurrent LSTM state included)
         tr = self.tracer
-        k_t0 = tr.now() if tr.enabled else 0.0
-        if self.sampled:
-            fn = eng._sample_step(self.head, self.temperature, self.top_p)
-            key, ki = jax.random.split(self._key)
-            nxt, _, cache = fn(eng.params, ki, tok, self.cache, pos)
-        else:
-            fn = eng._greedy_step(self.head)
-            nxt, _, cache = fn(eng.params, tok, self.cache, pos)
-        nxt = guard_tokens(self.fault_injector, "step", self.head_name,
-                           nxt, self.vocab, rows=idx)
-        if tr.enabled:
-            tr.span("kernel.step", "kernel", k_t0,
-                    args={"head": self.head_name, "active": len(idx)})
-        if self.sampled:
-            self._key = key
-        self.cache = cache
-        for i in idx:
-            s = self.slots[i]
-            t = int(nxt[i])
-            s.tokens.append(t)
-            s.remaining -= 1
-            self.tok[i] = t
-            self.pos[i] += 1
-            if s.remaining == 0:
-                out.append((s.tag, s.request,
-                            np.asarray(s.tokens, np.int32)))
-                self.slots[i] = None
+        with tr.scope("serve.step", "step"):
+            if tr.enabled:
+                tr.note(head=self.head_name, active=len(idx),
+                        rids=[_tag_id(self.slots[i].tag) for i in idx])
+            eng = self.engine
+            tok = jnp.asarray(self.tok)
+            pos = jnp.asarray(self.pos)
+            # compute into locals and commit (cache, PRNG) only after the
+            # guard: a step-boundary fault leaves the stream untouched, so
+            # the scheduler's retry re-runs the identical step bit-for-bit
+            # (jax caches are immutable pytrees — holding the old reference
+            # IS the rollback, recurrent LSTM state included)
+            if self.sampled:
+                fn = eng._sample_step(self.head, self.temperature,
+                                      self.top_p)
+                key, ki = jax.random.split(self._key)
+                nxt, _, cache = fn(eng.params, ki, tok, self.cache, pos)
+            else:
+                fn = eng._greedy_step(self.head)
+                nxt, _, cache = fn(eng.params, tok, self.cache, pos)
+            with tr.scope("serve.step.wait", "step"):
+                nxt = np.asarray(nxt)
+            nxt = guard_tokens(self.fault_injector, "step", self.head_name,
+                               nxt, self.vocab, rows=idx)
+            if self.sampled:
+                self._key = key
+            self.cache = cache
+            for i in idx:
+                s = self.slots[i]
+                t = int(nxt[i])
+                s.tokens.append(t)
+                s.remaining -= 1
+                self.tok[i] = t
+                self.pos[i] += 1
+                if s.remaining == 0:
+                    out.append((s.tag, s.request,
+                                np.asarray(s.tokens, np.int32)))
+                    self.slots[i] = None
         return out
 
     def pop_finished(self) -> List[tuple]:
@@ -803,6 +816,12 @@ class DecodeStream:
             raise ValueError(f"slot {slot} is not occupied")
         self.slots[slot] = None
         return (s.tag, s.request, np.asarray(s.tokens, np.int32))
+
+
+def _tag_id(tag):
+    """The request id of a slot's tag: a scheduler tags each slot with its
+    queued request, whose ``id`` is the result id; None for other tags."""
+    return getattr(tag, "id", None)
 
 
 def _splice_cache(group, solo, slot, cfg):

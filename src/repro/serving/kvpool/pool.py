@@ -34,6 +34,8 @@ from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional
 
+from repro.serving.observe.trace import NULL_TRACER
+
 TRASH_PAGE = 0
 
 
@@ -77,6 +79,7 @@ class PagePool:
         self.reclaimer: Optional[Callable[[int], int]] = None
         self.store = None                # PagedKVStore once bound (attn)
         self.radix = None                # RadixCache (set by bind/attach)
+        self.tracer = NULL_TRACER        # armed by the scheduler
         self._engine = None
 
     # -- core refcounted alloc/free ------------------------------------------

@@ -222,15 +222,22 @@ class RadixCache:
         no holder besides this cache (releasing a stream-shared page would
         not free memory, so such leaves are skipped). Cascades: a parent
         whose last child is evicted becomes a leaf candidate. Returns the
-        number of pages actually freed."""
-        freed = 0
-        while freed < n_pages:
-            cands = [lf for lf in self._leaves()
-                     if self.pool.ref(lf.page) == 1]
-            if not cands:
-                break
-            self._drop(min(cands, key=lambda lf: lf.stamp))
-            freed += 1
+        number of pages actually freed. Traced as one
+        ``serve.pool.reclaim`` scope (pages ``freed``, leaves
+        ``scanned``) on the pool's tracer."""
+        tr = self.pool.tracer
+        with tr.scope("serve.pool.reclaim", "pool"):
+            freed = scanned = 0
+            while freed < n_pages:
+                leaves = self._leaves()
+                scanned += len(leaves)
+                cands = [lf for lf in leaves if self.pool.ref(lf.page) == 1]
+                if not cands:
+                    break
+                self._drop(min(cands, key=lambda lf: lf.stamp))
+                freed += 1
+            if tr.enabled:
+                tr.note(freed=freed, scanned=scanned)
         return freed
 
     def clear(self) -> int:

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.serving.engine import _splice_cache, _StreamSlot
+from repro.serving.engine import _splice_cache, _StreamSlot, _tag_id
 from repro.serving.kvpool.pool import PagePool, PoolExhausted
 from repro.serving.request import ServeRequest
 from repro.serving.observe.trace import NULL_TRACER
@@ -129,12 +129,17 @@ class PagedDecodeStream:
 
     def _first_token(self, h_last) -> int:
         hd = self.head
-        h_in = h_last if hd.is_jittable else np.asarray(h_last)
-        if self.sampled:
-            key, k0 = jax.random.split(self._key)
-            first = hd.sample(k0, h_in, self.temperature, self.top_p)
-        else:
-            first = hd.next(h_in)
+        tr = self.tracer
+        with tr.scope("serve.join.first", "join"):
+            h_in = h_last if hd.is_jittable else np.asarray(h_last)
+            if self.sampled:
+                key, k0 = jax.random.split(self._key)
+                first = hd.sample(k0, h_in, self.temperature, self.top_p)
+            else:
+                first = hd.next(h_in)
+        # the join's one device sync: everything before it only dispatched
+        with tr.scope("serve.join.wait", "join"):
+            first = np.asarray(first)
         # guard before the PRNG key (or any stream state) commits — join's
         # page rollback plus an unconsumed key make the retry bit-identical
         first = int(guard_tokens(self.fault_injector, "join", self.head_name,
@@ -156,8 +161,9 @@ class PagedDecodeStream:
                 f"request needs {Tp + request.max_new} cache slots, stream "
                 f"max_len is {eng.max_len}")
         slot = self._first_free()
-        toks = [int(t) for t in request.prompt]
-        match = self.pool.radix.match(toks)
+        with self.tracer.scope("serve.join.match", "join"):
+            toks = [int(t) for t in request.prompt]
+            match = self.pool.radix.match(toks)
         held: List[int] = []                      # page refs this join owns
         try:
             if self.family == "lstm":
@@ -191,46 +197,52 @@ class PagedDecodeStream:
         """Resume prefill from the deepest cached snapshot; chunk the
         suffix at page boundaries, snapshotting each, so the whole prompt
         inserts as radix nodes. Returns the first token."""
-        eng, pool = self.engine, self.pool
+        eng, pool, tr = self.engine, self.pool, self.tracer
         P, Tp = pool.page_size, len(toks)
         t = match.n_full                      # snapshot exists exactly here
         for pg, _ in match.chain:
             held.append(pool.retain(pg))
-        cache1 = {"lstm": match.payload} if match.payload is not None else \
-            eng.model.init_cache(1, eng.max_len, dtype=eng.cache_dtype)
-        snaps, h_last, i = [], None, t
-        prompt = np.asarray(request.prompt)
-        while i < Tp:
-            n = min(P - (i % P), Tp - i)      # realign to the page grid
-            h, cache1 = eng._jit_resume_prefill(
-                eng.params, {"tokens": jnp.asarray(prompt[None, i:i + n])},
-                cache1)
-            i += n
-            snaps.append((i, cache1["lstm"]))
-            h_last = h[:, -1]
+        with tr.scope("serve.join.prefill", "join"):
+            cache1 = {"lstm": match.payload} if match.payload is not None \
+                else eng.model.init_cache(1, eng.max_len,
+                                          dtype=eng.cache_dtype)
+            snaps, h_last, i = [], None, t
+            prompt = np.asarray(request.prompt)
+            while i < Tp:
+                n = min(P - (i % P), Tp - i)  # realign to the page grid
+                h, cache1 = eng._jit_resume_prefill(
+                    eng.params,
+                    {"tokens": jnp.asarray(prompt[None, i:i + n])}, cache1)
+                i += n
+                snaps.append((i, cache1["lstm"]))
+                h_last = h[:, -1]
+        if tr.enabled:
+            tr.note(cached_tokens=t, chunks=len(snaps))
         if h_last is None:
             # whole prompt cached: the top layer's h AT the last prompt
             # token is the snapshot's own h — no forward pass needed at all
             h_last = cache1["lstm"][-1]["h"]
         first = self._first_token(h_last)
-        solo = cache1 if self._repl is None \
-            else jax.device_put(cache1, self._repl)
-        self.cache = _splice_cache(self.cache, solo, slot, eng.model.cfg)
-        # page chain: a partially-covered grid slot being EXTENDED must go
-        # private now (logical COW — its node's snapshot stops at t, ours
-        # will stop deeper); fresh pages back the remaining grid slots
-        n_prompt = (Tp + P - 1) // P
-        if t < Tp and t % P:
-            # in-place swap: if cow's alloc raises, held[-1] still names the
-            # shared ref so join's rollback releases it — no leak either way
-            held[-1] = pool.cow(held[-1])
-        while len(held) < n_prompt:
-            held.append(pool.alloc())
-        payloads: List[object] = [None] * n_prompt
-        for end, state in snaps:
-            payloads[(end - 1) // P] = state
-        pool.radix.insert(toks, held[:n_prompt], payloads)
-        pool.radix.record(t, Tp)
+        with tr.scope("serve.join.splice", "join"):
+            solo = cache1 if self._repl is None \
+                else jax.device_put(cache1, self._repl)
+            self.cache = _splice_cache(self.cache, solo, slot,
+                                       eng.model.cfg)
+            # page chain: a partially-covered grid slot being EXTENDED must
+            # go private now (logical COW — its node's snapshot stops at t,
+            # ours will stop deeper); fresh pages back the remaining slots
+            n_prompt = (Tp + P - 1) // P
+            if t < Tp and t % P:
+                # in-place swap: if cow's alloc raises, held[-1] still names
+                # the shared ref so join's rollback releases it — no leak
+                held[-1] = pool.cow(held[-1])
+            while len(held) < n_prompt:
+                held.append(pool.alloc())
+            payloads: List[object] = [None] * n_prompt
+            for end, state in snaps:
+                payloads[(end - 1) // P] = state
+            pool.radix.insert(toks, held[:n_prompt], payloads)
+            pool.radix.record(t, Tp)
         return first
 
     def _join_attn(self, request, toks, match, held) -> int:
@@ -253,20 +265,28 @@ class PagedDecodeStream:
             # for join's rollback)
             held.append(pool.retain(match.chain[-1][0]))
             held[-1] = pool.cow(held[-1])
-        cache1 = eng.model.init_cache(1, eng.max_len, dtype=eng.cache_dtype)
-        h, cache1 = eng._jit_prefill(
-            eng.params, {"tokens": jnp.asarray(np.asarray(request.prompt)[None])},
-            cache1)
-        first = self._first_token(h[:, -1])
-        while len(held) < n_prompt:
-            held.append(pool.alloc())
-        if self._repl is not None:
-            cache1 = jax.device_put(cache1, self._repl)
-        if j0 < n_prompt:
-            pool.store.write_prompt(held[:n_prompt], cache1["attn"],
-                                    first_page=j0)
-        pool.radix.insert(toks, held[:n_prompt])
-        pool.radix.record(j0 * P, Tp)
+        tr = self.tracer
+        with tr.scope("serve.join.prefill", "join"):
+            cache1 = eng.model.init_cache(1, eng.max_len,
+                                          dtype=eng.cache_dtype)
+            h, cache1 = eng._jit_prefill(
+                eng.params,
+                {"tokens": jnp.asarray(np.asarray(request.prompt)[None])},
+                cache1)
+            h_last = h[:, -1]
+        if tr.enabled:
+            tr.note(cached_tokens=j0 * P, chunks=1)
+        first = self._first_token(h_last)
+        with tr.scope("serve.join.splice", "join"):
+            while len(held) < n_prompt:
+                held.append(pool.alloc())
+            if self._repl is not None:
+                cache1 = jax.device_put(cache1, self._repl)
+            if j0 < n_prompt:
+                pool.store.write_prompt(held[:n_prompt], cache1["attn"],
+                                        first_page=j0)
+            pool.radix.insert(toks, held[:n_prompt])
+            pool.radix.record(j0 * P, Tp)
         return first
 
     # -- step -----------------------------------------------------------------
@@ -292,69 +312,72 @@ class PagedDecodeStream:
         May raise ``PoolExhausted`` BEFORE any state advances — the
         scheduler frees pages (cache eviction / preemption) and re-ticks."""
         idx = [i for i, s in enumerate(self.slots) if s is not None]
-        if idx:
-            self._ensure_pages(idx)
-        out = self._finished
-        self._finished = []
         if not idx:
-            return out
-        eng = self.engine
-        tok = jnp.asarray(self.tok)
-        pos = jnp.asarray(self.pos)
-        # compute into locals and commit (cache / pool tensors, PRNG) only
-        # after the guard — a step fault advances nothing, so a retry
-        # re-runs the identical step (pages grown by _ensure_pages stay in
-        # their chains and are simply reused, same as the PoolExhausted
-        # retry contract)
+            return self.pop_finished()
         tr = self.tracer
-        k_t0 = tr.now() if tr.enabled else 0.0
-        key = cache = new_k = new_v = store = None
-        if self.family == "lstm":
-            # the SAME cached dense step DecodeStream uses — the paged LSTM
-            # path adds zero step executables by construction
-            if self.sampled:
-                fn = eng._sample_step(self.head, self.temperature, self.top_p)
-                key, ki = jax.random.split(self._key)
-                nxt, _, cache = fn(eng.params, ki, tok, self.cache, pos)
+        with tr.scope("serve.step", "step"):
+            if tr.enabled:
+                tr.note(head=self.head_name, active=len(idx),
+                        rids=[_tag_id(self.slots[i].tag) for i in idx])
+            with tr.scope("serve.step.pages", "step"):
+                self._ensure_pages(idx)
+            out = self._finished
+            self._finished = []
+            eng = self.engine
+            tok = jnp.asarray(self.tok)
+            pos = jnp.asarray(self.pos)
+            # compute into locals and commit (cache / pool tensors, PRNG)
+            # only after the guard — a step fault advances nothing, so a
+            # retry re-runs the identical step (pages grown by _ensure_pages
+            # stay in their chains and are simply reused, same as the
+            # PoolExhausted retry contract)
+            key = cache = new_k = new_v = store = None
+            if self.family == "lstm":
+                # the SAME cached dense step DecodeStream uses — the paged
+                # LSTM path adds zero step executables by construction
+                if self.sampled:
+                    fn = eng._sample_step(self.head, self.temperature,
+                                          self.top_p)
+                    key, ki = jax.random.split(self._key)
+                    nxt, _, cache = fn(eng.params, ki, tok, self.cache, pos)
+                else:
+                    fn = eng._greedy_step(self.head)
+                    nxt, _, cache = fn(eng.params, tok, self.cache, pos)
             else:
-                fn = eng._greedy_step(self.head)
-                nxt, _, cache = fn(eng.params, tok, self.cache, pos)
-        else:
-            store = self.pool.store
-            table = jnp.asarray(self.table)
+                store = self.pool.store
+                table = jnp.asarray(self.table)
+                if self.sampled:
+                    fn = eng._paged_sample_step(self.head, self.temperature,
+                                                self.top_p)
+                    key, ki = jax.random.split(self._key)
+                    nxt, _, new_k, new_v = fn(eng.params, ki, tok, store.k,
+                                              store.v, table, pos)
+                else:
+                    fn = eng._paged_greedy_step(self.head)
+                    nxt, _, new_k, new_v = fn(eng.params, tok, store.k,
+                                              store.v, table, pos)
+            with tr.scope("serve.step.wait", "step"):
+                nxt = np.asarray(nxt)
+            nxt = guard_tokens(self.fault_injector, "step", self.head_name,
+                               nxt, self.vocab, rows=idx)
             if self.sampled:
-                fn = eng._paged_sample_step(self.head, self.temperature,
-                                            self.top_p)
-                key, ki = jax.random.split(self._key)
-                nxt, _, new_k, new_v = fn(eng.params, ki, tok, store.k,
-                                          store.v, table, pos)
+                self._key = key
+            if self.family == "lstm":
+                self.cache = cache
             else:
-                fn = eng._paged_greedy_step(self.head)
-                nxt, _, new_k, new_v = fn(eng.params, tok, store.k,
-                                          store.v, table, pos)
-        nxt = guard_tokens(self.fault_injector, "step", self.head_name,
-                           nxt, self.vocab, rows=idx)
-        if tr.enabled:
-            tr.span("kernel.step", "kernel", k_t0,
-                    args={"head": self.head_name, "active": len(idx),
-                          "paged": True})
-        if self.sampled:
-            self._key = key
-        if self.family == "lstm":
-            self.cache = cache
-        else:
-            store.k, store.v = new_k, new_v
-        for i in idx:
-            s = self.slots[i]
-            t = int(nxt[i])
-            s.tokens.append(t)
-            s.remaining -= 1
-            self.tok[i] = t
-            self.pos[i] += 1
-            if s.remaining == 0:
-                out.append((s.tag, s.request, np.asarray(s.tokens, np.int32)))
-                self.slots[i] = None
-                self._release_chain(i)
+                store.k, store.v = new_k, new_v
+            for i in idx:
+                s = self.slots[i]
+                t = int(nxt[i])
+                s.tokens.append(t)
+                s.remaining -= 1
+                self.tok[i] = t
+                self.pos[i] += 1
+                if s.remaining == 0:
+                    out.append((s.tag, s.request,
+                                np.asarray(s.tokens, np.int32)))
+                    self.slots[i] = None
+                    self._release_chain(i)
         return out
 
     def pop_finished(self) -> List[tuple]:

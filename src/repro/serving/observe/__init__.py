@@ -1,19 +1,16 @@
-"""Serving observability: span tracing, typed metrics, cost-drift audit.
+"""Serving observability: span tracing and typed metrics.
 
-Three host-side instruments threaded through the serving stack (none may
+Two host-side instruments threaded through the serving stack (none may
 introduce recompiles — the traced CI smoke asserts zero):
 
-* ``Tracer`` / ``NullTracer`` — per-request span timeline on the
-  scheduler's injectable clock, exportable as Chrome trace-event JSON
+* ``Tracer`` / ``NullTracer`` — nested ``serve.*`` scopes and per-request
+  spans on the scheduler's injectable clock, mirrored into the JAX
+  profiler's trace while enabled, exportable as Chrome trace-event JSON
   (``chrome://tracing`` / Perfetto) or JSONL.
 * ``MetricsRegistry`` with ``Counter`` / ``Gauge`` / ``Histogram`` —
   Prometheus-style text exposition + JSON snapshot; ``ServerStats``
   mirrors its funnel/pool/spec/resilience counters into one.
-* ``audit_cost_drift`` — cataloged ``flops_per_query`` /
-  ``bytes_per_query`` vs HLO-measured + wall-clock reality, the
-  ``cost_drift`` section of ``BENCH_serving.json``.
 """
-from repro.serving.observe.drift import audit_cost_drift
 from repro.serving.observe.metrics import (Counter, Gauge, Histogram,
                                            MetricsRegistry)
 from repro.serving.observe.trace import (NULL_TRACER, SCHED_TID, NullTracer,
@@ -22,5 +19,4 @@ from repro.serving.observe.trace import (NULL_TRACER, SCHED_TID, NullTracer,
 __all__ = [
     "Tracer", "NullTracer", "NULL_TRACER", "SCHED_TID",
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "audit_cost_drift",
 ]

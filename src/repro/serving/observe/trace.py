@@ -1,25 +1,36 @@
 """Span tracing for the serving stack.
 
 A ``Tracer`` records per-request spans (submit -> admit/queue/join ->
-decode ticks -> retire), scheduler-tick spans and kernel-dispatch spans
-into a bounded ring buffer, exportable as Chrome trace-event JSON
-(loadable in ``chrome://tracing`` / Perfetto) or as JSONL.
+decode ticks -> retire), nested ``serve.*`` scopes at every layer
+boundary of the serving program (tick, join, stream step, pool reclaim)
+and instants into a bounded ring buffer, exportable as Chrome trace-event
+JSON (loadable in ``chrome://tracing`` / Perfetto) or as JSONL.
 
 Design constraints, in order:
 
 * **Zero recompiles.** Everything here is host-side Python; nothing the
-  tracer does may feed a traced value into jit. Kernel spans time the
-  host-side dispatch+guard window around the already-compiled step call.
+  tracer does may feed a traced value into jit.
 * **One timeline.** The tracer reads the scheduler's injectable clock
   (``LogicalClock`` under chaos tests, ``time.perf_counter`` in real
   runs), so spans, deadlines and watchdog decisions share an axis.
+* **The profiler's timeline too.** While the tracer is enabled, each
+  ``scope`` also enters a ``jax.profiler.TraceAnnotation`` of the same
+  name, so a profiler trace taken at the same time shows the program's
+  host spans beside the device's ops, on the profiler's own clock: the
+  device's idle gaps can be put down to the host code running in them.
 * **Cheap when off.** ``NullTracer`` no-ops every method and advertises
   ``enabled = False`` so hot paths can skip argument construction with
-  ``if tracer.enabled:``. The module-level ``NULL_TRACER`` singleton is
-  the default everywhere a tracer is threaded through.
+  ``if tracer.enabled:``; its ``scope`` hands back one preallocated
+  no-op context. The module-level ``NULL_TRACER`` singleton is the
+  default everywhere a tracer is threaded through.
+
+Scopes nest: each records one "X" span whose ``args`` carry its ``id``
+and the ``parent`` id of the innermost scope open when it began (None at
+the top), so a span's self time is its duration less its children's.
+The tracer is single-threaded, like the scheduler that drives it.
 
 Chrome trace-event mapping: request rows use ``tid = rid`` so every
-request gets its own lane under one process; the scheduler's tick spans
+request gets its own lane under one process; the scheduler's scopes
 live on ``tid = SCHED_TID`` (-1 — request ids start at 0, so the
 scheduler lane must sit outside the rid space). Durations/timestamps
 are exported in microseconds as the format requires.
@@ -36,22 +47,88 @@ from typing import Callable, Dict, List, Optional
 SCHED_TID = -1
 
 
+def _profiler_annotation(name: str):
+    import jax.profiler
+    return jax.profiler.TraceAnnotation(name)
+
+
+class _Scope:
+    """One ``Tracer.scope``: records its span on exit (see the module
+    docstring). ``args`` may grow while it is open (``Tracer.note``)."""
+
+    __slots__ = ("_tr", "name", "cat", "tid", "args", "id", "parent",
+                 "_t0", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str, cat: str,
+                 tid: Optional[int], args: dict):
+        self._tr = tracer
+        self.name, self.cat, self.tid, self.args = name, cat, tid, args
+
+    def __enter__(self) -> "_Scope":
+        tr = self._tr
+        outer = tr._open[-1] if tr._open else None
+        self.parent = None if outer is None else outer.id
+        if self.tid is None:
+            self.tid = SCHED_TID if outer is None else outer.tid
+        tr._scopes += 1
+        self.id = tr._scopes
+        tr._open.append(self)
+        self._ann = tr._annotate(self.name)
+        self._ann.__enter__()
+        self._t0 = tr.now()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        tr = self._tr
+        t1 = tr.now()
+        self._ann.__exit__(exc_type, exc, tb)
+        tr._open.pop()
+        self.args["id"] = self.id
+        self.args["parent"] = self.parent
+        if exc_type is not None:
+            self.args["error"] = exc_type.__name__
+        tr.span(self.name, self.cat, self._t0, t1, tid=self.tid,
+                args=self.args)
+        return False
+
+
+class _NullScope:
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullScope":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        return False
+
+
+_NULL_SCOPE = _NullScope()
+
+
 class Tracer:
     """Bounded ring buffer of trace events on an injectable clock.
 
     Events are stored as small dicts in trace-event shape (seconds
     internally; scaled to microseconds at export). When the buffer
     overflows, the oldest events are dropped — ``dropped`` reports how
-    many, so exports can say so instead of silently truncating."""
+    many, so exports can say so instead of silently truncating.
+
+    ``annotate(name)`` returns the context each scope also enters: by
+    default a ``jax.profiler.TraceAnnotation``, which costs about a
+    microsecond and records only while a profiler trace is running."""
 
     enabled = True
 
     def __init__(self, clock: Optional[Callable[[], float]] = None,
-                 capacity: int = 65536):
+                 capacity: int = 65536,
+                 annotate: Callable[[str], object] = _profiler_annotation):
         self._clock = clock if clock is not None else time.perf_counter
         self._buf: deque = deque(maxlen=int(capacity))
         self.capacity = int(capacity)
         self.emitted = 0
+        self._annotate = annotate
+        self._open: List[_Scope] = []      # scopes entered, not yet exited
+        self._scopes = 0                   # ids handed out so far
 
     # -- recording -----------------------------------------------------------
     def now(self) -> float:
@@ -81,6 +158,21 @@ class Tracer:
             ev["args"] = dict(args)
         self._buf.append(ev)
         self.emitted += 1
+
+    def scope(self, name: str, cat: str, tid: Optional[int] = None,
+              **args) -> _Scope:
+        """Context manager: one "X" span from entry to exit, child of the
+        innermost scope open at entry, on lane ``tid`` (default: the
+        enclosing scope's lane, else ``SCHED_TID``). A scope left by an
+        exception still records, with the exception's type as
+        ``args["error"]``."""
+        return _Scope(self, name, cat, tid, args)
+
+    def note(self, **args) -> None:
+        """Add ``args`` to the innermost open scope (for values known only
+        once its work is under way: a radix hit, a chunk count)."""
+        if self._open:
+            self._open[-1].args.update(args)
 
     # -- inspection / export -------------------------------------------------
     @property
@@ -162,6 +254,13 @@ class NullTracer:
         pass
 
     def instant(self, *a, **k) -> None:
+        pass
+
+    def scope(self, name: str, cat: str, tid: Optional[int] = None,
+              **args) -> _NullScope:
+        return _NULL_SCOPE
+
+    def note(self, **args) -> None:
         pass
 
     def events(self) -> List[dict]:

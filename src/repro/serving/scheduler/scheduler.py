@@ -122,11 +122,14 @@ class ContinuousScheduler:
                     re-routing (exponential tick-backoff, capped at 8).
     ``tracer``      optional ``observe.Tracer``: per-request span timeline
                     (submit → admit/queue/join → decode → retire, plus
-                    every fault/retry/fallback instant), scheduler-tick
-                    spans and the streams' kernel-dispatch spans. Give it
-                    the SAME clock as the scheduler so the timeline and
-                    the deadline machinery share an axis. ``None`` keeps
-                    the hot path on the allocation-free ``NULL_TRACER``.
+                    every fault/retry/fallback instant) and the nested
+                    ``serve.*`` scopes — ``serve.submit``, ``serve.tick``
+                    and inside it each ``serve.join``, the streams'
+                    ``serve.step``s, the pool's ``serve.pool.reclaim`` and
+                    ``serve.tick.observe``. Give it the SAME clock as the
+                    scheduler so the timeline and the deadline machinery
+                    share an axis. ``None`` keeps the hot path on the
+                    allocation-free ``NULL_TRACER``.
     """
 
     def __init__(self, engine: DecodeEngine, policy=None,
@@ -167,6 +170,8 @@ class ContinuousScheduler:
         self._retry_at: Dict[tuple, int] = {}   # stream sig -> resume tick
         self._fail_count: Dict[tuple, int] = {}  # sig -> consecutive faults
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        if kv_pool is not None:
+            kv_pool.tracer = self.tracer
         self._trace_t0: Dict[int, float] = {}   # rid -> submit stamp
         if breaker is not None:
             # chain the breaker's transition hook through ServerStats so
@@ -297,6 +302,10 @@ class ContinuousScheduler:
     def submit(self, request: ServeRequest) -> int:
         """Admit-or-refuse one request. Returns its result id; rejected
         requests get their typed ``AdmissionRejected`` immediately."""
+        with self.tracer.scope("serve.submit", "request"):
+            return self._submit(request)
+
+    def _submit(self, request: ServeRequest) -> int:
         Tp = int(request.prompt.shape[0])
         if Tp + request.max_new > self.engine.max_len:
             raise ValueError(
@@ -340,10 +349,11 @@ class ContinuousScheduler:
             if draft is not None:
                 draft_len = self.spec.draft_len_for(request,
                                                     self.engine.max_len)
-        load = self._load()
-        if self.kv_pool is not None:
-            load.request_pages = self._marginal_pages(
-                request, draft_slack=draft_len - 1 if draft else 0)
+        with tr.scope("serve.submit.load", "admission"):
+            load = self._load()
+            if self.kv_pool is not None:
+                load.request_pages = self._marginal_pages(
+                    request, draft_slack=draft_len - 1 if draft else 0)
         if draft is not None:
             load.request_extra_flops = head_flops(catalog, draft)
         decision = self.admission.admit(request, name, catalog, load)
@@ -581,11 +591,15 @@ class ContinuousScheduler:
         """One scheduler tick. Returns the number of requests that reached
         a terminal state (completed, preempted, faulted or timed out) this
         tick."""
+        with self.tracer.scope("serve.tick", "scheduler"):
+            return self._tick()
+
+    def _tick(self) -> int:
         self.stats.ticks += 1
         terminal = 0
+        joins = steps = 0
         pool_blocked = False    # a PoolExhausted fired somewhere this tick
         tr = self.tracer
-        tick_t0 = tr.now() if tr.enabled else 0.0
         # 0. injected tick delays (chaos): advances the shared logical
         #    clock, so deadline/timeout machinery feels the lost time
         if self.fault_injector is not None:
@@ -632,7 +646,10 @@ class ContinuousScheduler:
                 continue
             t0 = time.perf_counter()
             try:
-                stream.join(qr.request, tag=qr)
+                with tr.scope("serve.join", "join", tid=qr.id):
+                    if tr.enabled:
+                        tr.note(prompt_len=int(qr.request.prompt.shape[0]))
+                    stream.join(qr.request, tag=qr)
             except HeadFault as e:
                 # the guard fired BEFORE any stream state mutated (pages
                 # rolled back, PRNG unconsumed), so the request simply
@@ -682,6 +699,7 @@ class ContinuousScheduler:
                                          head=stream.head_name)
                 continue
             dt = time.perf_counter() - t0
+            joins += 1
             self.queue.remove(qr)
             self._retry_at.pop(sig, None)
             now = self.clock()
@@ -712,6 +730,7 @@ class ContinuousScheduler:
                            for h in self._stream_heads(stream))
             if stream.n_active and not skip:
                 n_tok = stream.n_active
+                steps += 1
                 t0 = time.perf_counter()
                 try:
                     finished = stream.step()
@@ -908,15 +927,15 @@ class ContinuousScheduler:
                 self.stats.record_timeout()
                 terminal += 1
                 self._trace_terminal(qr.id, "timed_out", head=qr.head)
-        if self.kv_pool is not None:
-            self.stats.observe_pool(self.kv_pool.telemetry(),
-                                    stalled=pool_blocked)
-        self.stats.observe_queue(len(self.queue))
+        with tr.scope("serve.tick.observe", "scheduler"):
+            if self.kv_pool is not None:
+                self.stats.observe_pool(self.kv_pool.telemetry(),
+                                        stalled=pool_blocked)
+            self.stats.observe_queue(len(self.queue))
         if tr.enabled:
-            tr.span("tick", "scheduler", tick_t0,
-                    args={"tick": self.stats.ticks, "terminal": terminal,
-                          "queued": len(self.queue),
-                          "inflight": len(self._inflight)})
+            tr.note(tick=self.stats.ticks, joins=joins, steps=steps,
+                    terminal=terminal, queued=len(self.queue),
+                    inflight=len(self._inflight))
         return terminal
 
     def _find_slot(self, rid: int):

@@ -1,15 +1,16 @@
 """Observability layer: Tracer ring buffer + Chrome trace-event export,
-NullTracer no-op contract, typed Counter/Gauge/Histogram + registry
-(monotonic mirroring, label validation, Prometheus text exposition),
-ServerStats -> registry mirroring, defensive snapshot copies, request
-spans submit->retire through the scheduler (plus retry/fallback instants
-under injected faults, with zero recompiles while traced), schema-stamped
-bench JSON with loud old-schema upgrades, compiled_step_counts under
-paged / speculative / resilience step kinds, and the HLO cost-drift
-audit."""
+nested scopes (ids, parents, the profiler mirror), NullTracer no-op
+contract, typed Counter/Gauge/Histogram + registry (monotonic mirroring,
+label validation, Prometheus text exposition), ServerStats -> registry
+mirroring, defensive snapshot copies, request spans submit->retire through
+the scheduler (plus retry/fallback instants under injected faults, with
+zero recompiles while traced), the ``serve.*`` span tree of a scheduler
+run, schema-stamped bench JSON with loud old-schema upgrades, and
+compiled_step_counts under paged / speculative / resilience step kinds."""
+import contextlib
 import json
-import math
-import os
+import time
+import tracemalloc
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +27,7 @@ from repro.serving import (NULL_TRACER, CircuitBreaker, ContinuousScheduler,
                            DecodeEngine, FaultInjector, LogicalClock,
                            MetricsRegistry, NullTracer, PagePool,
                            ServeRequest, ServeResult, StaticPolicy,
-                           TierPolicy, Tracer, audit_cost_drift)
+                           TierPolicy, Tracer)
 from repro.serving.observe.trace import SCHED_TID
 from repro.serving.scheduler import ServerStats
 
@@ -86,6 +87,119 @@ def test_tracer_chrome_trace_shape_and_export(tmp_path):
     with open(pl) as f:
         lines = [json.loads(ln) for ln in f]
     assert len(lines) == 4 and all("pid" in ln for ln in lines)
+
+
+def _no_annotation(name):
+    return contextlib.nullcontext()
+
+
+def test_tracer_scope_nesting_ids_and_parents():
+    """Each scope records one "X" span carrying its id and the id of the
+    innermost scope open when it began; lanes default to the parent's;
+    ``note`` fills the innermost open scope; a scope left by an exception
+    still records and unwinds."""
+    tr = Tracer(clock=FakeClock(dt=1.0), annotate=_no_annotation)
+    with tr.scope("a", "t"):
+        with tr.scope("b", "t", tid=7, k=1):
+            tr.note(extra=2)
+        with tr.scope("c", "t"):
+            with tr.scope("d", "t"):
+                pass
+    with tr.scope("e", "t"):
+        pass
+    ev = {e["name"]: e for e in tr.events()}
+    assert all(e["ph"] == "X" for e in ev.values())
+    ids = {n: e["args"]["id"] for n, e in ev.items()}
+    assert len(set(ids.values())) == 5
+    parent = {n: e["args"]["parent"] for n, e in ev.items()}
+    assert parent == {"a": None, "b": ids["a"], "c": ids["a"],
+                      "d": ids["c"], "e": None}
+    assert ev["b"]["tid"] == 7 and ev["b"]["args"]["k"] == 1 \
+        and ev["b"]["args"]["extra"] == 2
+    assert ev["a"]["tid"] == ev["c"]["tid"] == ev["d"]["tid"] == SCHED_TID
+    # a parent's interval covers its children's on the tracer's clock
+    end = {n: e["ts"] + e["dur"] for n, e in ev.items()}
+    assert ev["a"]["ts"] < ev["b"]["ts"] and end["d"] < end["c"] < end["a"]
+    with pytest.raises(ValueError):
+        with tr.scope("f", "t"):
+            raise ValueError("boom")
+    with tr.scope("g", "t"):
+        pass
+    ev = {e["name"]: e for e in tr.events()}
+    assert ev["f"]["args"]["error"] == "ValueError"
+    assert ev["g"]["args"]["parent"] is None        # the stack unwound
+    out = next(e for e in tr.chrome_trace()["traceEvents"]
+               if e.get("name") == "d")
+    assert out["args"]["parent"] == ids["c"] and out["dur"] == \
+        pytest.approx(1e6)
+
+
+def test_scope_mirrors_into_profiler_annotations():
+    """While enabled, every scope enters and leaves exactly one profiler
+    annotation of its own name, nested as the scopes are; plain spans and
+    instants do not annotate."""
+    log = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    tr = Tracer(clock=FakeClock(), annotate=Ann)
+    with tr.scope("serve.tick", "scheduler"):
+        with tr.scope("serve.join", "join", tid=3):
+            tr.instant("join", "queue", tid=3)
+    tr.span("queue.wait", "queue", 0.0)
+    assert log == [("enter", "serve.tick"), ("enter", "serve.join"),
+                   ("exit", "serve.join"), ("exit", "serve.tick")]
+
+
+def test_scope_lands_in_a_profiler_trace(tmp_path):
+    """The default mirror is ``jax.profiler.TraceAnnotation``: a profiler
+    trace taken meanwhile holds each scope as a host event of its name."""
+    from jax.profiler import ProfileData
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.scope("serve.tick", "scheduler"):
+            with tr.scope("serve.step", "step"):
+                jnp.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    names = {ev.name for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for ev in line.events}
+    assert {"serve.tick", "serve.step"} <= names
+
+
+def test_null_tracer_scope_allocates_and_records_nothing():
+    """Tracing off: ``scope`` hands back one preallocated no-op context,
+    and ten thousand of them allocate nothing that outlives them."""
+    s = NULL_TRACER.scope("serve.tick", "scheduler")
+    assert s is NULL_TRACER.scope("serve.join", "join", tid=3)
+
+    def run(n):
+        for _ in range(n):
+            with NULL_TRACER.scope("serve.tick", "scheduler"):
+                with NULL_TRACER.scope("serve.join", "join", tid=3):
+                    pass
+
+    run(10)
+    tracemalloc.start()
+    try:
+        before = tracemalloc.get_traced_memory()[0]
+        tracemalloc.reset_peak()
+        run(10_000)
+        now, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert now == before and peak - before < 1024
+    assert NULL_TRACER.events() == [] and NULL_TRACER.emitted == 0
 
 
 def test_null_tracer_is_inert_but_exports_empty(tmp_path):
@@ -309,10 +423,10 @@ def test_scheduler_traces_request_lifecycle(trained):
     assert {e["tid"] for e in _by_name(tr, "admit")} == per_req
     assert {e["tid"] for e in _by_name(tr, "join")} == per_req
     assert {e["tid"] for e in _by_name(tr, "queue.wait")} == per_req
-    ticks = _by_name(tr, "tick")
+    ticks = _by_name(tr, "serve.tick")
     assert ticks and all(e["tid"] == SCHED_TID for e in ticks)
     assert ticks[-1]["args"]["tick"] == sched.stats.ticks
-    kern = _by_name(tr, "kernel.step")
+    kern = _by_name(tr, "serve.step")
     assert kern and {e["args"]["head"] for e in kern} == {"screened", "exact"}
     # the request span COVERS its kernel work on the shared timeline
     t0 = min(s["ts"] for s in spans)
@@ -320,6 +434,163 @@ def test_scheduler_traces_request_lifecycle(trained):
     # live-source gauges flow through the same registry
     snap = sched.stats.metrics.snapshot()
     assert snap["serve_requests_total"]["values"]["event=completed"] == 4
+
+
+JOIN_KIDS = ["serve.join.first", "serve.join.match", "serve.join.prefill",
+             "serve.join.splice", "serve.join.wait"]
+
+
+def _kids(tr):
+    out = {}
+    for e in tr.events():
+        if e["ph"] == "X" and e.get("args", {}).get("parent") is not None:
+            out.setdefault(e["args"]["parent"], []).append(e)
+    return out
+
+
+def _paged_run(trained, tracer=None, pool_pages=64, n=6, eng=None):
+    """Greedy requests through a paged scheduler (the served path), some
+    sharing a two-page prefix so the radix cache hits."""
+    corpus = trained[3]
+    eng = eng if eng is not None else _engine(trained)
+    base = corpus.sample_batch(n, 10, seed=61)
+    shared = base[0][:8]
+    reqs = [ServeRequest(prompt=np.concatenate([shared, p[:2 + i % 3]])
+                         if i % 2 else p[:5 + i], max_new=3 + i % 3)
+            for i, p in enumerate(base)]
+    sched = ContinuousScheduler(eng, policy=StaticPolicy("screened"),
+                                max_slots=2, kv_pool=PagePool(pool_pages, 4),
+                                tracer=tracer)
+    return eng, sched, sched.serve(reqs)
+
+
+def test_scheduler_span_tree_paged(trained):
+    """On the paged LSTM path every ``serve.join`` sits in a ``serve.tick``
+    and has exactly one each of match, prefill, first-token head call,
+    first-token wait and splice; every ``serve.step`` has its page growth
+    and its wait; ticks count their joins and steps; submissions carry the
+    admission load as a child."""
+    tr = Tracer(clock=FakeClock(dt=1e-4))
+    _, sched, out = _paged_run(trained, tr)
+    assert all(isinstance(r, ServeResult) for r in out)
+    kids = _kids(tr)
+    by_id = {e["args"]["id"]: e for e in tr.events()
+             if e["ph"] == "X" and "id" in e.get("args", {})}
+    joins = _by_name(tr, "serve.join")
+    assert len(joins) == len(out)
+    for j in joins:
+        assert sorted(c["name"] for c in kids[j["args"]["id"]]) == JOIN_KIDS
+        assert by_id[j["args"]["parent"]]["name"] == "serve.tick"
+        assert j["tid"] >= 0                    # the request's own lane
+        a = j["args"]
+        assert 0 <= a["cached_tokens"] < a["prompt_len"] and a["chunks"] >= 1
+    assert any(j["args"]["cached_tokens"] > 0 for j in joins)
+    assert {j["tid"] for j in joins} == {e["tid"] for e in
+                                         _by_name(tr, "join")}
+    steps = _by_name(tr, "serve.step")
+    assert steps
+    for st in steps:
+        assert sorted(c["name"] for c in kids[st["args"]["id"]]) == [
+            "serve.step.pages", "serve.step.wait"]
+        assert by_id[st["args"]["parent"]]["name"] == "serve.tick"
+        assert st["args"]["active"] == len(st["args"]["rids"]) >= 1
+    ticks = _by_name(tr, "serve.tick")
+    assert len(ticks) == sched.stats.ticks
+    for t in ticks:
+        assert t["args"]["parent"] is None
+        names = [c["name"] for c in kids.get(t["args"]["id"], [])]
+        assert names.count("serve.join") == t["args"]["joins"]
+        assert names.count("serve.step") == t["args"]["steps"]
+        assert names.count("serve.tick.observe") == 1
+    subs = _by_name(tr, "serve.submit")
+    assert len(subs) == len(out)
+    for s in subs:
+        assert [c["name"] for c in kids[s["args"]["id"]]] == [
+            "serve.submit.load"]
+
+
+def test_pool_reclaim_span_nests_under_its_allocation(trained):
+    """A pool small enough that the radix cache must give pages back:
+    each ``serve.pool.reclaim`` nests under the join splice or step page
+    growth that allocated, with the pages freed and leaves scanned."""
+    tr = Tracer(clock=FakeClock(dt=1e-4))
+    _, sched, out = _paged_run(trained, tr, pool_pages=12, n=8)
+    assert all(isinstance(r, ServeResult) for r in out)
+    rec = _by_name(tr, "serve.pool.reclaim")
+    assert rec
+    by_id = {e["args"]["id"]: e for e in tr.events()
+             if e["ph"] == "X" and "id" in e.get("args", {})}
+    for r in rec:
+        assert by_id[r["args"]["parent"]]["name"] in (
+            "serve.join.splice", "serve.step.pages")
+        assert r["args"]["scanned"] >= r["args"]["freed"] >= 0
+    assert sum(r["args"]["freed"] for r in rec) == \
+        sched.kv_pool.radix.evictions
+
+
+def test_decode_stream_span_tree(trained):
+    """The unpaged ``DecodeStream`` has the same tree minus what it has no
+    pool for: no radix match, no page growth."""
+    cfg, _, _, corpus, _ = trained
+    eng = _engine(trained)
+    tr = Tracer(clock=FakeClock(dt=1e-4))
+    reqs = [ServeRequest(prompt=p, max_new=3)
+            for p in corpus.sample_batch(3, 6, seed=67)]
+    out = ContinuousScheduler(eng, policy=StaticPolicy("screened"),
+                              max_slots=2, tracer=tr).serve(reqs)
+    assert all(isinstance(r, ServeResult) for r in out)
+    kids = _kids(tr)
+    joins = _by_name(tr, "serve.join")
+    assert len(joins) == len(reqs)
+    for j in joins:
+        assert sorted(c["name"] for c in kids[j["args"]["id"]]) == [
+            n for n in JOIN_KIDS if n != "serve.join.match"]
+        assert j["args"]["cached_tokens"] == 0 and j["args"]["chunks"] == 1
+    for st in _by_name(tr, "serve.step"):
+        assert [c["name"] for c in kids[st["args"]["id"]]] == [
+            "serve.step.wait"]
+
+
+def test_join_instant_unchanged(trained):
+    """The ``join`` instant keeps its shape and place: on the request's
+    lane, stamped after its ``serve.join`` scope closes, with ``join_s``
+    timing the whole join around that scope."""
+    tr = Tracer(clock=time.perf_counter)
+    _paged_run(trained, tr)
+    spans = {e["tid"]: e for e in _by_name(tr, "serve.join")}
+    instants = _by_name(tr, "join")
+    assert instants and len(instants) == len(spans)
+    for ev in instants:
+        assert set(ev) == {"name", "cat", "ph", "s", "ts", "tid", "args"}
+        assert (ev["cat"], ev["ph"], ev["s"]) == ("queue", "i", "t")
+        assert set(ev["args"]) == {"head", "join_s"}
+        sp = spans[ev["tid"]]
+        assert ev["ts"] >= sp["ts"] + sp["dur"]
+        assert ev["args"]["join_s"] >= sp["dur"]
+
+
+def test_tracing_compiles_nothing_and_keeps_tokens(trained):
+    """With the tracer on, a warm paged run compiles nothing (steps or
+    eager ops) and emits the same greedy tokens as with it off."""
+    eng, _, plain = _paged_run(trained)
+    compiles = []
+
+    def on_duration(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    counts0 = eng.compiled_step_counts()
+    tr = Tracer(clock=time.perf_counter)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        _, _, traced = _paged_run(trained, tr, eng=eng)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert _by_name(tr, "serve.step")
+    assert compiles == []
+    assert eng.compiled_step_counts() == counts0
+    for a, b in zip(plain, traced):
+        np.testing.assert_array_equal(a.tokens, b.tokens)
 
 
 def test_scheduler_traces_reject_and_fault_paths(trained):
@@ -434,25 +705,3 @@ def test_compiled_step_counts_flat_under_retries(trained):
     assert isinstance(out[0], ServeResult)
     assert sched.stats.retries == 2
     assert eng.compiled_step_counts() == counts0
-
-
-# -- cost-drift audit ---------------------------------------------------------
-
-def test_audit_cost_drift_measures_exact_head(trained):
-    """The drift audit compares cataloged flops/bytes against compiled-HLO
-    measurements for jittable single-mesh heads: predicted and measured
-    are both positive, the ratio is finite, wall-clock is real, and
-    unknown head names are skipped rather than fatal."""
-    eng = _engine(trained)
-    drift = audit_cost_drift(eng, ("exact", "no-such-head"),
-                             iters=5, warmup=1)
-    assert set(drift) == {"exact"}                  # unknown name skipped
-    d = drift["exact"]
-    assert d["predicted"]["flops_per_query"] > 0
-    assert d["measured"]["hlo_flops"] > 0
-    assert d["measured"]["wall_s_per_query"] > 0
-    r = d["ratio"]["flops"]
-    assert r is not None and math.isfinite(r) and r > 0
-    # the exact head is a plain matmul: HLO flops within 100x of the model
-    assert 1e-2 < r < 1e2
-    assert json.loads(json.dumps(drift))            # JSON-serializable
