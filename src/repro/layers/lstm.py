@@ -64,6 +64,33 @@ def lstm_forward(params, x, cfg: ModelConfig, state=None) -> Tuple[jnp.ndarray, 
     return out, new_state
 
 
+def lstm_forward_steps(params, x, state, n) -> Tuple[list, list]:
+    """``lstm_forward`` over the first ``n`` of x's T steps, keeping every
+    step's state. x: (B, T, d) embedded inputs; ``n`` may be traced, so T
+    can be a padded bucket: the loop runs exactly ``n`` steps.
+
+    Returns (final state, step states): per layer ``{"h", "c"}`` of
+    (T, B, d), where entry k is the state after step k (entries from ``n``
+    on are zeros). Each step is the same ``_cell`` as ``lstm_forward``'s,
+    and the two agree bit for bit; a select on a step mask inside the
+    loop would not, since it changes how the compiler fuses the cell."""
+    out = jnp.moveaxis(x, 0, 1)
+    final, steps = [], []
+    for li, p in enumerate(params["layers"]):
+        def body(i, carry, p=p, xs=out):
+            h, c, hs, cs = carry
+            h, c = _cell(p, xs[i], h, c)
+            return h, c, hs.at[i].set(h), cs.at[i].set(c)
+        h0 = state[li]["h"]
+        zeros = jnp.zeros((x.shape[1], *h0.shape), h0.dtype)
+        hT, cT, hs, cs = jax.lax.fori_loop(
+            0, n, body, (h0, state[li]["c"], zeros, zeros))
+        out = hs
+        final.append({"h": hT, "c": cT})
+        steps.append({"h": hs, "c": cs})
+    return final, steps
+
+
 def lstm_decode_step(params, x1, state, cfg: ModelConfig):
     """x1: (B, d) one embedded token → (h_top (B, d), new state)."""
     out = x1

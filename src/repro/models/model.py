@@ -9,8 +9,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.layers.embeddings import embed_init, embed_tokens, head_matrix, lm_logits
 from repro.layers.initializers import dense_init
-from repro.layers.lstm import (lstm_decode_step, lstm_forward, lstm_init,
-                               lstm_init_state)
+from repro.layers.lstm import (lstm_decode_step, lstm_forward,
+                               lstm_forward_steps, lstm_init, lstm_init_state)
 from repro.layers.rope import mrope_positions
 from repro.layers.transformer import (stack_decode, stack_decode_paged,
                                       stack_forward, stack_init,
@@ -105,6 +105,18 @@ class Model:
         bit-identical to one-shot prefill over the full prompt."""
         with jax.named_scope("prefill"):
             return self._prefill(params, batch, cache, window, resume)
+
+    def prefill_steps(self, params, tokens, state, n):
+        """LSTM prefill of ``tokens`` (B, T) from recurrent ``state``, of
+        which only the first ``n`` steps are real (T may be a padded
+        bucket). → (final state, per-step states); see
+        ``lstm_forward_steps``. The paged serving path's join reads its
+        page snapshots from the per-step states."""
+        if self.cfg.family != "lstm":
+            raise NotImplementedError("prefill_steps is LSTM-only")
+        with jax.named_scope("prefill"):
+            x = embed_tokens(params["embed"], tokens, self.cfg)
+            return lstm_forward_steps(params["lstm"], x, state, n)
 
     def _prefill(self, params, batch, cache, window, resume):
         cfg = self.cfg

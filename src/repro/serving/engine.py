@@ -106,6 +106,12 @@ class DecodeEngine:
         self._jit_resume_prefill = jax.jit(
             lambda p, batch, cache: model.prefill(p, batch, cache,
                                                   resume=True))
+        # the paged LSTM join as one executable (see _lstm_join): suffix
+        # prefill padded to the page grid, its page snapshots, and the splice
+        # of its final state into the stream's cache
+        self._jit_lstm_join = jax.jit(
+            lambda p, ints, src, group: _lstm_join(model, p, ints, src,
+                                                   group))
         self._jit_decode_paged = jax.jit(
             lambda p, tok, pk, pv, table, pos: model.decode_step_paged(
                 p, tok, {"k": pk, "v": pv}, table, pos))
@@ -833,6 +839,35 @@ def _splice_cache(group, solo, slot, cfg):
                          for g, s in zip(group["lstm"], solo["lstm"])]}
     return jax.tree_util.tree_map(lambda g, s: g.at[:, slot].set(s[:, 0]),
                                   group, solo)
+
+
+def _lstm_join(model, params, ints, src, group):
+    """One paged LSTM join on the device.
+
+    ``ints`` packs the host's int32 inputs into one upload: ``[n, k, slot,
+    snap_idx (S), tokens (T)]`` — the real suffix length (0 for a
+    whole-prompt hit), the index of the resume state in ``src``, the stream
+    row to write, the step after which each page snapshot is taken
+    (padding entries read step 0), and the suffix padded to its bucket T,
+    a multiple of the page size. ``src`` holds S stacked states per layer
+    (a page snapshot handle's arrays, or zeros); the prefill starts from
+    ``src[k]`` and runs exactly ``n`` steps.
+
+    Returns (h_last (1, d): the top layer's h after the last real step;
+    snapshots: S states per layer, ``src``'s layout; the stream cache with
+    row ``slot`` replaced by the final state). ``group`` is not donated:
+    the caller commits the new cache only once the join can no longer
+    fail."""
+    S = src[0]["h"].shape[0]
+    n, k, slot = ints[0], ints[1], ints[2]
+    snap_idx, tokens = ints[3:3 + S], ints[3 + S:]
+    state0 = [{key: a[k] for key, a in layer.items()} for layer in src]
+    final, steps = model.prefill_steps(params, tokens[None], state0, n)
+    snaps = [{key: a[snap_idx] for key, a in layer.items()}
+             for layer in steps]
+    group = {"lstm": [{key: g[key].at[slot].set(f[key][0]) for key in g}
+                      for g, f in zip(group["lstm"], final)]}
+    return final[-1]["h"], snaps, group
 
 
 def _reorder_cache(cache, src_idx, cfg):

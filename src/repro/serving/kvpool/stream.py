@@ -20,8 +20,11 @@ of pool pages per slot:
     pressure semantics) and the radix cache's node payloads are recurrent
     state snapshots. A prefix hit is a true COMPUTE skip: prefill resumes
     from the deepest snapshot and runs only the suffix, bit-exactly (a
-    restarted scan is the same cell sequence), chunked at page boundaries
-    so every new node gets its snapshot.
+    restarted scan is the same cell sequence). The whole join — suffix
+    prefill padded to the page grid, the state at every page boundary,
+    and the splice into the slot's row — is ONE device dispatch
+    (``engine._lstm_join``); each new node's payload is a
+    ``SnapshotHandle`` into that dispatch's stacked snapshots.
 
 Sharing is copy-on-write: a slot's first write into a page with other
 holders (a cache-pinned prompt tail, a sibling slot's shared prefix)
@@ -37,17 +40,34 @@ eviction/preemption frees pages).
 """
 from __future__ import annotations
 
-from typing import List, Optional
+from dataclasses import dataclass
+from typing import Any, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.serving.engine import _splice_cache, _StreamSlot, _tag_id
+from repro.serving.engine import _StreamSlot, _tag_id
 from repro.serving.kvpool.pool import PagePool, PoolExhausted
 from repro.serving.request import ServeRequest
 from repro.serving.observe.trace import NULL_TRACER
 from repro.serving.resilience.faults import HeadFault, guard_tokens
+
+
+@dataclass(frozen=True)
+class SnapshotHandle:
+    """An LSTM radix payload: the recurrent state after one page, as row
+    ``index`` of the stacked snapshots (per layer ``{"h", "c"}`` of
+    (S, 1, d)) that one join's dispatch returned. Pages of a join share
+    its arrays, so storing them costs no device work; the join executable
+    slices the row it resumes from."""
+    states: Any
+    index: int
+
+    def resolve(self) -> list:
+        """The state itself, per layer ``{"h", "c"}`` of (1, d)."""
+        return [{k: a[self.index] for k, a in layer.items()}
+                for layer in self.states]
 
 
 class PagedDecodeStream:
@@ -85,8 +105,15 @@ class PagedDecodeStream:
         if self.family == "lstm":
             self.cache = engine.model.init_cache(self.width, engine.max_len,
                                                  dtype=engine.cache_dtype)
+            # a cold join resumes from row 0 of all-zero snapshots: same
+            # shapes as a handle's, so cold and resumed joins share one
+            # executable per bucket
+            self._zero_snaps = jax.tree.map(
+                lambda a: jnp.zeros((self.max_pages, 1, *a.shape[1:]),
+                                    a.dtype), self.cache["lstm"])
             if self._repl is not None:
-                self.cache = jax.device_put(self.cache, self._repl)
+                self.cache, self._zero_snaps = jax.device_put(
+                    (self.cache, self._zero_snaps), self._repl)
             self.table = None
         else:
             # per-slot sequence-page -> pool-page map; 0 = trash page, so
@@ -194,40 +221,39 @@ class PagedDecodeStream:
         return slot
 
     def _join_lstm(self, slot, request, toks, match, held) -> int:
-        """Resume prefill from the deepest cached snapshot; chunk the
-        suffix at page boundaries, snapshotting each, so the whole prompt
-        inserts as radix nodes. Returns the first token."""
+        """Resume prefill from the deepest cached snapshot over the suffix,
+        padded up to the page grid, snapshotting at every page boundary so
+        the whole prompt inserts as radix nodes, and splice the final state
+        into ``slot``: one dispatch. The new cache commits only after the
+        first token's guard and the page bookkeeping pass, so a fault
+        leaves the stream as it was. Returns the first token."""
         eng, pool, tr = self.engine, self.pool, self.tracer
-        P, Tp = pool.page_size, len(toks)
+        P, Tp, S = pool.page_size, len(toks), self.max_pages
         t = match.n_full                      # snapshot exists exactly here
         for pg, _ in match.chain:
             held.append(pool.retain(pg))
+        n = Tp - t
+        # the page grid buckets the suffix; a whole-prompt hit (n == 0)
+        # runs no step and rides the smallest bucket, already compiled
+        bucket = max(P, -(-n // P) * P)
+        # page-grid ends in (t, Tp]: each closes a node that gets a snapshot
+        ends = [*range((t // P + 1) * P, Tp, P), Tp] if n else []
         with tr.scope("serve.join.prefill", "join"):
-            cache1 = {"lstm": match.payload} if match.payload is not None \
-                else eng.model.init_cache(1, eng.max_len,
-                                          dtype=eng.cache_dtype)
-            snaps, h_last, i = [], None, t
-            prompt = np.asarray(request.prompt)
-            while i < Tp:
-                n = min(P - (i % P), Tp - i)  # realign to the page grid
-                h, cache1 = eng._jit_resume_prefill(
-                    eng.params,
-                    {"tokens": jnp.asarray(prompt[None, i:i + n])}, cache1)
-                i += n
-                snaps.append((i, cache1["lstm"]))
-                h_last = h[:, -1]
+            src = match.payload
+            states, k = (self._zero_snaps, 0) if src is None \
+                else (src.states, src.index)
+            ints = np.zeros(3 + S + bucket, np.int32)
+            ints[:3] = n, k, slot
+            ints[3:3 + len(ends)] = np.asarray(ends, np.int32) - t - 1
+            ints[3 + S:3 + S + n] = request.prompt[t:]
+            h_last, snaps, cache = eng._jit_lstm_join(eng.params, ints,
+                                                      states, self.cache)
+            if tr.enabled:
+                tr.note(bucket=bucket, snapshots=len(ends))
         if tr.enabled:
-            tr.note(cached_tokens=t, chunks=len(snaps))
-        if h_last is None:
-            # whole prompt cached: the top layer's h AT the last prompt
-            # token is the snapshot's own h — no forward pass needed at all
-            h_last = cache1["lstm"][-1]["h"]
+            tr.note(cached_tokens=t, chunks=int(n > 0))
         first = self._first_token(h_last)
         with tr.scope("serve.join.splice", "join"):
-            solo = cache1 if self._repl is None \
-                else jax.device_put(cache1, self._repl)
-            self.cache = _splice_cache(self.cache, solo, slot,
-                                       eng.model.cfg)
             # page chain: a partially-covered grid slot being EXTENDED must
             # go private now (logical COW — its node's snapshot stops at t,
             # ours will stop deeper); fresh pages back the remaining slots
@@ -239,10 +265,11 @@ class PagedDecodeStream:
             while len(held) < n_prompt:
                 held.append(pool.alloc())
             payloads: List[object] = [None] * n_prompt
-            for end, state in snaps:
-                payloads[(end - 1) // P] = state
+            for j, end in enumerate(ends):
+                payloads[(end - 1) // P] = SnapshotHandle(snaps, j)
             pool.radix.insert(toks, held[:n_prompt], payloads)
             pool.radix.record(t, Tp)
+            self.cache = cache
         return first
 
     def _join_attn(self, request, toks, match, held) -> int:

@@ -22,7 +22,7 @@ from repro.configs import get_config
 from repro.models import build_model
 from repro.serving import (BudgetAdmission, ContinuousScheduler,
                            DecodeEngine, PagePool, PoolExhausted,
-                           ServeRequest, ServeResult)
+                           ServeRequest, ServeResult, Tracer)
 from repro.serving.kvpool import RadixCache
 from repro.serving.scheduler import AdmissionRejected
 
@@ -378,6 +378,132 @@ def test_scheduler_paged_zero_recompiles(lstm_engine):
     assert all(isinstance(r, ServeResult) for r in res)
     counts1 = eng.compiled_step_counts()
     assert sum(counts1.values()) == sum(counts0.values()), (counts0, counts1)
+
+
+def test_scheduler_paged_join_zero_recompiles(lstm_engine):
+    """The fused LSTM join compiles one executable per page-grid bucket: a
+    second scheduler over prompt lengths a first one already served
+    compiles nothing at all — no join bucket, no step, no eager op."""
+    cfg, eng = lstm_engine
+    lengths = (1, 3, 4, 6, 9, 12, 13)
+
+    def batch(seed):
+        rng = np.random.default_rng(seed)
+        return [ServeRequest(prompt=rng.integers(0, cfg.vocab_size, size=n)
+                             .astype(np.int32), max_new=3) for n in lengths]
+
+    ContinuousScheduler(eng, max_slots=3,
+                        kv_pool=PagePool(64, 4)).serve(batch(43))
+    n_join = eng._jit_lstm_join._cache_size()
+    compiles = []
+
+    def on_duration(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        res = ContinuousScheduler(eng, max_slots=3,
+                                  kv_pool=PagePool(64, 4)).serve(batch(47))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert all(isinstance(r, ServeResult) for r in res)
+    assert compiles == []
+    assert eng._jit_lstm_join._cache_size() == n_join
+
+
+# -- fused LSTM join: one dispatch, page-grid buckets, lazy snapshots ---------
+
+P_FUSED = 4
+# (kind, cached tokens t, prompt length): every prompt length 1..3P+1, so
+# every bucket and every pad amount; resumes from a partial tail
+# (t % P != 0), a suffix longer than a page among them; whole-prompt hits
+# from an identical prompt and from a page-aligned prefix of a longer one
+_FUSED_CASES = (
+    [("cold", 0, n) for n in range(1, 3 * P_FUSED + 2)]
+    + [("resume", n - 1 if (n - 1) % P_FUSED else n - 2, n)
+       for n in range(2, 3 * P_FUSED + 2)]
+    + [("resume", 1, 13), ("resume", 3, 13), ("resume", 5, 11)]
+    + [("whole", n, n) for n in range(1, 3 * P_FUSED + 2)]
+    + [("prefix", n, n) for n in (4, 8, 12)])
+
+
+def _resume_prefill_state(eng, prompt):
+    """Reference snapshot: ``_jit_resume_prefill`` over ``prompt`` from
+    zeros, per layer ``{"h", "c"}``."""
+    cache = eng.model.init_cache(1, eng.max_len, dtype=eng.cache_dtype)
+    _, cache = eng._jit_resume_prefill(
+        eng.params, {"tokens": jnp.asarray(prompt[None])}, cache)
+    return cache["lstm"]
+
+
+@pytest.mark.parametrize("kind,t,n", _FUSED_CASES)
+def test_lstm_fused_join_parity(lstm_engine, kind, t, n):
+    """One fused join per case: tokens bit-identical to solo generate,
+    the join resumed from exactly ``t`` cached tokens, and every page of
+    the prompt carries a snapshot handle that resolves to the state
+    ``_jit_resume_prefill`` reaches from zeros over the page's prefix."""
+    cfg, eng = lstm_engine
+    P = P_FUSED
+    rng = np.random.default_rng(1000 + 31 * t + n)
+    full = rng.integers(0, cfg.vocab_size, size=13).astype(np.int32)
+    prompt = full[:n]
+    pool = PagePool(64, P)
+    stream = eng.open_paged_stream(pool, width=2)
+    first = {"resume": full[:t], "whole": prompt, "prefix": full}.get(kind)
+    if first is not None:
+        _run_stream(stream, [ServeRequest(prompt=first, max_new=2)])
+    hit0 = pool.radix.tokens_hit
+    req = ServeRequest(prompt=prompt, max_new=4)
+    got = _run_stream(stream, [req])[0]
+    ref = eng.generate(prompt[None], req.max_new).tokens[0]
+    np.testing.assert_array_equal(got, ref)
+    assert pool.radix.tokens_hit - hit0 == t
+    for end in [*range(P, n, P), n]:
+        handle = pool.radix.match(prompt[:end], peek=True).payload
+        want = _resume_prefill_state(eng, prompt[:end])
+        for layer, (g, w) in enumerate(zip(handle.resolve(), want)):
+            for k in ("h", "c"):
+                np.testing.assert_array_equal(
+                    np.asarray(g[k]), np.asarray(w[k]),
+                    err_msg=f"end {end} layer {layer} {k}")
+
+
+def test_lstm_join_is_one_dispatch(lstm_engine, monkeypatch):
+    """Through the scheduler, every paged LSTM join is exactly one call of
+    the join executable — cold, resumed or whole-prompt hit — and the
+    chunked ``_jit_resume_prefill`` is never called."""
+    cfg, eng = lstm_engine
+    calls = {"join": 0, "resume": 0}
+
+    def counted(name, fn):
+        def wrapped(*args, **kw):
+            calls[name] += 1
+            return fn(*args, **kw)
+        return wrapped
+
+    monkeypatch.setattr(eng, "_jit_lstm_join",
+                        counted("join", eng._jit_lstm_join))
+    monkeypatch.setattr(eng, "_jit_resume_prefill",
+                        counted("resume", eng._jit_resume_prefill))
+    rng = np.random.default_rng(53)
+    base = rng.integers(0, cfg.vocab_size, size=13).astype(np.int32)
+    prompts = [base, base[:6], base[:8]]            # resumed, whole hits
+    prompts += [rng.integers(0, cfg.vocab_size, size=m).astype(np.int32)
+                for m in (1, 2, 5, 7, 10, 13)]
+    tr = Tracer()
+    res = ContinuousScheduler(eng, max_slots=3, kv_pool=PagePool(64, 4),
+                              tracer=tr).serve(
+        [ServeRequest(prompt=p, max_new=3) for p in prompts])
+    assert all(isinstance(r, ServeResult) for r in res)
+    joins = [e for e in tr.events() if e["name"] == "serve.join"]
+    assert len(joins) == len(prompts)
+    assert calls == {"join": len(joins), "resume": 0}
+    forward = [j for j in joins if j["args"]["chunks"] == 1]
+    whole = [j for j in joins if j["args"]["chunks"] == 0]
+    assert len(forward) + len(whole) == len(joins)
+    assert whole and all(j["args"]["cached_tokens"] == j["args"]["prompt_len"]
+                         for j in whole)
 
 
 # -- multidevice: paged decode under a vocab-sharded head ---------------------

@@ -509,6 +509,31 @@ def test_scheduler_span_tree_paged(trained):
             "serve.submit.load"]
 
 
+def test_paged_lstm_prefill_span_args(trained):
+    """On the paged LSTM path a join's ``serve.join.prefill`` carries the
+    padded scan length ``bucket`` and the page ``snapshots`` it wrote: a
+    forward pass is one dispatch (``chunks`` 1 on the join) over the
+    smallest page-grid bucket that holds the suffix, with one snapshot per
+    page the suffix reaches."""
+    P = 4
+    tr = Tracer(clock=FakeClock(dt=1e-4))
+    _, _, out = _paged_run(trained, tr)
+    assert all(isinstance(r, ServeResult) for r in out)
+    kids = _kids(tr)
+    joins = _by_name(tr, "serve.join")
+    assert joins and any(j["args"]["cached_tokens"] > 0 for j in joins)
+    for j in joins:
+        a = j["args"]
+        (pre,) = [c for c in kids[a["id"]]
+                  if c["name"] == "serve.join.prefill"]
+        suffix = a["prompt_len"] - a["cached_tokens"]
+        assert suffix > 0 and a["chunks"] == 1
+        bucket = pre["args"]["bucket"]
+        assert bucket % P == 0 and suffix <= bucket < suffix + P
+        assert pre["args"]["snapshots"] == \
+            -(-a["prompt_len"] // P) - a["cached_tokens"] // P
+
+
 def test_pool_reclaim_span_nests_under_its_allocation(trained):
     """A pool small enough that the radix cache must give pages back:
     each ``serve.pool.reclaim`` nests under the join splice or step page

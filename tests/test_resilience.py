@@ -24,8 +24,9 @@ from repro.optim import adamw_init
 from repro.serving import (AdmissionRejected, CircuitBreaker,
                            ContinuousScheduler, DecodeEngine, FaultInjector,
                            FaultSpec, HeadFault, LogicalClock, PagePool,
-                           SchedulerStalled, ServeRequest, ServeResult,
-                           StaticPolicy, StreamWatchdog, TierPolicy)
+                           PoolExhausted, SchedulerStalled, ServeRequest,
+                           ServeResult, StaticPolicy, StreamWatchdog,
+                           TierPolicy)
 from repro.serving.resilience.breaker import CLOSED, HALF_OPEN, OPEN
 from repro.serving.resilience.faults import guard_tokens, invalid_token_rows
 from repro.serving.scheduler import TIER_DEADLINES
@@ -437,6 +438,88 @@ def test_drain_max_ticks_exhaustion_raises(trained):
                               max_new=20))
     with pytest.raises(SchedulerStalled, match="max_ticks"):
         sched.drain(max_ticks=3)
+
+
+def _paged_stream_state(stream):
+    """Everything a paged LSTM join may touch, as host values: the cache,
+    tokens, positions, slots, page chains, pool refcounts, and the radix
+    tree (path, page, payload identity) with its counters. LRU stamps are
+    left out: the radix match that opens a join refreshes them whether or
+    not the join goes on to succeed."""
+    radix, nodes = stream.pool.radix, []
+    stack = [(radix.root, ())]
+    while stack:
+        node, path = stack.pop()
+        for child in [*node.children.values(), *node.partials]:
+            key = path + child.tokens
+            nodes.append((key, child.page, id(child.payload)))
+            stack.append((child, key))
+    return {"cache": [np.asarray(a) for a in jax.tree.leaves(stream.cache)],
+            "tok": stream.tok.tolist(), "pos": stream.pos.tolist(),
+            "slots": list(stream.slots),
+            "pages": [list(c) for c in stream._pages],
+            "refs": stream.pool.live_pages(), "nodes": sorted(nodes),
+            "counts": (radix.nodes, radix.evictions, radix.lookups,
+                       radix.lookup_hits, radix.tokens_hit,
+                       radix.tokens_total)}
+
+
+def _assert_same_state(a, b):
+    assert len(a["cache"]) == len(b["cache"])
+    for x, y in zip(a["cache"], b["cache"]):
+        np.testing.assert_array_equal(x, y)
+    for key in a:
+        if key != "cache":
+            assert a[key] == b[key], key
+
+
+@pytest.mark.parametrize("fault", ["head", "pool"])
+def test_paged_lstm_join_rolls_back_bit_identical(trained, fault):
+    """A join that fails after its device dispatch — a ``HeadFault`` at the
+    first-token guard, or ``PoolExhausted`` at the COW of the partial tail
+    it extends — leaves the stream's cache, tokens, positions, page chains
+    and radix exactly as they were; the retried join then matches a clean
+    run and solo generate."""
+    cfg, _, _, corpus, _ = trained
+    eng = _engine(trained)
+    a, b, c = corpus.sample_batch(3, 9, seed=71)
+    a = a[:6]                         # a partial tail at 6 (P = 4)
+    req_b = ServeRequest(prompt=np.concatenate([a, b[:3]]), max_new=4)
+
+    def drain(stream):
+        out = {}
+        while stream.n_active:
+            out.update((tag, t) for tag, _, t in stream.step())
+        return out
+
+    clean_stream = eng.open_paged_stream(PagePool(64, 4), head="exact",
+                                         width=3)
+    clean_stream.join(ServeRequest(prompt=a, max_new=4), tag="a")
+    clean_stream.join(req_b, tag="b")
+    clean = drain(clean_stream)["b"]
+    # "pool": 4 allocatable pages, two held by a and two by c, so b's COW
+    # of a's shared tail page finds nothing free and nothing reclaimable
+    pool = PagePool(64 if fault == "head" else 5, 4)
+    stream = eng.open_paged_stream(pool, head="exact", width=3)
+    stream.join(ServeRequest(prompt=a, max_new=4), tag="a")
+    stream.join(ServeRequest(prompt=c[:8], max_new=4), tag="c")
+    if fault == "head":
+        stream.step()
+        inj = FaultInjector(seed=0)
+        inj.arm("join", "transient", count=1)
+        stream.fault_injector = inj
+    before = _paged_stream_state(stream)
+    with pytest.raises(HeadFault if fault == "head" else PoolExhausted):
+        stream.join(req_b, tag="b")
+    _assert_same_state(_paged_stream_state(stream), before)
+    if fault == "pool":
+        for i, _ in stream.occupied():
+            stream.evict(i)
+    stream.join(req_b, tag="b")
+    got = drain(stream)["b"]
+    np.testing.assert_array_equal(got, clean)
+    np.testing.assert_array_equal(
+        got, eng.generate(req_b.prompt[None], 4, head="exact").tokens[0])
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])

@@ -1,12 +1,14 @@
-"""The served Pallas kernels compile for a TPU v5e at nmt-deen-lstm widths.
+"""The served Pallas kernels compile for a TPU v5e at nmt-deen-lstm widths,
+and the paged LSTM join at ptb-large-lstm widths.
 
 The TPU compiler runs without a chip: each test lowers a kernel entry point
 with shapes placed on a described ``v5e:2x2`` device and compiles it, which
 refuses what interpret mode cannot see (block tiling, VMEM limits, scalar
-stores into vector memory). Widths are the paper's DE→EN model: d=500 and a
-25,000-word vocabulary, i.e. 196 blocks of 128; batches are the scheduler's
-decode width and a single query. Every compile must contain a Mosaic kernel
-(``tpu_custom_call``): interpreted kernels would lower to plain XLA.
+stores into vector memory). Kernel widths are the paper's DE→EN model: d=500
+and a 25,000-word vocabulary, i.e. 196 blocks of 128; batches are the
+scheduler's decode width and a single query. Every kernel compile must
+contain a Mosaic kernel (``tpu_custom_call``): interpreted kernels would
+lower to plain XLA. The join is plain XLA and has none.
 
 The topology is described inside a fixture, never at import, so every test
 worker collects the same tests and only the one running this file loads the
@@ -121,3 +123,29 @@ def test_sharded_fused_local_compiles(topo, monkeypatch):
         _spec((MAX_SLOTS, D), on(None, None)), k=TOPK).compile()
     _assert_kernel(compiled)
     assert "all-gather" in compiled.as_text()
+
+
+@pytest.mark.parametrize("bucket", [8, 64])
+def test_lstm_join_compiles(one_chip, bucket):
+    """The paged LSTM join — suffix prefill over one page-grid bucket, the
+    page snapshots, the splice into a stream cache of the scheduler's
+    width — compiles as one executable at the PTB-Large widths (d=1500,
+    V=10,000), with pages of 8 and a 72-slot cache (9 snapshot rows), and
+    runs its steps in one device loop per layer."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serving.engine import _lstm_join
+    model = build_model(get_config("ptb-large-lstm"))
+    params = jax.tree.map(lambda s: _spec(s.shape, one_chip, s.dtype),
+                          model.init_shapes(jnp.float32))
+    layers = model.cfg.num_layers
+    d = model.cfg.d_model
+    S = 72 // 8
+    src = [{k: _spec((S, 1, d), one_chip) for k in "hc"}] * layers
+    group = {"lstm": [{k: _spec((MAX_SLOTS, d), one_chip) for k in "hc"}]
+             * layers}
+    ints = _spec((3 + S + bucket,), one_chip, jnp.int32)
+    compiled = jax.jit(
+        lambda p, i, s, g: _lstm_join(model, p, i, s, g)).lower(
+        params, ints, src, group).compile()
+    assert compiled.as_text().count(" while(") >= layers
