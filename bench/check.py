@@ -97,7 +97,8 @@ def judge(r: Dict[str, np.ndarray], who: str, limits: Dict[str, float]
 
 def reference_for(conf: dict, work: dict, seed: int, screen_host):
     weights = system.make_weights(conf, seed)
-    return reference.Reference(weights, screen_host, conf["vocab_size"],
+    return reference.Reference(system.load_family(conf["family"]), conf,
+                               weights, screen_host, conf["vocab_size"],
                                block_rows=work["check"]["block_rows"],
                                seq_len=system.max_len(work))
 

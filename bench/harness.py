@@ -27,7 +27,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from bench.traffic import LatencyBook, Record
+from bench.traffic import ClosedPlan, LatencyBook, Record
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -116,7 +116,14 @@ class Driver:
     """Offers the cell's planned traffic to a scheduler on the harness's
     clock and keeps the due-time book. Single-threaded: submissions happen
     between ticks, so a long tick makes the generator late, and the
-    latency of every request timed from its due time shows it."""
+    latency of every request timed from its due time shows it.
+
+    Open loop (a list of planned requests): each is submitted once its due
+    time has come. Closed loop (a ``traffic.ClosedPlan``): every client
+    keeps one request outstanding, submitting its next in the tick after
+    its last one finished, with no think time; a request is due when it is
+    submitted. Either way nothing is submitted once the window has closed.
+    """
 
     def __init__(self, system, sched, planned, seconds: float, ramp_s: float,
                  drain_s: float, clock=time.perf_counter,
@@ -134,6 +141,9 @@ class Driver:
         self.ticks: List[tuple] = []
         self.submits: List[tuple] = []
         self.book: Optional[LatencyBook] = None
+        # closed loop: clients ready to submit, and each one's next request
+        self.ready: Optional[List[int]] = None
+        self.sent: List[int] = []
 
     def _submit(self, p, due: float):
         req = self.system.request(p)
@@ -152,6 +162,8 @@ class Driver:
         rid = self.by_req.pop(id(res.request))
         rec = self.records[rid]
         rec.done = t
+        if self.ready is not None:
+            self.ready.append(self.planned.client_of(rec.planned))
         if not isinstance(res, ServeResult):
             rec.failed = f"refused ({res.stage})"
         elif res.head != self.system.head:
@@ -168,7 +180,13 @@ class Driver:
         t1 = t0 + self.seconds
         self.book = LatencyBook(t0, t1)
         opened = closed_window = False
-        todo = sorted(self.planned, key=lambda p: p.due)
+        closed_loop = isinstance(self.planned, ClosedPlan)
+        if closed_loop:
+            self.ready = list(range(self.planned.clients))
+            self.sent = [0] * self.planned.clients
+            todo = []
+        else:
+            todo = sorted(self.planned, key=lambda p: p.due)
         ptr = 0
         while True:
             now = clock()
@@ -180,6 +198,12 @@ class Driver:
                 closed_window = True
                 if self.on_close:
                     self.on_close()
+            if closed_loop and not closed_window:
+                ready, self.ready = self.ready, []
+                for c in ready:
+                    p = self.planned.request(c, self.sent[c])
+                    self.sent[c] += 1
+                    self._submit(p, clock())
             while ptr < len(todo) and t_base + todo[ptr].due <= now \
                     and t_base + todo[ptr].due < t1:
                 self._submit(todo[ptr], t_base + todo[ptr].due)
@@ -359,7 +383,9 @@ def run(args, t_start: float, require_accelerator: bool = True,
                         gc_clock.listening():
                     book = drv.run()
             finally:
+                t = time.perf_counter()
                 jax.profiler.stop_trace()
+                log(f"profiler stopped in {time.perf_counter() - t:.1f} s")
         else:
             with gc_clock.listening():
                 book = drv.run()
@@ -391,6 +417,7 @@ def run(args, t_start: float, require_accelerator: bool = True,
            "ticks": drv.window_ticks(),
            "all_ticks": list(drv.ticks),
            "records": drv.records, "win": win, "lbar": lbar,
+           "family": sysm.family,
            "screen_r": None if sysm.screen is None else int(sysm.screen.r),
            "tracer_events": tracer.events() if tracer is not None else None,
            "trace": None}
@@ -416,8 +443,10 @@ def run(args, t_start: float, require_accelerator: bool = True,
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     else:
         from bench import trace_reduce
+        t = time.perf_counter()
         tr = trace_reduce.reduce_dir(trace_dir, book.t0, book.t1,
                                      art["all_ticks"], art["ticks"], log=log)
+        log(f"trace read in {time.perf_counter() - t:.1f} s")
         art["trace"] = tr
         if tr is not None:
             device["busy_s"] = tr["busy_s"]

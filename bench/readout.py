@@ -11,6 +11,8 @@ from __future__ import annotations
 import re
 from typing import Optional
 
+import numpy as np
+
 from bench import peaks, work
 from bench.trace_reduce import op_text
 
@@ -68,6 +70,35 @@ def head_macs(art) -> float:
                           art["screen_r"] or 0, art["lbar"] or 0.0)
 
 
+def trunk_positions(art) -> Optional[tuple]:
+    """(prompt positions, decode positions) of the trunk's tokens in the
+    window: every prompt token of the window's joins, and the input token
+    of every row of the window's decode steps (the k-th step of a request
+    with a prompt of p tokens runs its token k−1 at position p + k − 1);
+    None without the program's join instants and step spans."""
+    joins = joins_in_window(art)
+    evs = art.get("tracer_events")
+    if joins is None or evs is None:
+        return None
+    recs = art["records"]
+    prompt = [np.arange(len(recs[rid].planned.prompt))
+              for rid, _ in joins if rid in recs]
+    b = art["book"]
+    steps: dict = {}
+    decode = []
+    for ev in sorted((e for e in evs if e["name"] == "serve.step"
+                      and e.get("ph") == "X"
+                      and "error" not in e.get("args", {})),
+                     key=lambda e: e["ts"]):
+        inside = b.t0 <= ev["ts"] < b.t1
+        for rid in ev["args"].get("rids", ()):
+            steps[rid] = k = steps.get(rid, 0) + 1
+            if inside and rid in recs:
+                decode.append(len(recs[rid].planned.prompt) + k - 1)
+    return (np.concatenate(prompt) if prompt else np.zeros(0, np.int64),
+            np.asarray(decode, np.int64))
+
+
 def step_mfu(art) -> Optional[float]:
     """Model operations the window's work required over (device-busy time
     in the window, from the trace, × chips × peak), in %. The peak is the
@@ -75,14 +106,11 @@ def step_mfu(art) -> Optional[float]:
     TPU's default precision, one bfloat16-operand pass, so no faster rate
     exists for them."""
     tr = art.get("trace")
-    joins = joins_in_window(art)
-    if tr is None or joins is None or tr["busy_s"] <= 0:
+    pos = trunk_positions(art)
+    if tr is None or pos is None or tr["busy_s"] <= 0:
         return None
-    c = art["conf"]
-    prompt = sum(len(art["records"][rid].planned.prompt)
-                 for rid, _ in joins if rid in art["records"])
-    flops = work.model_flops(c["d_model"], c["num_layers"], head_macs(art),
-                             prompt, output_tokens(art), len(joins))
+    flops = work.model_flops(art["family"], art["conf"], head_macs(art),
+                             pos[0], pos[1], output_tokens(art))
     peak = peaks.chip_peaks(art["kind"])["flops_bf16"]
     return 100.0 * flops / (tr["busy_s"] * art["chips"] * peak)
 

@@ -1,8 +1,7 @@
-"""The plain reference: a float32 LSTM language model and both head
-semantics, written from the equations alone.
+"""The plain reference: a model's trunk from its family module
+(``bench/families/<family>.py``) and both head semantics, written from the
+equations alone.
 
-    gates = x·Wx + h·Wh + b,  (i, f, g, o) = split(gates, 4)
-    c' = σ(f)·c + σ(i)·tanh(g),  h' = σ(o)·tanh(c')
     logits = h·Woutᵀ + bout
 
 Exact head: the served token is the arg-max of the logits over the whole
@@ -11,16 +10,17 @@ cluster t = argmax_t v_t·h, and the served token is the arg-max of the
 logits over that cluster's candidate words.
 
 Nothing here imports the program. The weights are regenerated from the
-seed by ``init_weights`` (the same function the harness used to make the
-weights it handed the program); the fitted screen (v, candidate blocks) is
-the served model's own parameter set and is copied to the host at set-up.
-Every matrix product runs at ``Precision.HIGHEST`` so that a TPU does not
-round its float32 operands to bfloat16.
+seed by the family's ``init_weights`` (the same function the harness used
+to make the weights it handed the program); the fitted screen (v,
+candidate blocks) is the served model's own parameter set and is copied
+to the host at set-up. Every matrix product runs at ``Precision.HIGHEST``
+so that a TPU does not round its float32 operands to bfloat16.
 
 The controls (``CONTROLS``) are the same model at a lower precision, put in
 the program's place: ``float8``, every weight matrix rounded to float8
 e4m3 (3 mantissa bits) with a per-tensor scale, activations and state in
-float32; ``bfloat16``, weights, activations and state in bfloat16.
+float32; ``bfloat16``, weights, activations and state in bfloat16. The
+family's ``lower`` rounds its weights; the route vectors are rounded here.
 """
 from __future__ import annotations
 
@@ -33,28 +33,20 @@ import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
+CONTROLS = ("float8", "bfloat16")
 
-def init_weights(key, vocab: int, d: int, layers: int,
-                 dtype=jnp.float32) -> Dict[str, object]:
-    """Random weights from one key, at PyTorch's default initialisation:
-    embedding N(0, 1); every LSTM and output weight and bias uniform in
-    ±1/√d; the forget-gate bias raised by 1 (Jozefowicz et al. 2015, and
-    the program's own LSTM initialiser). → {"emb" (V, d), "layers":
-    [{"wx" (d, 4d), "wh" (d, 4d), "b" (4d,)}], "w_out" (V, d), "b_out" (V,)}.
-    """
-    ks = jax.random.split(key, 3 + 3 * layers)
-    a = 1.0 / float(np.sqrt(d))
+# A chunk of the head's rows holds at most this many bytes of float32
+# logits, (rows, V+1). Both LSTM cells' blocks fit in one chunk (DE→EN 307
+# MB, PTB-Large 92 MB), so their arithmetic is the unchunked one; at a
+# vocabulary of 2·10⁵ a chunk is 670 rows, and a chunk's logits, the
+# control's beside them and the candidate gathers stay a few GB, inside
+# one chip's 16 GB however long the block's sequences are.
+HEAD_CHUNK_BYTES = 512 * 2 ** 20
 
-    def u(k, shape):
-        return jax.random.uniform(k, shape, dtype, -a, a)
 
-    return {"emb": jax.random.normal(ks[0], (vocab, d), dtype),
-            "layers": [{"wx": u(ks[3 + 3 * i], (d, 4 * d)),
-                        "wh": u(ks[4 + 3 * i], (d, 4 * d)),
-                        "b": u(ks[5 + 3 * i], (4 * d,)).at[d:2 * d].add(1.0)}
-                       for i in range(layers)],
-            "w_out": u(ks[1], (vocab, d)),
-            "b_out": u(ks[2], (vocab,))}
+def chunk_rows(vocab: int, chunk_bytes: int = HEAD_CHUNK_BYTES) -> int:
+    """Rows of (V+1) float32 logits that fit in ``chunk_bytes``."""
+    return max(1, chunk_bytes // (4 * (int(vocab) + 1)))
 
 
 def _mm(a, b):
@@ -73,49 +65,25 @@ def float8_weights(a):
     return q * scale
 
 
-def lower(w, v, control: str):
-    """Weights and route vectors at the control's precision."""
+def lower_route(v, control: str):
+    """The route vectors at the control's precision."""
     if control == "bfloat16":
-        return (jax.tree.map(lambda a: a.astype(jnp.bfloat16), w),
-                v.astype(jnp.bfloat16))
+        return v.astype(jnp.bfloat16)
     if control == "float8":
-        low = {"emb": float8_weights(w["emb"]),
-               "layers": [{"wx": float8_weights(p["wx"]),
-                           "wh": float8_weights(p["wh"]), "b": p["b"]}
-                          for p in w["layers"]],
-               "w_out": float8_weights(w["w_out"]), "b_out": w["b_out"]}
-        return low, float8_weights(v)
+        return float8_weights(v)
     raise ValueError(f"unknown control {control!r} (known: {CONTROLS})")
 
 
-CONTROLS = ("float8", "bfloat16")
-
-
-def trunk(w, tokens):
-    """Teacher-forced hidden states: tokens (B, T) → h (B, T, d)."""
-    x = w["emb"][tokens]
-    B = tokens.shape[0]
-    for p in w["layers"]:
-        d = p["wh"].shape[0]
-
-        def cell(carry, xt, p=p):
-            h, c = carry
-            gates = _mm(xt, p["wx"]) + _mm(h, p["wh"]) + p["b"]
-            i, f, g, o = jnp.split(gates, 4, axis=-1)
-            c = jax.nn.sigmoid(f) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
-            h = jax.nn.sigmoid(o) * jnp.tanh(c)
-            return (h, c), h
-
-        zero = jnp.zeros((B, d), x.dtype)
-        _, ys = jax.lax.scan(cell, (zero, zero), jnp.moveaxis(x, 0, 1))
-        x = jnp.moveaxis(ys, 0, 1)
-    return x
+def output_logits(w, h):
+    """h·Woutᵀ, plus bout where the model has an output bias."""
+    z = _mm(h, w["w_out"].T)
+    return z + w["b_out"] if "b_out" in w else z
 
 
 def _head(w, v, cand_words, h):
     """Logits (N, V+1) with a −inf column for padded candidate slots, route
     scores (N, r), and each cluster's best candidate logit (N, r)."""
-    z = _mm(h, w["w_out"].T) + w["b_out"]
+    z = output_logits(w, h)
     z = jnp.concatenate([z, jnp.full((z.shape[0], 1), -jnp.inf, z.dtype)], 1)
     s = _mm(h, v.T)
     best_c = jax.lax.map(lambda words: jnp.max(z[:, words], axis=-1),
@@ -123,31 +91,50 @@ def _head(w, v, cand_words, h):
     return z, s, best_c
 
 
-@partial(jax.jit, static_argnames=("screened", "control"))
-def _block(w, v, cand_words, tokens, targets, screened: bool,
-           control: Optional[str]):
+def _rows(fn, xs, rows: int):
+    """``fn`` over chunks of at most ``rows`` leading rows of the arrays
+    ``xs``, its outputs joined again; one call when they fit in one."""
+    n = xs[0].shape[0]
+    if n <= rows:
+        return fn(*xs)
+    k = -(-n // rows)
+    pad = [jnp.pad(x, [(0, k * rows - n)] + [(0, 0)] * (x.ndim - 1))
+           .reshape((k, rows) + x.shape[1:]) for x in xs]
+    out = jax.lax.map(lambda c: fn(*c), pad)
+    return jax.tree.map(lambda a: a.reshape((k * rows,) + a.shape[2:])[:n],
+                        out)
+
+
+def _block(trunk, lower, w, v, cand_words, tokens, targets, screened: bool,
+           control: Optional[str], rows: int):
     """One block of sequences. → dict of per-position arrays (B·T,)."""
     f32 = jax.tree.map(lambda a: a.astype(jnp.float32), w)
     h = trunk(f32, tokens).reshape(-1, f32["w_out"].shape[1])
-    z, s, best_c = _head(f32, v.astype(jnp.float32), cand_words, h)
+    v32 = v.astype(jnp.float32)
     tgt = targets.reshape(-1)
-    rows = jnp.arange(z.shape[0])
-    out = {"zmax": jnp.max(z, -1), "zarg": jnp.argmax(z, -1),
-           "z_served": z[rows, tgt], "s": s, "best_c": best_c}
     if control:
-        low, vl = lower(w, v, control)
+        low, vl = lower(w, control), lower_route(v, control)
         hl = trunk(low, tokens).reshape(h.shape)
-        zl, sl, _ = _head(low, vl, cand_words, hl)
-        if screened:
-            t = jnp.argmax(sl, -1)
-            words = cand_words[t]                          # (N, Cw)
-            pick = jnp.argmax(jnp.take_along_axis(zl, words, 1), -1)
-            tok = jnp.take_along_axis(words, pick[:, None], 1)[:, 0]
-        else:
-            tok = jnp.argmax(zl[:, :-1], -1)
-        out["ctl_tok"] = tok
-        out["z_ctl"] = z[rows, tok]
-    return out
+
+    def head(h, tgt, *hl_chunk):
+        z, s, best_c = _head(f32, v32, cand_words, h)
+        out = {"zmax": jnp.max(z, -1), "zarg": jnp.argmax(z, -1),
+               "z_served": jnp.take_along_axis(z, tgt[:, None], 1)[:, 0],
+               "s": s, "best_c": best_c}
+        if control:
+            zl, sl, _ = _head(low, vl, cand_words, hl_chunk[0])
+            if screened:
+                t = jnp.argmax(sl, -1)
+                words = cand_words[t]                          # (N, Cw)
+                pick = jnp.argmax(jnp.take_along_axis(zl, words, 1), -1)
+                tok = jnp.take_along_axis(words, pick[:, None], 1)[:, 0]
+            else:
+                tok = jnp.argmax(zl[:, :-1], -1)
+            out["ctl_tok"] = tok
+            out["z_ctl"] = jnp.take_along_axis(z, tok[:, None], 1)[:, 0]
+        return out
+
+    return _rows(head, (h, tgt) + ((hl,) if control else ()), rows)
 
 
 def candidate_words(cand_idx: np.ndarray, cand_len: np.ndarray, block: int,
@@ -166,15 +153,23 @@ def candidate_words(cand_idx: np.ndarray, cand_len: np.ndarray, block: int,
 class Reference:
     """Teacher-forced reference over served requests.
 
-    ``weights``: ``init_weights``' dict; ``screen``: None for the exact
-    head, else host copies {"v", "cand_idx", "cand_len", "block"}."""
+    ``family``: the configuration's family module; ``weights``: its
+    ``init_weights``' dict; ``screen``: None for the exact head, else host
+    copies {"v", "cand_idx", "cand_len", "block"}. The trunk runs over a
+    block of ``block_rows`` whole sequences at a time, the head over chunks
+    of at most ``chunk_rows(vocab, chunk_bytes)`` of its positions."""
 
-    def __init__(self, weights, screen, vocab: int, block_rows: int = 32,
-                 seq_len: int = 0):
+    def __init__(self, family, conf: dict, weights, screen, vocab: int,
+                 block_rows: int = 32, seq_len: int = 0,
+                 chunk_bytes: int = HEAD_CHUNK_BYTES):
         self.w = weights
         self.vocab = int(vocab)
         self.block_rows = int(block_rows)
         self.seq_len = int(seq_len)
+        self.rows = chunk_rows(self.vocab, chunk_bytes)
+        self._block = jax.jit(
+            partial(_block, partial(family.trunk, conf=conf), family.lower),
+            static_argnames=("screened", "control", "rows"))
         self.screened = screen is not None
         if self.screened:
             self.v = jnp.asarray(screen["v"], jnp.float32)
@@ -214,9 +209,9 @@ class Reference:
             for j, k in enumerate(blk):
                 n = len(seqs[k])
                 tok[j, :n], tgt[j, :n], msk[j, :n] = seqs[k], tgts[k], masks[k]
-            out = _block(self.w, self.v, cw, jnp.asarray(tok),
-                         jnp.asarray(tgt), screened=self.screened,
-                         control=control)
+            out = self._block(self.w, self.v, cw, jnp.asarray(tok),
+                              jnp.asarray(tgt), screened=self.screened,
+                              control=control, rows=self.rows)
             keep = msk.reshape(-1)
             part = {k: np.asarray(a)[keep] for k, a in out.items()}
             part["served"] = tgt.reshape(-1)[keep]

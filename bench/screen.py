@@ -6,8 +6,8 @@ A screen (paper §3) is r cluster centres v_t and a candidate set c_t per
 cluster; a context h routes to argmax_t v_t·h and is answered from c_t.
 Here it is made in one jitted call:
 
-1. contexts: the plain reference trunk (``reference.trunk``, float32 at
-   HIGHEST) over ``ctx_batches × ctx_batch`` sequences of ``ctx_len``
+1. contexts: the plain reference trunk (the family's ``trunk``, float32
+   at HIGHEST) over ``ctx_batches × ctx_batch`` sequences of ``ctx_len``
    tokens of the seeded corpus, every position's hidden state;
 2. labels: each context's ``label_topk`` best words under the reference's
    full softmax (the paper fits the screen to hold the full softmax's
@@ -36,17 +36,16 @@ def _mm(a, b):
     return jnp.matmul(a, b, precision=reference.HIGHEST)
 
 
-@partial(jax.jit, static_argnames=("r", "iters", "budget", "block", "topk"))
-def _fit(w, tokens, key, r: int, iters: int, budget: int, block: int,
+def _fit(trunk, w, tokens, key, r: int, iters: int, budget: int, block: int,
          topk: int):
     """tokens (n_batches, B, T) → (v (r, d) float32, blocks (r, budget))."""
     f32 = jax.tree.map(lambda a: a.astype(jnp.float32), w)
     d = f32["w_out"].shape[1]
     vocab = f32["w_out"].shape[0]
-    h = jax.lax.map(lambda t: reference.trunk(f32, t).reshape(-1, d), tokens)
+    h = jax.lax.map(lambda t: trunk(f32, t).reshape(-1, d), tokens)
     labels = jax.lax.map(
-        lambda hc: jax.lax.top_k(_mm(hc, f32["w_out"].T) + f32["b_out"],
-                                 topk)[1], h)
+        lambda hc: jax.lax.top_k(reference.output_logits(f32, hc), topk)[1],
+        h)
     h = h.reshape(-1, d)
     labels = labels.reshape(-1, topk)
     u = h / jnp.maximum(jnp.linalg.norm(h, axis=1, keepdims=True), 1e-30)
@@ -67,18 +66,23 @@ def _fit(w, tokens, key, r: int, iters: int, budget: int, block: int,
     return v, jnp.sort(blocks, axis=1).astype(jnp.int32)
 
 
-def fit(weights, conf: dict, corpus: traffic.Corpus, seed: int) -> dict:
-    """The screen as host arrays: {"v" (r, d) float32, "cand_idx" (r, C)
-    int32 block ids, "cand_len" (r,) int32, "block"}."""
+def fit(family, weights, conf: dict, corpus: traffic.Corpus, seed: int
+        ) -> dict:
+    """The screen of ``conf``'s model (``family``, its family module) as
+    host arrays: {"v" (r, d) float32, "cand_idx" (r, C) int32 block ids,
+    "cand_len" (r,) int32, "block"}."""
     sc = conf["screen"]
     rng = traffic.rng_for(seed, traffic.SALT_CONTEXTS)
     tokens = corpus.tokens(rng, sc["ctx_batches"] * sc["ctx_batch"],
                            sc["ctx_len"]).reshape(
         sc["ctx_batches"], sc["ctx_batch"], sc["ctx_len"])
     key = jax.random.key(traffic.seed32(seed, 101))
-    v, blocks = _fit(weights, jnp.asarray(tokens), key, r=sc["clusters"],
-                     iters=sc["kmeans_iters"], budget=sc["budget_blocks"],
-                     block=sc["block"], topk=sc["label_topk"])
+    fit_jit = jax.jit(partial(_fit, partial(family.trunk, conf=conf)),
+                      static_argnames=("r", "iters", "budget", "block",
+                                       "topk"))
+    v, blocks = fit_jit(weights, jnp.asarray(tokens), key, r=sc["clusters"],
+                        iters=sc["kmeans_iters"], budget=sc["budget_blocks"],
+                        block=sc["block"], topk=sc["label_topk"])
     blocks = np.array(blocks)
     return {"v": np.array(v, np.float32), "cand_idx": blocks,
             "cand_len": np.full(len(blocks), blocks.shape[1], np.int32),

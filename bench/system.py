@@ -3,25 +3,32 @@ benchmark's own screen (``screen.py``), the engine, and a scheduler over a
 page pool — the objects ``repro.launch.serve`` builds for ``--scheduler``,
 with the traffic's own width, pool and head.
 
+What a configuration's model family means to the benchmark (its weights,
+their place in the program's parameter tree, the reference trunk, the
+control's precision, the trunk's operations) lives in
+``bench/families/<family>.py``, found by the configuration's ``family``.
+
 This is the benchmark's only contact with the program's construction API;
 the harness drives what it returns through ``submit`` / ``step``.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 import time
 from pathlib import Path
 
 import jax
-import jax.numpy as jnp
 
-from bench import reference, screen, traffic
+from bench import screen, traffic
 
 BENCH = Path(__file__).resolve().parent
 CONFIG_DIR = BENCH / "configs"
 WORKLOAD_DIR = BENCH / "workloads"
+FAMILY_DIR = BENCH / "families"
 
-# The configuration's numbers that must equal the program's registry entry.
+# The configuration's numbers that must equal the program's registry entry,
+# beside its family's own ``checked_keys``.
 CHECKED_KEYS = ("num_layers", "d_model", "vocab_size", "dtype", "family")
 
 
@@ -33,13 +40,27 @@ def load_workload(name: str) -> dict:
     return json.loads((WORKLOAD_DIR / f"{name}.json").read_text())
 
 
+def load_family(name: str):
+    """``bench/families/<name>.py``, the module of a model family."""
+    path = FAMILY_DIR / f"{name}.py"
+    if not path.is_file():
+        known = sorted(f.stem for f in FAMILY_DIR.glob("*.py"))
+        raise ValueError(f"unknown model family {name!r}: no {path.name} in "
+                         f"{FAMILY_DIR} (known: {known})")
+    spec = importlib.util.spec_from_file_location(
+        "bench_family_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def model_config(conf: dict):
     """The program's ModelConfig for ``conf``, checked against the file."""
     from repro.configs import get_config
     cfg = get_config(conf["registry"])
     if conf.get("registry_reduced"):
         cfg = cfg.reduced()         # the program's own small variant (tests)
-    for key in CHECKED_KEYS:
+    for key in CHECKED_KEYS + tuple(load_family(conf["family"]).checked_keys):
         if getattr(cfg, key) != conf[key]:
             raise ValueError(f"{conf['name']}: {key} is {getattr(cfg, key)!r} "
                              f"in the program's registry, {conf[key]!r} in "
@@ -48,19 +69,11 @@ def model_config(conf: dict):
 
 
 def make_weights(conf: dict, seed: int):
-    """The served weights, on the device, in one jitted call."""
+    """The served weights, on the device, in one jitted call of the
+    family's ``init_weights``."""
+    family = load_family(conf["family"])
     key = jax.random.key(traffic.seed32(seed, 100))
-    fn = jax.jit(lambda k: reference.init_weights(
-        k, conf["vocab_size"], conf["d_model"], conf["num_layers"],
-        jnp.dtype(conf["dtype"])))
-    return fn(key)
-
-
-def program_params(w) -> dict:
-    """``init_weights``' arrays in the program's parameter layout."""
-    return {"embed": {"embedding": w["emb"], "lm_head": w["w_out"],
-                      "lm_bias": w["b_out"]},
-            "lstm": {"layers": [dict(p) for p in w["layers"]]}}
+    return jax.jit(lambda k: family.init_weights(k, conf))(key)
 
 
 def max_len(work: dict) -> int:
@@ -81,10 +94,11 @@ class System:
         self.conf, self.work, self.seed = conf, work, seed
         self.head = work["head"]
         t = time.perf_counter()
+        self.family = load_family(conf["family"])
         self.cfg = model_config(conf)
         self.model = build_model(self.cfg)
         self.weights = make_weights(conf, seed)
-        self.params = program_params(self.weights)
+        self.params = self.family.program_params(self.weights, conf)
         jax.block_until_ready(self.params)
         log(f"weights {time.perf_counter() - t:.2f} s")
         t = time.perf_counter()
@@ -94,8 +108,8 @@ class System:
         self.screen_host = None
         if self.head != "exact":
             t = time.perf_counter()
-            self.screen_host = screen.fit(self.weights, conf, self.corpus,
-                                          seed)
+            self.screen_host = screen.fit(self.family, self.weights, conf,
+                                          self.corpus, seed)
             self.screen = screen.program_screen(self.screen_host,
                                                 conf["vocab_size"])
             log(f"screen {time.perf_counter() - t:.2f} s "

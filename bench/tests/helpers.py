@@ -29,7 +29,10 @@ def small_conf(name: str = "nmt-deen-lstm") -> dict:
 def small_work(cell: str, limit: float = CPU_LIMIT) -> dict:
     work = copy.deepcopy(system.load_workload(cell))
     work.update(width=4, pool_pages=128, ramp_s=0.3, drain_s=30.0)
-    work["arrival"]["rate"] = 20.0
+    if work["arrival"]["law"] == "closed":
+        work["arrival"]["clients"] = work["width"]
+    else:
+        work["arrival"]["rate"] = 20.0
     work["prompt_len"].update(min=4, max=12, median=6)
     work["output_len"].update(min=2, max=12, median=6)
     work["check"].update(requests=32, block_rows=8)

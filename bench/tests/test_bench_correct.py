@@ -2,7 +2,8 @@
 chip: a sound run is correct; the control and each fault a served cell can
 have are not.
 
-Faults, each planted in the timed path underneath the harness:
+Faults, each planted in the timed path underneath the harness, on an open
+and on the closed loop:
   * a decode step that returns its recurrent state unchanged;
   * a token altered where it is produced (after the head, before commit).
 Each control, the reference computed at a lower precision (float8 weights,
@@ -17,7 +18,7 @@ import pytest
 from bench import control, harness, reference
 from bench.tests.helpers import CPU_LIMIT, run_small, small_conf, small_work
 
-CELLS = ["deen-l2s-poisson", "ptbl-l2s-nextword"]
+CELLS = ["deen-l2s-poisson", "ptbl-l2s-nextword", "deen-l2s-batch"]
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -38,7 +39,7 @@ def test_traced_run_reports_per_layer_metrics():
             "tick_ms"} == set(res["metrics"])
 
 
-def test_state_left_unchanged_is_caught(monkeypatch):
+def stick_the_state(monkeypatch):
     from repro.models.model import Model
     real = Model.decode_step
 
@@ -47,10 +48,9 @@ def test_state_left_unchanged_is_caught(monkeypatch):
         return h, cache
 
     monkeypatch.setattr(Model, "decode_step", stuck)
-    assert run_small("deen-l2s-poisson")["correct"] is False
 
 
-def test_altered_token_is_caught(monkeypatch):
+def alter_a_token(monkeypatch):
     from repro.serving.kvpool import stream
     real = stream.guard_tokens
 
@@ -60,7 +60,23 @@ def test_altered_token_is_caught(monkeypatch):
         return out
 
     monkeypatch.setattr(stream, "guard_tokens", altered)
+
+
+def test_state_left_unchanged_is_caught(monkeypatch):
+    stick_the_state(monkeypatch)
+    assert run_small("deen-l2s-poisson")["correct"] is False
+
+
+def test_altered_token_is_caught(monkeypatch):
+    alter_a_token(monkeypatch)
     assert run_small("ptbl-l2s-nextword")["correct"] is False
+
+
+@pytest.mark.parametrize("fault", [stick_the_state, alter_a_token],
+                         ids=["state", "token"])
+def test_closed_loop_faults_are_caught(monkeypatch, fault):
+    fault(monkeypatch)
+    assert run_small("deen-l2s-batch")["correct"] is False
 
 
 @pytest.mark.parametrize("precision", reference.CONTROLS)

@@ -54,6 +54,10 @@ def test_workload_file_loads(cell):
     assert system.max_len(work) % work["page_size"] == 0
     planned = traffic.plan(work, traffic.Corpus(64, 1), 5,
                            work["ramp_s"] + BENCH["run_seconds"])
+    if isinstance(planned, traffic.ClosedPlan):
+        assert planned.clients <= work["width"]
+        planned = [planned.request(c, k) for k in range(3)
+                   for c in range(planned.clients)]
     assert planned and all(
         work["prompt_len"]["min"] <= len(p.prompt) <= work["prompt_len"]["max"]
         for p in planned)

@@ -13,6 +13,18 @@ from bench.tests.helpers import small_conf
 SEED = 2 ** 31 + 99
 
 
+LSTM = system.load_family("lstm")
+
+
+def trunk(w, tokens, conf):
+    return LSTM.trunk(w, tokens, conf)
+
+
+def ref_of(w, conf, screen, **kw):
+    return reference.Reference(LSTM, conf, w, screen, conf["vocab_size"],
+                               **kw)
+
+
 @pytest.fixture(scope="module")
 def model_and_weights():
     from repro.models import build_model
@@ -23,12 +35,12 @@ def model_and_weights():
 
 def test_reference_matches_the_program(model_and_weights):
     model, w, conf = model_and_weights
-    params = system.program_params(w)
+    params = LSTM.program_params(w, conf)
     tokens = jnp.asarray(np.random.default_rng(0).integers(
         0, conf["vocab_size"], (3, 20)), jnp.int32)
     h_prog, _ = model.forward(params, {"tokens": tokens})
     z_prog = model.logits(params, h_prog)
-    h_ref = reference.trunk(w, tokens)
+    h_ref = trunk(w, tokens, conf)
     z_ref = h_ref @ w["w_out"].T + w["b_out"]
     # float32 on both sides; they differ by summation order alone
     tol = 1e-5
@@ -36,7 +48,7 @@ def test_reference_matches_the_program(model_and_weights):
     assert float(jnp.max(jnp.abs(z_prog - z_ref))) < tol
     # a bfloat16 trunk misses the same tolerance by orders of magnitude
     low = jax.tree.map(lambda a: a.astype(jnp.bfloat16), w)
-    h_low = reference.trunk(low, tokens).astype(jnp.float32)
+    h_low = trunk(low, tokens, conf).astype(jnp.float32)
     assert float(jnp.max(jnp.abs(h_low - h_ref))) > 100 * tol
 
 
@@ -57,15 +69,15 @@ def test_screened_gaps(model_and_weights):
     cand_len = np.array([2, 1, 1, 1], np.int32)
     screen = {"v": v, "cand_idx": cand_idx, "cand_len": cand_len,
               "block": 128}
-    ref = reference.Reference(w, screen, V, block_rows=2)
+    ref = ref_of(w, conf, screen, block_rows=2)
     prompts = [rng.integers(0, V, 6), rng.integers(0, V, 9)]
     served = [rng.integers(0, V, 4), rng.integers(0, V, 2)]
     out = ref.run(prompts, served)
     assert out["s"].shape == (6, 4)
     # the token the screened semantics picks has gap 0 on both numbers
     words = reference.candidate_words(cand_idx, cand_len, 128, V)
-    h = np.asarray(reference.trunk(w, jnp.asarray(
-        np.concatenate([prompts[0], served[0][:-1]])[None])))[0, 5:]
+    h = np.asarray(trunk(w, jnp.asarray(
+        np.concatenate([prompts[0], served[0][:-1]])[None]), conf))[0, 5:]
     z = h @ np.asarray(w["w_out"]).T + np.asarray(w["b_out"])
     s = h @ v.T
     picks = np.array([_brute_l2s(z[i], s[i], words) for i in range(4)])
@@ -88,7 +100,7 @@ def test_routing_near_tie_reads_as_a_route_gap(model_and_weights):
     screen = {"v": np.zeros((2, conf["d_model"]), np.float32),
               "cand_idx": np.array([[0, 0], [0, 1]], np.int32),
               "cand_len": np.array([1, 2], np.int32), "block": 128}
-    ref = reference.Reference(w, screen, conf["vocab_size"])
+    ref = ref_of(w, conf, screen)
     z_tok = np.array([2.0])
     out = {"s": np.array([[1.0, 1.001]]), "best_c": np.array([[2.0, 2.5]])}
     g = ref.gaps(out, np.array([5]), z_tok)
@@ -115,7 +127,7 @@ def test_float8_control_rounds_like_e4m3():
 
 def test_exact_gap_is_distance_to_the_best(model_and_weights):
     model, w, conf = model_and_weights
-    ref = reference.Reference(w, None, conf["vocab_size"], block_rows=1)
+    ref = ref_of(w, conf, None, block_rows=1)
     out = ref.run([np.arange(5)], [np.array([7, 8, 9])])
     g = ref.gaps(out, out["zarg"], out["zmax"])
     assert np.all(g["token_gap"] == 0)

@@ -6,8 +6,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference, screen, system, traffic
+from bench import screen, system, traffic
 from bench.tests.helpers import small_conf
+
+LSTM = system.load_family("lstm")
 
 
 @pytest.fixture(scope="module")
@@ -18,7 +20,8 @@ def conf():
 @pytest.mark.parametrize("seed", [2 ** 31 + 5, 2 ** 40 + 17])
 def test_screen_shape_is_fixed(conf, seed):
     w = system.make_weights(conf, seed)
-    s = screen.fit(w, conf, traffic.Corpus(conf["vocab_size"], seed), seed)
+    s = screen.fit(LSTM, w, conf, traffic.Corpus(conf["vocab_size"], seed),
+                   seed)
     sc = conf["screen"]
     n_blk = -(-conf["vocab_size"] // sc["block"])
     assert s["v"].shape == (sc["clusters"], conf["d_model"])
@@ -36,9 +39,9 @@ def test_screen_covers_the_reference_top1(conf):
     seed = 2 ** 31 + 11
     w = system.make_weights(conf, seed)
     corpus = traffic.Corpus(conf["vocab_size"], seed)
-    s = screen.fit(w, conf, corpus, seed)
+    s = screen.fit(LSTM, w, conf, corpus, seed)
     toks = corpus.tokens(traffic.rng_for(seed, 999), 8, 32)
-    h = np.asarray(reference.trunk(w, jnp.asarray(toks))).reshape(
+    h = np.asarray(LSTM.trunk(w, jnp.asarray(toks), conf)).reshape(
         -1, conf["d_model"])
     z = h @ np.asarray(w["w_out"]).T + np.asarray(w["b_out"])
     t = np.argmax(h @ s["v"].T, axis=1)

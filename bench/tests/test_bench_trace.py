@@ -6,7 +6,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import readout, trace_reduce
+from bench import readout, system, trace_reduce
 
 TESTDATA = Path(__file__).resolve().parent.parent / "testdata"
 
@@ -104,16 +104,22 @@ def test_step_mfu_over_device_busy_time():
         class planned:
             prompt = [0] * 10
 
+    steps = [{"name": "serve.step", "ph": "X", "tid": -1, "ts": ts,
+              "dur": 0.001, "args": {"id": i, "parent": None, "rids": [0]}}
+             for i, ts in enumerate((0.02, 0.03, 0.04))]
     art = {"trace": out, "win": {"tokens0": 0, "tokens1": 4},
            "tracer_events": [{"name": "join", "ph": "i", "tid": 0, "ts": 0.01,
-                              "args": {"join_s": 0.001}}],
+                              "args": {"join_s": 0.001}}] + steps,
            "book": type("B", (), {"t0": 0.0, "t1": 0.05})(),
            "records": {0: Rec}, "lbar": 1024.0, "screen_r": 50,
            "work": {"head": "screened-pallas"}, "chips": 1,
            "conf": {"d_model": 500, "num_layers": 2, "vocab_size": 25000},
-           "kind": "TPU v5 lite"}
+           "kind": "TPU v5 lite", "family": system.load_family("lstm")}
     # 10 prompt tokens + 3 decode steps of the trunk, 4 head queries, over
     # the 6 ms the device was busy
+    prompt, decode = readout.trunk_positions(art)
+    assert prompt.tolist() == list(range(10))
+    assert decode.tolist() == [10, 11, 12]
     flops = 13 * 2 * 8 * 500 ** 2 * 2 + 4 * 2 * (50 + 1024) * 500
     assert readout.step_mfu(art) == pytest.approx(
         100 * flops / (0.006 * 197e12))

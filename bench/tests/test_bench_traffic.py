@@ -2,6 +2,7 @@
 charges a stall to every request that came due during it."""
 from __future__ import annotations
 
+import hashlib
 from collections import Counter
 
 import numpy as np
@@ -152,3 +153,118 @@ def test_window_rate_counts_tokens_emitted_between_the_two_reads():
     from bench.harness import window_tokens_per_s
     win = {"t_open": 10.0, "t_close": 12.5, "tokens0": 400, "tokens1": 900}
     assert window_tokens_per_s(win) == 200.0
+
+
+# -- closed loop --------------------------------------------------------------
+
+CLOSED = dict(WORK, arrival={"law": "closed", "clients": 8},
+              output_len={"law": "lognormal", "median": 24, "sigma": 0.4,
+                          "min": 8, "max": 48})
+
+
+def closed_plan(seed, work=CLOSED):
+    return traffic.plan(work, traffic.Corpus(1000, seed), seed, 10.0)
+
+
+def test_closed_plan_is_seeded_and_repeatable():
+    a, b = closed_plan(BIG), closed_plan(BIG)
+    assert isinstance(a, traffic.ClosedPlan) and a.clients == 8
+    # drawn in another order, the same requests
+    for c, k in [(3, 5), (0, 0), (7, 2), (3, 0)]:
+        x, y = a.request(c, k), b.request(c, k)
+        assert x.idx == y.idx == 8 * k + c and a.client_of(x) == c
+        assert x.max_new == y.max_new and np.array_equal(x.prompt, y.prompt)
+    other = closed_plan(BIG + 1)
+    assert any(not np.array_equal(a.request(c, 0).prompt,
+                                  other.request(c, 0).prompt)
+               for c in range(8))
+
+
+def test_closed_rounds_hold_the_same_lengths_for_every_seed():
+    plans = [closed_plan(s) for s in (BIG, BIG + 1, 2 ** 40 + 3)]
+    for k in range(4):
+        rounds = [[p.request(c, k) for c in range(8)] for p in plans]
+        for key in (lambda r: len(r.prompt), lambda r: r.max_new):
+            counts = [Counter(key(r) for r in rnd) for rnd in rounds]
+            assert counts[0] == counts[1] == counts[2]
+    # the order within a round follows the seed
+    assert [len(plans[0].request(c, 0).prompt) for c in range(8)] != \
+        [len(plans[1].request(c, 0).prompt) for c in range(8)]
+
+
+class CountingScheduler(FakeScheduler):
+    """The fake scheduler, noting how many requests are outstanding at the
+    start of each tick."""
+
+    def __init__(self, clock):
+        super().__init__(clock, tick_s=0.01)
+        self.outstanding = []
+
+    def step(self):
+        self.outstanding.append((self.clock.t, len(self.queue)))
+        super().step()
+
+
+def test_closed_loop_keeps_n_outstanding():
+    clock = FakeClock()
+    sched = CountingScheduler(clock)
+    drv = harness.Driver(FakeSystem(), sched, closed_plan(BIG), seconds=2.0,
+                         ramp_s=0.5, drain_s=5.0, clock=clock,
+                         sleep=clock.sleep)
+    book = drv.run()
+    t1 = book.t1
+    before = [n for t, n in sched.outstanding if t < t1]
+    after = [n for t, n in sched.outstanding if t >= t1]
+    assert len(before) > 200 and set(before) == {8}
+    # once the window closes nobody sends again, and the rest drain
+    assert after == list(range(after[0], 0, -1))
+    # each client's requests go out in its own order, due when submitted
+    assert all(r.due <= r.submitted for r in book.records)
+    for c in range(8):
+        mine = [r.planned.idx for r in book.records if r.planned.idx % 8 == c]
+        assert mine == [8 * k + c for k in range(len(mine))]
+    assert not [r for r in book.records if r.failed]
+
+
+# digests of the planned requests (index, output length, prompt length, due
+# time, prompt tokens) the open loop made before the closed loop was added
+POISSON_PLANS = {
+    ("deen-l2s-poisson", 2147483700): (2014, "16aa9fbc83876d43"),
+    ("deen-l2s-poisson", 2 ** 40 + 3): (2014, "83c3ada641b5b5db"),
+    ("ptbl-l2s-nextword", 2147483700): (2120, "607d366c64929546"),
+    ("ptbl-l2s-nextword", 2 ** 40 + 3): (2120, "54fb5aec881ce64b"),
+}
+
+
+def plan_digest(planned) -> str:
+    m = hashlib.sha256()
+    for a in (np.array([(p.idx, p.max_new, len(p.prompt)) for p in planned]),
+              np.array([p.due for p in planned]),
+              np.concatenate([p.prompt for p in planned])):
+        m.update(str(a.dtype).encode())
+        m.update(str(a.shape).encode())
+        m.update(np.ascontiguousarray(a).tobytes())
+    return m.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("cell,seed", sorted(POISSON_PLANS))
+def test_poisson_plans_unchanged(cell, seed):
+    from bench import system
+    work = system.load_workload(cell)
+    conf = system.load_config(work["config"])
+    planned = traffic.plan(work, traffic.Corpus(conf["vocab_size"], seed),
+                           seed, 51.0)
+    assert (len(planned), plan_digest(planned)) == POISSON_PLANS[cell, seed]
+
+
+def test_poisson_requests_go_out_in_due_order():
+    clock = FakeClock()
+    sched = FakeScheduler(clock)
+    plan = schedule(BIG)
+    drv = harness.Driver(FakeSystem(), sched, plan, seconds=4.0, ramp_s=1.0,
+                         drain_s=5.0, clock=clock, sleep=clock.sleep)
+    book = drv.run()
+    due = sorted((p for p in plan if p.due < 5.0), key=lambda p: p.due)
+    assert [r.planned.idx for r in book.records] == [p.idx for p in due]
+    assert all(r.due == pytest.approx(100.0 + r.planned.due)
+               for r in book.records)

@@ -8,9 +8,12 @@ import subprocess
 import sys
 from pathlib import Path
 
+import numpy as np
 import pytest
 
-from bench import peaks, work
+from bench import peaks, system, work
+
+LSTM = system.load_family("lstm")
 
 ROOT = Path(__file__).resolve().parents[2]
 
@@ -24,7 +27,10 @@ ROOT = Path(__file__).resolve().parents[2]
 ])
 def test_macs_by_hand(d, layers, vocab, r, lbar, trunk_macs, exact_macs,
                       l2s_macs):
-    assert work.trunk_flops_per_token(d, layers) == 2 * trunk_macs
+    conf = {"d_model": d, "num_layers": layers}
+    assert LSTM.trunk_flops(conf, [0]) == 2 * trunk_macs
+    # the LSTM's trunk costs the same at every position
+    assert LSTM.trunk_flops(conf, [0, 7, 4095]) == 3 * 2 * trunk_macs
     assert work.head_macs("exact", vocab, d) == exact_macs
     assert work.head_macs("screened-pallas", vocab, d, r, lbar) == l2s_macs
 
@@ -32,8 +38,11 @@ def test_macs_by_hand(d, layers, vocab, r, lbar, trunk_macs, exact_macs,
 def test_model_flops_counts_each_trunk_step_once():
     # 2 requests: prompts 10 + 6 tokens, 4 + 3 outputs; the first output of
     # each comes from the prefill, so 16 + (7 - 2) trunk steps
-    f = work.model_flops(500, 2, 1000.0, prompt_tokens=16, output_tokens=7,
-                         joins=2)
+    conf = {"d_model": 500, "num_layers": 2}
+    prompts = np.concatenate([np.arange(10), np.arange(6)])
+    decodes = np.array([10, 11, 12, 6, 7])
+    f = work.model_flops(LSTM, conf, 1000.0, prompts, decodes,
+                         output_tokens=7)
     assert f == 21 * 8_000_000 + 7 * 2 * 1000.0
 
 

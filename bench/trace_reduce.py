@@ -9,7 +9,8 @@ breakdown the result line carries.
 
 ``ops`` are the events of each device plane's "XLA Ops" line (``label``
 holds the event's string stats, where the framework-level op name lives);
-``host`` are the harness's own ``TraceAnnotation`` spans (``bench.*``).
+``host`` are the harness's own ``TraceAnnotation`` spans (``bench.*``) and
+the program's (``serve.*``).
 ``reduce(trace, ...)`` works on that form only, so a trace in that form,
 recorded or made by hand, tests it without the chip.
 
@@ -34,7 +35,9 @@ import numpy as np
 
 DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):(\d+)$")
 OPS_LINE = "XLA Ops"
-HOST_PREFIX = "bench."
+# the harness's own spans and the program's (``serve.*``): an idle gap is
+# labelled with the innermost of them at its middle
+HOST_PREFIX = ("bench.", "serve.")
 LABEL_STATS = ("tf_op", "long_name", "hlo_op", "name", "kernel_details",
                "source")
 LAYOUT = re.compile(r"\{[^{}]*\}")

@@ -10,6 +10,11 @@ number of requests with the same multiset of prompt lengths, output
 lengths and gaps, so two seeds differ in which request comes when and in
 the tokens, never in how much work the window holds.
 
+Two arrival laws: ``poisson``, an open loop at a fixed rate, each request
+due at a planned time (``plan``); and ``closed``, a fixed pool of clients,
+each sending its next request as soon as its last one is back
+(``ClosedPlan``), so the server is always offered as much as it can take.
+
 The corpus is a first-order Zipf–Markov chain (the same shape as the
 program's ``ZipfMarkovCorpus``: Zipfian successor popularity, a small
 Dirichlet-skewed successor set per word), drawn vectorized so that a
@@ -31,10 +36,11 @@ def seed32(seed: int, salt: int = 0) -> int:
                .generate_state(1)[0])
 
 
-def rng_for(seed: int, salt: int) -> np.random.Generator:
-    """An independent numpy stream per purpose (``salt``) of one seed."""
+def rng_for(seed: int, salt: int, *more: int) -> np.random.Generator:
+    """An independent numpy stream per purpose (``salt``, and any further
+    whole numbers, such as a round) of one seed."""
     return np.random.default_rng(np.random.SeedSequence(
-        [int(seed) % (1 << 63), salt]))
+        [int(seed) % (1 << 63), salt, *more]))
 
 
 # purposes of the seed's streams (fixed: changing one changes the traffic)
@@ -134,15 +140,58 @@ def strata(start: float, span: float, stratum: Optional[float]):
     return [(start + i * span / k, span / k) for i in range(k)]
 
 
-def plan(work: dict, corpus: Corpus, seed: int, seconds: float
-         ) -> List[Planned]:
-    """The cell's requests, in the order they are offered: open loop
-    (``arrival.law == "poisson"``), ``rate·ramp_s`` arrivals in the ramp and
+class ClosedPlan:
+    """A closed loop (``arrival.law == "closed"``): ``arrival.clients``
+    clients, each with its own sequence of requests. The k-th request of
+    every client makes round k, the closed loop's stratum: its prompt and
+    output lengths are the fixed sets of ``clients`` lengths in a seeded
+    order, so every round holds the same work for every seed. A round is
+    drawn from streams of the seed of its own the first time a client
+    reaches it, so how many rounds a run uses changes none of them.
+    Request k of client c has ``idx`` k·clients + c; its due time is its
+    submission's, set by the driver."""
+
+    def __init__(self, work: dict, corpus: Corpus, seed: int):
+        self.clients = int(work["arrival"]["clients"])
+        if self.clients < 1:
+            raise ValueError(f"a closed loop needs clients, got "
+                             f"{self.clients}")
+        self.p_len = Lengths.from_dict(work["prompt_len"])
+        self.o_len = Lengths.from_dict(work["output_len"])
+        self.corpus, self.seed = corpus, seed
+        self._rounds: Dict[int, List[Planned]] = {}
+
+    def request(self, client: int, k: int) -> Planned:
+        """Client ``client``'s ``k``-th request (from 0)."""
+        if k not in self._rounds:
+            self._rounds[k] = self._draw(k)
+        return self._rounds[k][client]
+
+    def client_of(self, p: Planned) -> int:
+        return p.idx % self.clients
+
+    def _draw(self, k: int) -> List[Planned]:
+        n = self.clients
+        order = rng_for(self.seed, SALT_ORDER, k)
+        pl = order.permutation(self.p_len.fixed_set(n))
+        ol = order.permutation(self.o_len.fixed_set(n))
+        toks = self.corpus.tokens(rng_for(self.seed, SALT_PROMPTS, k), n,
+                                  int(self.p_len.max))
+        return [Planned(idx=k * n + c, prompt=toks[c, :int(pl[c])],
+                        max_new=int(ol[c]), due=math.nan) for c in range(n)]
+
+
+def plan(work: dict, corpus: Corpus, seed: int, seconds: float):
+    """The cell's requests. Open loop (``arrival.law == "poisson"``): a list
+    in the order they are offered, ``rate·ramp_s`` arrivals in the ramp and
     ``rate·seconds`` in the window. Each phase, or with ``stratum_s`` each
     stratum of about that many seconds of it, has its own fixed sets of
     gaps and lengths in a seeded order, so the window, and each stratum,
-    holds the same requests' worth of work for every seed."""
+    holds the same requests' worth of work for every seed. Closed loop
+    (``"closed"``): a ``ClosedPlan``."""
     arr = work["arrival"]
+    if arr["law"] == "closed":
+        return ClosedPlan(work, corpus, seed)
     if arr["law"] != "poisson":
         raise ValueError(f"unknown arrival law {arr['law']!r}")
     p_len = Lengths.from_dict(work["prompt_len"])

@@ -4,19 +4,14 @@ Counted from the configuration and the fitted screen, never from the
 implementation, so a PR that does the same work in fewer operations shows
 as a gain and one that skips work cannot hide it.
 
-Trunk: each LSTM layer computes gates = x·Wx + h·Wh + b with Wx, Wh of
-shape (d, 4d): 2·4d² multiply-adds = 16·d² operations per layer and token.
-Head: the exact softmax scores every word, L·d multiply-adds; the screened
+Trunk: the family's ``trunk_flops(conf, positions)`` (``bench/families/``),
+for a token at each position the trunk runs. Head: the exact softmax scores every word, L·d multiply-adds; the screened
 head scores the r cluster centres and the L̄ words of the routed candidate
 set, (r + L̄)·d.
 """
 from __future__ import annotations
 
 import numpy as np
-
-
-def trunk_flops_per_token(d: int, layers: int) -> float:
-    return 2.0 * 8 * d * d * layers
 
 
 def head_macs(head: str, vocab: int, d: int, r: int = 0,
@@ -32,15 +27,16 @@ def mean_candidate_words(cand_len, block: int) -> float:
     return float(np.mean(np.asarray(cand_len, np.float64))) * block
 
 
-def model_flops(d: int, layers: int, head_macs_per_query: float,
-                prompt_tokens: int, output_tokens: int, joins: int) -> float:
-    """Operations the served work required: every prompt token runs the
-    trunk once; every output token runs the head once; every output token
-    after a request's first runs one more trunk step (the first comes
-    straight from the prefill's last state)."""
-    trunk = trunk_flops_per_token(d, layers)
-    decode_steps = max(output_tokens - joins, 0)
-    return (prompt_tokens + decode_steps) * trunk \
+def model_flops(family, conf: dict, head_macs_per_query: float,
+                prompt_positions, decode_positions, output_tokens: int
+                ) -> float:
+    """Operations the served work required: the trunk once for every prompt
+    token and for every decode step's input token, each at its position
+    (``family.trunk_flops``); the head once for every output token. A
+    request's first output token comes straight from the prefill's last
+    state, so it has no decode step of its own."""
+    return family.trunk_flops(conf, prompt_positions) \
+        + family.trunk_flops(conf, decode_positions) \
         + output_tokens * 2.0 * head_macs_per_query
 
 
