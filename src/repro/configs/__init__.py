@@ -1,6 +1,7 @@
 """Architecture config registry: ``get_config("<arch-id>")``.
 
-Assigned pool (10 archs) + the paper's own LSTM language models.
+Assigned pool (10 archs), the paper's own LSTM language models, and one
+chip's share of Moonlight-16B-A3B (latent attention, held experts).
 """
 from __future__ import annotations
 
@@ -26,6 +27,7 @@ from repro.configs.zamba2_2p7b import CONFIG as _zamba2_2p7b
 from repro.configs.qwen15_110b import CONFIG as _qwen15_110b
 from repro.configs.mamba2_1p3b import CONFIG as _mamba2_1p3b
 from repro.configs.mixtral_8x7b import CONFIG as _mixtral_8x7b
+from repro.configs.moonlight_16b_a3b import CONFIG as _moonlight
 from repro.configs.ptb_lstm import PTB_SMALL as _ptb_small, PTB_LARGE as _ptb_large
 from repro.configs.nmt_deen import CONFIG as _nmt_deen
 
@@ -42,6 +44,7 @@ REGISTRY = {
         _qwen15_110b,
         _mamba2_1p3b,
         _mixtral_8x7b,
+        _moonlight,
         _ptb_small,
         _ptb_large,
         _nmt_deen,

@@ -3,7 +3,12 @@
 Every assigned architecture gets one ``ModelConfig`` in ``repro/configs/<id>.py``
 registered under its ``--arch`` id. ``ModelConfig.reduced()`` produces the
 small CPU-smoke-test variant of the same family (≤2 layers, d_model ≤ 512,
-≤4 experts) mandated by the brief.
+≤4 experts) mandated by the brief. It keeps every mechanism the family has:
+latent attention (MLA) keeps its split of query and key into a position-free
+and a rotary part and its normalised latent, at small widths; a leading
+dense layer stays first; an expert layer told which experts it holds keeps
+holding a share of them (one of the four), its routing's scaling and its
+shared experts.
 """
 from __future__ import annotations
 
@@ -24,6 +29,22 @@ class MoEConfig:
     capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
     router_jitter: float = 0.0
+    # gates of the held-expert layer (below) are scaled by this after their
+    # normalisation (DeepSeek-V3's routed_scaling_factor)
+    routed_scale: float = 1.0
+    # width of one routed expert (None: the model's d_ff) and the number of
+    # shared experts every token passes through (together one SwiGLU of
+    # num_shared × expert width)
+    d_ff: Optional[int] = None
+    num_shared: int = 0
+    # this chip's share of an expert-parallel deployment: it holds experts
+    # [held_offset, held_offset + held) of num_experts (num_experts // held
+    # chips share each expert layer). Set for the mla_moe stack, whose
+    # expert layer is the dropless held-expert layer with DeepSeek-V3
+    # routing (layers/moe.py); None: every expert, the capacity-bounded
+    # layer
+    held: Optional[int] = None
+    held_offset: int = 0
 
 
 @dataclass(frozen=True)
@@ -40,7 +61,7 @@ class SSMConfig:
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    # arch family: dense | moe | ssm | hybrid | vlm | audio | lstm
+    # arch family: dense | moe | mla_moe | ssm | hybrid | vlm | audio | lstm
     family: str
     num_layers: int
     d_model: int
@@ -71,16 +92,49 @@ class ModelConfig:
     source: str = ""
     # dtype for params/activations in dry-runs
     dtype: str = "bfloat16"
+    # latent attention (MLA, arXiv:2405.04434 §2.1; mla_moe family): keys
+    # and values come from a normalised kv_lora_rank latent; query and key
+    # heads are a position-free part (qk_nope_head_dim) and a rotary part
+    # (qk_rope_head_dim, one key head shared by all). The query is
+    # projected directly from x (no query compression: q_lora_rank null)
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # leading dense layers (MLP of width d_ff) before the expert layers
+    first_k_dense: int = 0
+    # RMSNorm epsilon of the mla_moe stack (other families use the norm's
+    # default)
+    norm_eps: float = 1e-6
 
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // max(self.num_heads, 1))
-        assert self.family in ("dense", "moe", "ssm", "hybrid", "vlm", "audio", "lstm"), self.family
+        assert self.family in ("dense", "moe", "mla_moe", "ssm", "hybrid",
+                               "vlm", "audio", "lstm"), self.family
         if self.num_heads:
             assert self.num_heads % max(self.num_kv_heads, 1) == 0, (
                 f"{self.name}: heads {self.num_heads} not divisible by kv {self.num_kv_heads}")
 
     # -- derived -----------------------------------------------------------
+    @property
+    def mla_latent_dim(self) -> int:
+        """Width of one token's cached latent: normalised c plus the rotary
+        key, shared by every head."""
+        return (self.kv_lora_rank or 0) + self.qk_rope_head_dim
+
+    # the expert settings by the names a configuration file gives them
+    num_experts = property(lambda s: s.moe.num_experts if s.moe else 0)
+    experts_per_token = property(lambda s: s.moe.top_k if s.moe else 0)
+    expert_d_ff = property(
+        lambda s: (s.moe.d_ff or s.d_ff) if s.moe else 0)
+    num_shared_experts = property(lambda s: s.moe.num_shared if s.moe else 0)
+    routed_scaling_factor = property(
+        lambda s: s.moe.routed_scale if s.moe else 1.0)
+    experts_held = property(
+        lambda s: (s.moe.held or s.moe.num_experts) if s.moe else 0)
+    expert_offset = property(lambda s: s.moe.held_offset if s.moe else 0)
+
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // max(self.num_kv_heads, 1)
@@ -110,6 +164,21 @@ class ModelConfig:
             # 2-layer LSTM: per layer 4 * (in + hidden + 1) * hidden
             for li in range(nl):
                 n += 4 * (d + d + 1) * d
+            return n
+        if self.family == "mla_moe":
+            # parameters held on this chip: every layer's MLA and norms, the
+            # leading dense MLPs, and per expert layer the router, its
+            # correction bias, the held experts and the shared experts
+            H, r = self.num_heads, self.kv_lora_rank
+            attn = (d * H * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+                    + d * self.mla_latent_dim + r
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * d + 2 * d)
+            expert = 3 * d * self.expert_d_ff
+            n_moe = nl - self.first_k_dense
+            n += nl * attn + self.first_k_dense * 3 * d * ff + d
+            n += n_moe * ((d + 1) * self.num_experts + expert * (
+                self.experts_held + self.num_shared_experts))
             return n
         per_layer_attn = (
             d * self.num_heads * hd            # Wq
@@ -172,7 +241,23 @@ class ModelConfig:
             dtype="float32",
         )
         if self.moe is not None:
-            kw["moe"] = replace(self.moe, num_experts=min(self.moe.num_experts, 4))
+            m = self.moe
+            E = min(m.num_experts, 4)
+            mk = dict(num_experts=E)
+            if m.held is not None:
+                held = max(1, E * m.held // m.num_experts)
+                mk.update(top_k=min(m.top_k, 2), held=held,
+                          held_offset=min(m.held_offset, E - held))
+            if m.d_ff is not None:
+                mk["d_ff"] = min(m.d_ff, 64)
+            kw["moe"] = replace(m, **mk)
+        if self.kv_lora_rank is not None:
+            kw.update(kv_lora_rank=min(self.kv_lora_rank, 32),
+                      qk_nope_head_dim=min(self.qk_nope_head_dim, 16),
+                      qk_rope_head_dim=min(self.qk_rope_head_dim, 8),
+                      v_head_dim=min(self.v_head_dim, 16))
+        if self.first_k_dense:
+            kw["first_k_dense"] = 1
         if self.ssm is not None:
             kw["ssm"] = replace(self.ssm, state_dim=min(self.ssm.state_dim, 16),
                                 head_dim=16, chunk=16, expand=2)

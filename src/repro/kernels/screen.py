@@ -34,18 +34,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.mode import interpret_mode
+from repro.utils.precision import split_terms
 
 V_BLK = 128
 
 
 def tile_logits(w_ref, h_ref, b_ref) -> jnp.ndarray:
     """(1, V_BLK) f32 logits of one gathered tile: h (1, d) · W_tileᵀ + b.
-    Shared by this kernel and the fused top-k kernel, so both compute the
-    same bits for the same tile."""
-    acc = jax.lax.dot_general(
-        h_ref[0], w_ref[0],                           # (1, d) · (V_BLK, d)ᵀ
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    Narrower (bfloat16) tiles meet h as its terms (``split_terms``, with
+    the rounding Mosaic can lower), so the logits keep float32's accuracy;
+    float32 tiles take h as it is. Shared by this kernel and the fused
+    top-k kernel, so both compute the same bits for the same tile."""
+    def dot(h):                                       # (1, d) · (V_BLK, d)ᵀ
+        return jax.lax.dot_general(
+            h, w_ref[0], dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    h = h_ref[0]
+    if w_ref.dtype == h.dtype:
+        acc = dot(h)
+    else:
+        acc = 0.0
+        for t in split_terms(h, w_ref.dtype, in_kernel=True):
+            acc = acc + dot(t)
     return acc + b_ref[0].astype(jnp.float32)
 
 

@@ -18,7 +18,9 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.layers.initializers import dense_init
+from repro.layers.norms import rmsnorm
 from repro.layers.rope import apply_mrope, apply_rope
+from repro.utils.precision import split_terms
 
 NEG_INF = -1e30
 
@@ -310,3 +312,159 @@ def attn_decode_paged(params, x1, pk, pv, page_table, pos, cfg: ModelConfig):
     out = _sdpa(q, ck, cv, valid[:, None, :], cfg)
     out = jnp.einsum("bthk,hkd->btd", out, params["wo"])
     return out, pk, pv
+
+
+# -- latent attention (MLA) ---------------------------------------------------
+#
+# DeepSeek-V2 (arXiv:2405.04434 §2.1) without query compression:
+#   q = x·W_q, split per head into q_nope and q_rope;
+#   [c, k_rope] = x·W_kva;  c′ = RMSNorm(c);  [k_nope, v] = c′·W_kvb;
+#   RoPE on q_rope and on k_rope, one rotary key shared by every head;
+#   score = (q_nope·k_nope + q_rope·k_rope) / √(nope + rope).
+# RoPE layout: split halves (``rope.apply_rope``). The published code
+# de-interleaves the rotary channels first; with random weights that is a
+# fixed permutation of W_q's and W_kva's rotary columns.
+# The cache holds [c′, k_rope] per token (``cfg.mla_latent_dim`` wide).
+# Prefill decompresses keys and values from c′; decode absorbs W_kvb's key
+# half into the query and its value half into the output and attends over
+# the cached latent itself. Activations and the cache are float32; the
+# products between activations run at HIGHEST and those with weights
+# through ``wdot``.
+
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def wdot(spec: str, x, w):
+    """``einsum(spec, x, w)`` in float32 with weights of any dtype. A
+    float32 ``x`` meets narrower (bfloat16) weights as its terms
+    (``utils.precision.split_terms``) in one product, so the weights are
+    read once and the result keeps float32's accuracy. One expert choice
+    that differs from a float32 computation's changes a token's state in
+    every later layer and every later token's attention."""
+    x = x.astype(jnp.float32)
+    if w.dtype == jnp.float32:
+        return jnp.einsum(spec, x, w)
+    xs, rest = spec.split(",", 1)
+    out = jnp.einsum("z" + xs + "," + rest.replace("->", "->z"),
+                     jnp.stack(split_terms(x, w.dtype)), w,
+                     preferred_element_type=jnp.float32)
+    return out.sum(axis=0)
+
+
+def mla_init(key, cfg: ModelConfig, dtype=jnp.float32):
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+
+    def init(k, shape, fan_in):
+        return dense_init(k, shape, dtype, scale=fan_in ** -0.5)
+
+    return {"wq": init(ks[0], (d, H, nope + rope), d),
+            "wkv_a": init(ks[1], (d, r + rope), d),
+            "kv_norm": {"scale": jnp.ones((r,), dtype)},
+            "wkv_b": init(ks[2], (r, H, nope + vd), r),
+            "wo": init(ks[3], (H, vd, d), H * vd)}
+
+
+def _mla_query(params, x, cfg: ModelConfig, positions):
+    """(q_nope (B, T, H, nope), rotated q_rope (B, T, H, rope)), float32."""
+    q = wdot("btd,dhk->bthk", x, params["wq"])
+    nope = cfg.qk_nope_head_dim
+    return q[..., :nope], apply_rope(q[..., nope:], positions, cfg.rope_theta)
+
+
+def mla_latent(params, x, cfg: ModelConfig, positions):
+    """What the cache holds for each token of ``x``: [c′, rotated k_rope],
+    (B, T, kv_lora_rank + rope) float32."""
+    kv = wdot("btd,dr->btr", x, params["wkv_a"])
+    r = cfg.kv_lora_rank
+    c = rmsnorm(params["kv_norm"], kv[..., :r], cfg.norm_eps)
+    k_rope = apply_rope(kv[..., None, r:], positions, cfg.rope_theta)
+    k_rope = k_rope[..., 0, :]
+    return jnp.concatenate([c, k_rope], axis=-1)
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+
+
+def mla_forward(params, x, cfg: ModelConfig, positions):
+    """Causal MLA over a whole sequence in the decompressed form.
+    x (B, T, d) → (out (B, T, d) float32, latent (B, T, r + rope))."""
+    with jax.named_scope("mla"):
+        q_nope, q_rope = _mla_query(params, x, cfg, positions)
+        lat = mla_latent(params, x, cfg, positions)
+        r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+        kv = wdot("bsr,rhk->bshk", lat[..., :r], params["wkv_b"])
+        k_nope, v = kv[..., :nope], kv[..., nope:]
+        scores = (jnp.einsum("bthk,bshk->bhts", q_nope, k_nope,
+                             precision=HIGHEST)
+                  + jnp.einsum("bthk,bsk->bhts", q_rope, lat[..., r:],
+                               precision=HIGHEST)) * _mla_scale(cfg)
+        T = x.shape[1]
+        mask = make_mask(T, T, causal=True)
+        probs = jax.nn.softmax(jnp.where(mask, scores, NEG_INF), axis=-1)
+        o = jnp.einsum("bhts,bshk->bthk", probs, v, precision=HIGHEST)
+        return wdot("bthk,hkd->btd", o, params["wo"]), lat
+
+
+def mla_attend_latent(params, x1, latent, valid, pos, cfg: ModelConfig):
+    """One query token per row attending over cached latents in the
+    absorbed form. x1 (B, 1, d); latent (B, S, r + rope) holding every
+    position the row may see, the row's own included; valid (B, S) bool;
+    pos (B,) int32. → (B, 1, d) float32."""
+    with jax.named_scope("mla"):
+        q_nope, q_rope = _mla_query(params, x1, cfg, pos[:, None])
+        r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+        w_k = params["wkv_b"][..., :nope]                    # (r, H, nope)
+        w_v = params["wkv_b"][..., nope:]                    # (r, H, v)
+        q_c = wdot("bthk,rhk->bthr", q_nope, w_k)
+        lat = latent.astype(jnp.float32)
+        scores = (jnp.einsum("bthr,bsr->bhts", q_c, lat[..., :r],
+                             precision=HIGHEST)
+                  + jnp.einsum("bthk,bsk->bhts", q_rope, lat[..., r:],
+                               precision=HIGHEST)) * _mla_scale(cfg)
+        scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        o_c = jnp.einsum("bhts,bsr->bthr", probs, lat[..., :r],
+                         precision=HIGHEST)
+        o = wdot("bthr,rhk->bthk", o_c, w_v)
+        return wdot("bthk,hkd->btd", o, params["wo"])
+
+
+def mla_decode(params, x1, cache, layer, pos, cfg: ModelConfig):
+    """One-token decode against the contiguous latent cache of every layer,
+    (L, B, S, r + rope); ``layer`` selects this layer's; pos (B,) int32
+    per-row positions (an idle row parked past the end clamps onto the last
+    slot, like ``attn_decode``). → (out (B, 1, d), new cache)."""
+    B, S = cache.shape[1:3]
+    pvec = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    lat1 = mla_latent(params, x1, cfg, pvec[:, None])
+    cache = cache.at[layer, jnp.arange(B), jnp.minimum(pvec, S - 1)].set(
+        lat1[:, 0].astype(cache.dtype))
+    valid = jnp.arange(S)[None, :] <= pvec[:, None]
+    return mla_attend_latent(params, x1, cache[layer], valid, pvec,
+                             cfg), cache
+
+
+def mla_decode_paged(params, x1, pool, layer, page_table, pos,
+                     cfg: ModelConfig):
+    """One-token decode against the latent pages of every layer, (L,
+    N_pages, P, r + rope), addressed as ``attn_decode_paged`` addresses K/V
+    pages: this ``layer``'s new latent of each row is written at
+    (page_table[row, pos // P], pos % P), and the row attends over its
+    gathered pages, positions past ``pos`` masked. Writing into and reading
+    from the whole pool, never a copy of one layer's slice, keeps the
+    step's pool traffic to the rows it touches. → (out (B, 1, d), pool)."""
+    B = x1.shape[0]
+    pvec = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    P = pool.shape[2]
+    n_pages = page_table.shape[1]
+    S = n_pages * P
+    lat1 = mla_latent(params, x1, cfg, pvec[:, None])
+    page = page_table[jnp.arange(B), jnp.minimum(pvec // P, n_pages - 1)]
+    pool = pool.at[layer, page, pvec % P].set(lat1[:, 0].astype(pool.dtype))
+    view = pool[layer, page_table].reshape(B, S, pool.shape[-1])
+    valid = jnp.arange(S)[None, :] <= pvec[:, None]
+    return mla_attend_latent(params, x1, view, valid, pvec, cfg), pool

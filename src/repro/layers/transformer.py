@@ -7,6 +7,9 @@ standard production pattern. Per-layer params are stacked on a leading L axis.
 Block families:
   * dense/vlm/audio: pre-norm attention + pre-norm MLP
   * moe: pre-norm attention + pre-norm MoE
+  * mla_moe: pre-norm latent attention (MLA) + pre-norm MLP for the
+    leading ``first_k_dense`` layers, run one by one, then pre-norm MLA +
+    the held-expert MoE for the rest, scanned (``_mla_run``)
   * ssm (mamba2): pre-norm SSD block only
   * hybrid (zamba2): SSD layers with ONE weight-shared attention+MLP block
     applied every ``hybrid_shared_period`` layers (scan over super-blocks)
@@ -21,10 +24,13 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.layers.attention import (attn_decode, attn_decode_paged,
                                     attn_forward, attn_forward_kv, attn_init,
-                                    init_cache as attn_init_cache)
+                                    init_cache as attn_init_cache,
+                                    mla_decode, mla_decode_paged, mla_forward,
+                                    mla_init)
 from repro.layers.mlp import mlp_apply, mlp_init
-from repro.layers.moe import moe_apply, moe_init
-from repro.layers.norms import norm_apply, norm_init
+from repro.layers.moe import (gated_ffn, moe_apply, moe_held_apply,
+                              moe_held_init, moe_init)
+from repro.layers.norms import norm_apply, norm_init, rmsnorm
 from repro.layers.ssm import (ssm_decode_step, ssm_forward, ssm_init,
                               ssm_init_cache)
 from repro.utils.shard import shard_batch
@@ -70,6 +76,8 @@ def shared_block_init(key, cfg: ModelConfig, dtype=jnp.float32):
 
 
 def stack_init(key, cfg: ModelConfig, dtype=jnp.float32):
+    if cfg.family == "mla_moe":
+        return _mla_stack_init(key, cfg, dtype)
     keys = jax.random.split(key, cfg.num_layers + 1)
     blocks = _stack_layers([block_init(keys[i], cfg, dtype)
                             for i in range(cfg.num_layers)])
@@ -103,6 +111,10 @@ def stack_forward(params, x, cfg: ModelConfig, positions,
     """
     if cfg.family in ("ssm", "hybrid"):
         return _ssm_stack_forward(params, x, cfg, remat=remat)
+    if cfg.family == "mla_moe":
+        h, _ = _mla_run(params, x, cfg, lambda p, x, st, l: (
+            mla_forward(p, x, cfg, positions)[0], st), None)
+        return h, jnp.float32(0.0)
 
     def body(carry, p):
         x, aux = carry
@@ -162,6 +174,13 @@ def stack_prefill(params, x, cfg: ModelConfig, positions, cache,
     T = x.shape[1]
     if cfg.family in ("ssm", "hybrid"):
         return _ssm_stack_prefill(params, x, cfg, cache, window)
+    if cfg.family == "mla_moe":
+        def attend(p, x, c, layer):
+            out, lat = mla_forward(p, x, cfg, positions)
+            return out, jax.lax.dynamic_update_slice(
+                c, lat[None].astype(c.dtype), (layer, 0, 0, 0))
+        h, lat = _mla_run(params, x, cfg, attend, cache["latent"])
+        return h, {"latent": lat}
 
     w = window if window is not None else cfg.sliding_window
 
@@ -247,6 +266,9 @@ def stack_init_cache(cfg: ModelConfig, batch: int, max_len: int,
                      dtype=jnp.bfloat16, window: Optional[int] = None):
     """Stacked per-layer caches (leading L axis) + shared-block caches."""
     L = cfg.num_layers
+    if cfg.family == "mla_moe":
+        return {"latent": jnp.zeros((L, batch, max_len, cfg.mla_latent_dim),
+                                    dtype)}
     if cfg.family in ("ssm", "hybrid"):
         one = ssm_init_cache(cfg, batch, dtype)
         cache = {"ssm": jax.tree_util.tree_map(
@@ -270,6 +292,10 @@ def stack_decode(params, x1, cache, pos, cfg: ModelConfig,
     """One-token decode through the stack. x1: (B, 1, d)."""
     if cfg.family in ("ssm", "hybrid"):
         return _ssm_stack_decode(params, x1, cache, pos, cfg, window)
+    if cfg.family == "mla_moe":
+        h, lat = _mla_run(params, x1, cfg, lambda p, x, c, layer: mla_decode(
+            p, x, c, layer, pos, cfg), cache["latent"])
+        return h, {"latent": lat}
 
     def body2(x, xs):
         p, c = xs
@@ -303,9 +329,15 @@ def stack_decode_paged(params, x1, pool, page_table, pos, cfg: ModelConfig):
     same op order — with ``attn_decode_paged`` swapped in for the cache
     update, which is what keeps paged greedy tokens bit-identical to the
     contiguous path (see attn_decode_paged)."""
+    if cfg.family == "mla_moe":
+        h, lat = _mla_run(params, x1, cfg, lambda p, x, c, layer:
+                          mla_decode_paged(p, x, c, layer, page_table, pos,
+                                           cfg), pool["latent"])
+        return h, {"latent": lat}
     if cfg.family not in ("dense", "moe"):
         raise NotImplementedError(
-            f"paged decode supports dense/moe stacks, not {cfg.family}")
+            f"paged decode supports dense/moe/mla_moe stacks, not "
+            f"{cfg.family}")
 
     def body(x, xs):
         p, pk, pv = xs
@@ -323,6 +355,69 @@ def stack_decode_paged(params, x1, pool, page_table, pos, cfg: ModelConfig):
                                (params["blocks"], pool["k"], pool["v"]))
     x = norm_apply(params["final_norm"], x, cfg.norm)
     return x, {"k": nk, "v": nv}
+
+
+# -- latent attention over held experts (mla_moe) -----------------------------
+#
+# params: {"dense": [block] * first_k_dense, "blocks": the expert layers'
+# blocks stacked on a leading axis, "final_norm"}; a block is {"norm1",
+# "attn" (MLA), "norm2", "mlp" | "moe"}. Cache and pages hold one latent
+# array with a leading axis over all layers, dense ones first:
+# {"latent": (L, B, S, r + rope)} or {"latent": (L, N_pages, P, r + rope)}.
+
+def _mla_block_init(key, cfg: ModelConfig, dtype, expert: bool):
+    k1, k2 = jax.random.split(key)
+    p = {"norm1": norm_init(cfg.d_model, "rmsnorm", dtype),
+         "attn": mla_init(k1, cfg, dtype),
+         "norm2": norm_init(cfg.d_model, "rmsnorm", dtype)}
+    if expert:
+        p["moe"] = moe_held_init(k2, cfg, dtype)
+    else:
+        p["mlp"] = mlp_init(k2, cfg, dtype)
+    return p
+
+
+def _mla_stack_init(key, cfg: ModelConfig, dtype):
+    nd, L = cfg.first_k_dense, cfg.num_layers
+    keys = jax.random.split(key, L)
+    return {"dense": [_mla_block_init(keys[i], cfg, dtype, False)
+                      for i in range(nd)],
+            "blocks": _stack_layers([_mla_block_init(keys[i], cfg, dtype,
+                                                     True)
+                                     for i in range(nd, L)]),
+            "final_norm": norm_init(cfg.d_model, "rmsnorm", dtype)}
+
+
+def _mla_run(params, x, cfg: ModelConfig, attend, state):
+    """The mla_moe layers over x (B, T, d), in a float32 residual stream:
+    each layer is ``x + attend(p["attn"], norm(x), state, layer)`` then the
+    MLP or the held experts; ``attend`` returns (out, state) and ``state``
+    (the latent cache or pages of every layer, or None) is carried through
+    every layer, so each layer writes its rows in place.
+    → (final-normed h, state)."""
+    def norm(p, x):
+        return rmsnorm(p, x, cfg.norm_eps)
+
+    def layer(p, x, state, l):
+        a, state = attend(p["attn"], norm(p["norm1"], x), state, l)
+        h = x + a
+        y = moe_held_apply(p["moe"], norm(p["norm2"], h), cfg) \
+            if "moe" in p else gated_ffn(p["mlp"], norm(p["norm2"], h))
+        return h + y, state
+
+    x = x.astype(jnp.float32)
+    for l, p in enumerate(params["dense"]):
+        x, state = layer(p, x, state, l)
+
+    def body(carry, xs):
+        x, state = layer(xs[0], *carry, xs[1])
+        return (x, state), None
+
+    nd = len(params["dense"])
+    (x, state), _ = jax.lax.scan(
+        body, (x, state),
+        (params["blocks"], nd + jnp.arange(cfg.num_layers - nd)))
+    return norm(params["final_norm"], x), state
 
 
 def _ssm_stack_decode(params, x1, cache, pos, cfg: ModelConfig, window):

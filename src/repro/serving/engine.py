@@ -112,9 +112,15 @@ class DecodeEngine:
         self._jit_lstm_join = jax.jit(
             lambda p, ints, src, group: _lstm_join(model, p, ints, src,
                                                    group))
+        # the paged attention join as one executable (see _attn_join): the
+        # prompt prefilled at its bucket, its rows written into the pages
+        self._jit_attn_join = jax.jit(
+            lambda p, tokens, n, pool, dest: _attn_join(model, p, tokens, n,
+                                                        pool, dest),
+            donate_argnums=(3,))
         self._jit_decode_paged = jax.jit(
-            lambda p, tok, pk, pv, table, pos: model.decode_step_paged(
-                p, tok, {"k": pk, "v": pv}, table, pos))
+            lambda p, tok, pool, table, pos: model.decode_step_paged(
+                p, tok, pool, table, pos))
         self.head = self.resolve_head("exact" if head is None else head)
 
     # -- head resolution ----------------------------------------------------
@@ -131,7 +137,8 @@ class DecodeEngine:
         return head.prepare()
 
     # -- per-head jitted steps (built once, cached) --------------------------
-    def _head_jit(self, heads: Sequence[SoftmaxHead], step, n_placed: int):
+    def _head_jit(self, heads: Sequence[SoftmaxHead], step, n_placed: int,
+                  donate: Optional[int] = None):
         """jit a composed step ``step(*heads, *args)`` → ``fn(*args)``.
 
         The heads' arrays (``device_state()``) enter the executable as
@@ -147,17 +154,24 @@ class DecodeEngine:
         single-device arrays (e.g. the prefill cache) reshard instead of
         erroring; once outputs come back mesh-placed, the device_put is a
         no-op. The jitted callable is built ONCE here and cached like every
-        other step — no per-step re-jitting. ``fn.lower(*args)`` lowers it."""
+        other step — no per-step re-jitting. ``fn.lower(*args)`` lowers it.
+
+        ``donate``: the index of the one arg whose buffers the step
+        consumes (a paged step's pool, updated in place instead of
+        copied); the caller uses the step's output in its place."""
         heads = tuple(h.prepare() for h in heads)
         state = tuple(h.device_state() for h in heads)
 
-        def traced(state, args):
+        def traced(state, args, donated):
+            if donate is not None:
+                args = (*args[:donate], donated, *args[donate + 1:])
             return step(*(h.with_state(s) for h, s in zip(heads, state)),
                         *args)
 
+        donate_argnums = () if donate is None else (2,)
         mesh = next((h.mesh for h in heads if h.mesh is not None), None)
         if mesh is None:
-            jitted = jax.jit(traced)
+            jitted = jax.jit(traced, donate_argnums=donate_argnums)
 
             def place(args):
                 return args
@@ -168,16 +182,23 @@ class DecodeEngine:
             state = jax.tree.map(
                 lambda a: a if a.sharding.device_set == repl.device_set
                 else jax.device_put(a, repl), state)
-            jitted = jax.jit(traced, in_shardings=(
-                jax.tree.map(lambda a: a.sharding, state), repl))
+            jitted = jax.jit(traced, donate_argnums=donate_argnums,
+                             in_shardings=(jax.tree.map(lambda a: a.sharding,
+                                                        state), repl, repl))
 
             def place(args):
                 return (*jax.device_put(args[:n_placed], repl),
                         *args[n_placed:])
 
+        def split(args):
+            args = place(args)
+            if donate is None:
+                return args, ()
+            return (*args[:donate], None, *args[donate + 1:]), args[donate]
+
         def fn(*args):
-            return jitted(state, place(args))
-        fn.lower = lambda *args: jitted.lower(state, place(args))
+            return jitted(state, *split(args))
+        fn.lower = lambda *args: jitted.lower(state, *split(args))
         fn._inner_jit = jitted
         return fn
 
@@ -305,23 +326,25 @@ class DecodeEngine:
     def _paged_greedy_step(self, head: SoftmaxHead):
         """Composed (decode over pool pages + head.next) step, cached under
         ``(head.step_key(), "greedy-paged")`` with the same LRU/meshing
-        discipline as ``_greedy_step``. Signature:
-        ``fn(params, tok, pk, pv, table, pos) -> (next, h, pk, pv)``."""
+        discipline as ``_greedy_step``. ``pool`` is the store's arrays
+        (``PagedKVStore.arrays``), donated: the step writes its rows in
+        place and the caller keeps the returned pool. Signature:
+        ``fn(params, tok, pool, table, pos) -> (next, h, pool)``."""
         key = (head.step_key(), "greedy-paged")
         if key not in self._step_cache:
             if head.is_jittable:
-                def step(hd, params, tok, pk, pv, table, pos):
+                def step(hd, params, tok, pool, table, pos):
                     h, pool = self.model.decode_step_paged(
-                        params, tok, {"k": pk, "v": pv}, table, pos)
-                    return hd.next(h), h, pool["k"], pool["v"]
-                fn = self._head_jit((head,), step, n_placed=4)
+                        params, tok, pool, table, pos)
+                    return hd.next(h), h, pool
+                fn = self._head_jit((head,), step, n_placed=3, donate=2)
             else:
-                def fn(params, tok, pk, pv, table, pos):
-                    h, pool = self._jit_decode_paged(params, tok, pk, pv,
+                def fn(params, tok, pool, table, pos):
+                    h, pool = self._jit_decode_paged(params, tok, pool,
                                                      table, pos)
                     nxt = jnp.asarray(np.asarray(head.next(np.asarray(h))),
                                       jnp.int32)
-                    return nxt, h, pool["k"], pool["v"]
+                    return nxt, h, pool
             self._put_step(key, fn)
         else:
             self._step_cache.move_to_end(key)       # LRU hit → most recent
@@ -337,21 +360,20 @@ class DecodeEngine:
             self._step_cache.move_to_end(key)       # LRU hit → most recent
         if key not in self._step_cache:
             if head.is_jittable:
-                def step(hd, params, rkey, tok, pk, pv, table, pos):
+                def step(hd, params, rkey, tok, pool, table, pos):
                     h, pool = self.model.decode_step_paged(
-                        params, tok, {"k": pk, "v": pv}, table, pos)
-                    return (hd.sample(rkey, h, temperature, top_p), h,
-                            pool["k"], pool["v"])
-                fn = self._head_jit((head,), step, n_placed=5)
+                        params, tok, pool, table, pos)
+                    return hd.sample(rkey, h, temperature, top_p), h, pool
+                fn = self._head_jit((head,), step, n_placed=4, donate=3)
             else:
-                def fn(params, rkey, tok, pk, pv, table, pos):
-                    h, pool = self._jit_decode_paged(params, tok, pk, pv,
+                def fn(params, rkey, tok, pool, table, pos):
+                    h, pool = self._jit_decode_paged(params, tok, pool,
                                                      table, pos)
                     nxt = jnp.asarray(
                         np.asarray(head.sample(rkey, np.asarray(h),
                                                temperature, top_p)),
                         jnp.int32)
-                    return nxt, h, pool["k"], pool["v"]
+                    return nxt, h, pool
             self._put_step(key, fn)
         return self._step_cache[key]
 
@@ -868,6 +890,32 @@ def _lstm_join(model, params, ints, src, group):
     group = {"lstm": [{key: g[key].at[slot].set(f[key][0]) for key in g}
                       for g, f in zip(group["lstm"], final)]}
     return final[-1]["h"], snaps, group
+
+
+def _attn_join(model, params, tokens, n, pool, dest):
+    """One paged attention join on the device: the prompt prefilled solo at
+    its bucket and its rows written into the pool's pages.
+
+    ``tokens`` (1, T): the prompt padded to its bucket T; ``n``: its real
+    length. Padding lies after the prompt, so the causal mask keeps it out
+    of every real position. ``pool``: the store's arrays (a leading layer
+    axis, then pages); ``dest`` (2, T) int32: the page and the in-page row
+    that position t is written to, the page out of range (the pool's page
+    count) for a position not to be written (the padding, and the prompt's
+    shared prefix pages). Returns (h_last (1, d): the top layer's state at
+    the prompt's last position; the pool with those rows written). ``pool``
+    is donated, its rows written in place: the caller keeps the returned
+    pool whatever becomes of the join (a join that fails later wrote only
+    its own private pages, which its rollback frees)."""
+    dtype = jax.tree.leaves(pool)[0].dtype
+    cache = model.init_cache(1, tokens.shape[1], dtype=dtype)
+    h, cache = model.prefill(params, {"tokens": tokens}, cache)
+    solo = cache.get("attn", cache)
+    page, row = dest[0], dest[1]
+    pool = {k: a.at[:, page, row].set(solo[k][:, 0].astype(a.dtype),
+                                      mode="drop")
+            for k, a in pool.items()}
+    return jax.lax.dynamic_slice_in_dim(h[0], n - 1, 1), pool
 
 
 def _reorder_cache(cache, src_idx, cfg):

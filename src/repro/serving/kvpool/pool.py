@@ -14,11 +14,12 @@ The pool is deliberately split from physical storage:
     suite drives through thousands of random alloc/share/COW/free
     sequences — no arrays, no jit, just the invariants;
   * ``bind(engine)`` attaches the model-specific substance: a device-side
-    ``PagedKVStore`` for attention families (k/v pool tensors the paged
-    decode step scatters into), or nothing for the LSTM family, whose
-    "pages" are logical accounting over recurrent-state snapshots held by
-    the radix cache (see radix.py) — admission and telemetry stay uniform
-    across families either way.
+    ``PagedKVStore`` for attention families (k/v pool tensors, or the
+    latent pages of the MLA family, that the paged decode step scatters
+    into), or nothing for the LSTM family, whose "pages" are logical
+    accounting over recurrent-state snapshots held by the radix cache
+    (see radix.py) — admission and telemetry stay uniform across families
+    either way.
 
 Page 0 is RESERVED as the trash page: idle stream slots park their page
 table entries (and their per-step scatter writes) there, so the decode
@@ -179,7 +180,7 @@ class PagePool:
                 f"{engine.max_len} (the paged view must have the dense "
                 f"cache's exact shape for bit-identical decode)")
         cfg = engine.model.cfg
-        if cfg.family in ("dense", "moe"):
+        if cfg.family in ("dense", "moe", "mla_moe"):
             if cfg.sliding_window is not None:
                 raise NotImplementedError(
                     "paged KV does not support sliding-window (ring) "
@@ -189,7 +190,7 @@ class PagePool:
                                       engine.cache_dtype)
         elif cfg.family != "lstm":
             raise NotImplementedError(
-                f"paged KV supports lstm/dense/moe families, not "
+                f"paged KV supports lstm/dense/moe/mla_moe families, not "
                 f"{cfg.family} ({cfg.name})")
         if self.radix is None:
             from repro.serving.kvpool.radix import RadixCache
@@ -201,9 +202,10 @@ class PagePool:
     # -- telemetry -------------------------------------------------------------
     def bytes_per_page(self) -> int:
         """HBM bytes one resident page costs. Attention families: the
-        store's per-page K/V rows. LSTM: the recurrent-state snapshot a
-        cached page carries (2 * L * d floats) — its pages are logical, so
-        this is the accounting rate for residency, not a tensor stride."""
+        store's per-page K/V (or latent) rows. LSTM: the recurrent-state
+        snapshot a cached page carries (2 * L * d floats) — its pages are
+        logical, so this is the accounting rate for residency, not a tensor
+        stride."""
         if self.store is not None:
             return self.store.bytes_per_page
         eng = self._engine

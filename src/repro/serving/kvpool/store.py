@@ -1,26 +1,30 @@
-"""Device-side paged KV storage for attention-family models.
+"""Device-side paged storage for attention-family models.
 
-One pair of pool tensors per engine, shared by every paged stream:
+One set of pool arrays per engine, shared by every paged stream, each with
+a leading layer axis and then the pages (``arrays``):
 
-    k, v : (L, N_pages, P, KV, head_dim)    in the engine's cache dtype
+    dense / moe :  k, v    (L, N_pages, P, KV, head_dim)
+    mla_moe     :  latent  (L, N_pages, P, kv_lora_rank + qk_rope_head_dim)
 
-The paged decode step (``attn_decode_paged``) scatter-writes each row's
-new token at ``(page_table[row, pos // P], pos % P)`` and gathers
-``k[layer][page_table]`` back into a dense ``(B, n_pages * P, KV, hd)``
-view — shaped EXACTLY like the contiguous cache when ``page_size`` divides
-``max_len``, which is what keeps paged greedy decode bit-identical to the
-dense path (stale rows beyond ``pos`` are masked to exact zeros either
-way; see layers/attention.py).
+in the engine's cache dtype. The MLA family caches one normalised latent
+and one rotary key per token, shared by every head, instead of per-head K
+and V.
 
-Host-side mutation (join-time prompt writes, COW copies) goes through
-functional ``.at[].set`` updates that replace the whole pool tensor — XLA
-copies the buffer, which is fine at serving-test scale; on real TPUs the
-step's donated pool args and an in-place scatter kernel would remove the
-copies without changing any value.
+The paged decode step (``attn_decode_paged`` / ``mla_decode_paged``)
+scatter-writes each row's new token at ``(page_table[row, pos // P],
+pos % P)`` and gathers the row's pages back into a dense
+``(B, n_pages * P, ...)`` view — shaped EXACTLY like the contiguous cache
+when ``page_size`` divides ``max_len``, which is what keeps paged greedy
+decode bit-identical to the dense path (stale rows beyond ``pos`` are
+masked to exact zeros either way; see layers/attention.py). A join writes
+its prompt's rows inside its own executable (``engine._attn_join``).
+
+The paged step and the join take the pool arrays donated and write their
+rows in place. A COW copy (``copy_page``) still goes through a functional
+``.at[].set`` that replaces the whole pool array — XLA copies the buffer,
+which is fine for one page per join.
 """
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,64 +33,45 @@ from repro.configs.base import ModelConfig
 
 
 class PagedKVStore:
-    """Physical page storage (+ per-page copy/write helpers) for one
-    engine's dense/moe attention stack."""
+    """Physical page storage (+ a per-page copy helper) for one engine's
+    dense, moe or mla_moe attention stack."""
 
     def __init__(self, cfg: ModelConfig, num_pages: int, page_size: int,
                  dtype=jnp.float32):
-        if cfg.family not in ("dense", "moe"):
+        lead = (cfg.num_layers, num_pages, page_size)
+        if cfg.family == "mla_moe":
+            self.arrays = {"latent": jnp.zeros(lead + (cfg.mla_latent_dim,),
+                                               dtype)}
+        elif cfg.family in ("dense", "moe"):
+            shape = lead + (cfg.num_kv_heads, cfg.head_dim)
+            self.arrays = {"k": jnp.zeros(shape, dtype),
+                           "v": jnp.zeros(shape, dtype)}
+        else:
             raise NotImplementedError(
-                f"PagedKVStore supports dense/moe stacks, not {cfg.family}")
-        shape = (cfg.num_layers, num_pages, page_size,
-                 cfg.num_kv_heads, cfg.head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
+                f"PagedKVStore supports dense/moe/mla_moe stacks, not "
+                f"{cfg.family}")
+        self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self._sharding = None
 
     @property
     def nbytes(self) -> int:
-        return int(self.k.nbytes + self.v.nbytes)
+        return int(sum(a.nbytes for a in self.arrays.values()))
 
     @property
     def bytes_per_page(self) -> int:
-        L, N = self.k.shape[:2]
-        return int((self.k.nbytes + self.v.nbytes) // N)
+        return self.nbytes // self.num_pages
 
     def place(self, sharding) -> None:
-        """Pin the pool tensors to a mesh sharding (replicated) so
+        """Pin the pool arrays to a mesh sharding (replicated) so
         mesh-aware paged steps and host-side updates stay on one device
         set. Idempotent per sharding."""
         if sharding is not None and self._sharding is not sharding:
-            self.k = jax.device_put(self.k, sharding)
-            self.v = jax.device_put(self.v, sharding)
+            self.arrays = jax.device_put(self.arrays, sharding)
             self._sharding = sharding
 
     def copy_page(self, src: int, dst: int) -> None:
         """COW substance: duplicate every layer's rows of ``src`` into
         ``dst`` (the new sole-holder page)."""
-        self.k = self.k.at[:, dst].set(self.k[:, src])
-        self.v = self.v.at[:, dst].set(self.v[:, src])
-
-    def write_prompt(self, pages, solo_cache, first_page: int = 0) -> None:
-        """Scatter a solo (B=1) prefilled cache's rows into ``pages``.
-
-        ``pages[j]`` receives dense rows ``[j*P, (j+1)*P)`` for every
-        layer; only pages from index ``first_page`` on are written (earlier
-        grid slots are shared prefix pages another request already owns —
-        rewriting them would race other streams for no value). Rows past
-        the prompt length carry the solo cache's zero-init — finite, and
-        masked until the stream's own decode overwrites them."""
-        P = self.page_size
-        n = len(pages)
-        if first_page >= n:
-            return
-        sel = jnp.asarray(pages[first_page:], jnp.int32)
-        k1, v1 = solo_cache["k"], solo_cache["v"]       # (L, 1, S, KV, hd)
-        lo, hi = first_page * P, n * P
-        rows_k = k1[:, 0, lo:hi].reshape(
-            k1.shape[0], n - first_page, P, *k1.shape[3:])
-        rows_v = v1[:, 0, lo:hi].reshape(
-            v1.shape[0], n - first_page, P, *v1.shape[3:])
-        self.k = self.k.at[:, sel].set(rows_k.astype(self.k.dtype))
-        self.v = self.v.at[:, sel].set(rows_v.astype(self.v.dtype))
+        self.arrays = {k: a.at[:, dst].set(a[:, src])
+                       for k, a in self.arrays.items()}

@@ -5,15 +5,19 @@
 compile discipline) with per-stream contiguous caches replaced by a chain
 of pool pages per slot:
 
-  * ATTENTION families (dense/moe): K/V rows live in the engine-wide
-    ``PagedKVStore``; each slot owns a page chain and the batched step runs
-    ``decode_step_paged`` over (pool tensors, page table, positions). The
-    gathered paged view has the dense cache's exact shape (``page_size``
-    divides ``max_len``), identical values at every unmasked position, and
-    the identical keep-mask — greedy tokens are bit-identical to the
-    contiguous path. Prefix reuse is STORAGE sharing: fully-covered prompt
-    pages are shared by reference; the join still prefills solo (the
-    first-token bit-identity guarantee), writing only its private pages.
+  * ATTENTION families (dense/moe/mla_moe): K/V rows (or MLA latents)
+    live in the engine-wide ``PagedKVStore``; each slot owns a page chain
+    and the batched step runs ``decode_step_paged`` over (pool arrays, page
+    table, positions). The gathered paged view has the dense cache's exact
+    shape (``page_size`` divides ``max_len``), identical values at every
+    unmasked position, and the identical keep-mask. Prefix reuse is
+    STORAGE sharing: fully-covered prompt pages are shared by reference;
+    the join still prefills the whole prompt solo, padded to a bucket (a
+    power of two of pages), and writes only its real positions on its
+    private pages, in one dispatch (``engine._attn_join``), so a stream
+    compiles one join per bucket, not one per prompt length. The ``moe``
+    family's capacity follows the prefill's length, so its joins prefill
+    at the prompt's own length (``bucket``).
 
   * LSTM family (the paper's architecture): decode carries no per-token
     KV, so pages are LOGICAL accounting (uniform admission / telemetry /
@@ -97,6 +101,9 @@ class PagedDecodeStream:
         self.tracer = NULL_TRACER
         self.vocab = int(engine.W.shape[0])
         self.family = engine.model.cfg.family
+        # experts this chip holds per expert layer (MoE families), for the
+        # step spans
+        self.experts_held = engine.model.cfg.experts_held
         self.max_pages = engine.max_len // pool.page_size
         self._repl = None
         if self.head.mesh is not None:
@@ -272,10 +279,25 @@ class PagedDecodeStream:
             self.cache = cache
         return first
 
+    def bucket(self, n: int) -> int:
+        """The padded length a join of an ``n``-token prompt prefills at:
+        its pages rounded up to a power of two, at most ``max_len``. A
+        capacity-bounded expert layer (``moe`` family) sizes its capacity
+        from the prefill's length, so padding would keep tokens that the
+        contiguous prefill drops: its joins stay at the prompt's length."""
+        if self.family == "moe":
+            return n
+        P = self.pool.page_size
+        pages = 1 << max(0, -(-n // P) - 1).bit_length()
+        return min(pages * P, self.engine.max_len)
+
     def _join_attn(self, request, toks, match, held) -> int:
-        """Solo full prefill (first-token bit-identity), storage-shared
-        full prefix pages, private pages scatter-written for the rest.
-        Returns the first token."""
+        """Storage-shared full prefix pages, private pages for the rest,
+        then one dispatch: the prompt prefilled solo at its bucket, its
+        real positions past the shared pages written into the private
+        pages, in place (the pool is donated, so the returned one is kept at
+        once; a join that faults later wrote only pages its rollback frees,
+        and freed pages' rows are never read). Returns the first token."""
         eng, pool = self.engine, self.pool
         P, Tp = pool.page_size, len(toks)
         n_prompt = (Tp + P - 1) // P
@@ -292,26 +314,28 @@ class PagedDecodeStream:
             # for join's rollback)
             held.append(pool.retain(match.chain[-1][0]))
             held[-1] = pool.cow(held[-1])
-        tr = self.tracer
+        while len(held) < n_prompt:
+            held.append(pool.alloc())
+        store, tr = pool.store, self.tracer
         with tr.scope("serve.join.prefill", "join"):
-            cache1 = eng.model.init_cache(1, eng.max_len,
-                                          dtype=eng.cache_dtype)
-            h, cache1 = eng._jit_prefill(
-                eng.params,
-                {"tokens": jnp.asarray(np.asarray(request.prompt)[None])},
-                cache1)
-            h_last = h[:, -1]
+            T = self.bucket(Tp)
+            t = np.arange(T)
+            own = (t >= j0 * P) & (t < Tp)
+            dest = np.stack([np.full(T, store.num_pages), t % P]).astype(
+                np.int32)
+            dest[0, own] = np.asarray(held, np.int32)[t[own] // P]
+            tokens = np.zeros((1, T), np.int32)
+            tokens[0, :Tp] = request.prompt
+            args = (eng.params, tokens, np.int32(Tp), store.arrays, dest)
+            if self._repl is not None:
+                args = jax.device_put(args, self._repl)
+            h_last, store.arrays = eng._jit_attn_join(*args)
+            if tr.enabled:
+                tr.note(prompt_len=Tp, bucket=T)
         if tr.enabled:
             tr.note(cached_tokens=j0 * P, chunks=1)
         first = self._first_token(h_last)
         with tr.scope("serve.join.splice", "join"):
-            while len(held) < n_prompt:
-                held.append(pool.alloc())
-            if self._repl is not None:
-                cache1 = jax.device_put(cache1, self._repl)
-            if j0 < n_prompt:
-                pool.store.write_prompt(held[:n_prompt], cache1["attn"],
-                                        first_page=j0)
             pool.radix.insert(toks, held[:n_prompt])
             pool.radix.record(j0 * P, Tp)
         return first
@@ -346,6 +370,8 @@ class PagedDecodeStream:
             if tr.enabled:
                 tr.note(head=self.head_name, active=len(idx),
                         rids=[_tag_id(self.slots[i].tag) for i in idx])
+                if self.experts_held:
+                    tr.note(experts_held=self.experts_held)
             with tr.scope("serve.step.pages", "step"):
                 self._ensure_pages(idx)
             out = self._finished
@@ -353,12 +379,14 @@ class PagedDecodeStream:
             eng = self.engine
             tok = jnp.asarray(self.tok)
             pos = jnp.asarray(self.pos)
-            # compute into locals and commit (cache / pool tensors, PRNG)
-            # only after the guard — a step fault advances nothing, so a
-            # retry re-runs the identical step (pages grown by _ensure_pages
-            # stay in their chains and are simply reused, same as the
-            # PoolExhausted retry contract)
-            key = cache = new_k = new_v = store = None
+            # compute into locals and commit (cache, PRNG) only after the
+            # guard — a step fault advances nothing, so a retry re-runs the
+            # identical step (pages grown by _ensure_pages stay in their
+            # chains and are simply reused, same as the PoolExhausted retry
+            # contract). The page pool is donated and kept at once: the
+            # step wrote each row's entry at its own position, which a
+            # retry rewrites with the same values
+            key = cache = None
             if self.family == "lstm":
                 # the SAME cached dense step DecodeStream uses — the paged
                 # LSTM path adds zero step executables by construction
@@ -377,12 +405,12 @@ class PagedDecodeStream:
                     fn = eng._paged_sample_step(self.head, self.temperature,
                                                 self.top_p)
                     key, ki = jax.random.split(self._key)
-                    nxt, _, new_k, new_v = fn(eng.params, ki, tok, store.k,
-                                              store.v, table, pos)
+                    nxt, _, store.arrays = fn(eng.params, ki, tok,
+                                              store.arrays, table, pos)
                 else:
                     fn = eng._paged_greedy_step(self.head)
-                    nxt, _, new_k, new_v = fn(eng.params, tok, store.k,
-                                              store.v, table, pos)
+                    nxt, _, store.arrays = fn(eng.params, tok, store.arrays,
+                                              table, pos)
             with tr.scope("serve.step.wait", "step"):
                 nxt = np.asarray(nxt)
             nxt = guard_tokens(self.fault_injector, "step", self.head_name,
@@ -391,8 +419,6 @@ class PagedDecodeStream:
                 self._key = key
             if self.family == "lstm":
                 self.cache = cache
-            else:
-                store.k, store.v = new_k, new_v
             for i in idx:
                 s = self.slots[i]
                 t = int(nxt[i])

@@ -81,3 +81,22 @@ def test_param_counts_match_published():
         assert abs(got - n) / n < 0.15, (arch, got, n)
     # MoE active params
     assert get_config("mixtral-8x7b").active_param_count() < 14e9
+
+
+def test_moonlight_is_one_chips_share():
+    """Moonlight-16B-A3B's published widths, with the cut stated beside
+    them: 9 of 27 layers, 8 of 64 routed experts held (8 chips share each
+    expert layer)."""
+    cfg = get_config("moonlight-16b-a3b-ep8")
+    assert (cfg.d_model, cfg.num_heads, cfg.vocab_size, cfg.d_ff) == \
+        (2048, 16, 163_840, 11_264)
+    assert (cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+            cfg.v_head_dim) == (512, 128, 64, 128)
+    assert (cfg.num_experts, cfg.experts_per_token, cfg.expert_d_ff,
+            cfg.num_shared_experts) == (64, 6, 1408, 2)
+    assert cfg.routed_scaling_factor == 2.446
+    assert (cfg.num_layers, cfg.first_k_dense) == (9, 1)
+    assert cfg.num_experts // cfg.experts_held == 8
+    assert cfg.mla_latent_dim == 576 and not cfg.tie_embeddings
+    # 1.56 G parameters held here: 3.1 GB in bfloat16
+    assert 1.5e9 < cfg.param_count() < 1.6e9

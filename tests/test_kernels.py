@@ -180,3 +180,31 @@ def test_interpret_mode_follows_backend(monkeypatch):
     monkeypatch.setattr(mode.jax, "default_backend", lambda: "cpu")
     assert mode.interpret_mode() is True
     assert mode.interpret_mode(False) is False
+
+
+def test_bfloat16_tiles_keep_float32_accuracy():
+    """A float32 query against bfloat16 tiles: the kernel meets the tile
+    with the query's three bfloat16 terms, so its logits are those of the
+    exact product with the rounded weights to float32's accuracy, and the
+    layers' product (``wdot``), which splits through the same helper,
+    gives the same logits. One bfloat16 rounding of the query would be off
+    by ~2⁻⁹ of each product's terms, far outside the tolerance."""
+    from repro.layers.attention import wdot
+    rng = np.random.default_rng(5)
+    n_blk, d, B, K = 6, 256, 3, 4
+    W = jnp.asarray(rng.standard_normal((n_blk, 128, d)), jnp.bfloat16)
+    bb = jnp.zeros((n_blk, 128), jnp.float32)
+    h = jnp.asarray(rng.standard_normal((B, d)), jnp.float32)
+    ids = jnp.asarray(rng.integers(0, n_blk, (B, K)), jnp.int32)
+    out = np.asarray(screened_logits_pallas(W, bb, h, ids), np.float64)
+    w64 = np.asarray(W, np.float64)[np.asarray(ids)]          # (B, K, 128, d)
+    exact = np.einsum("bkvd,bd->bkv", w64, np.asarray(h, np.float64))
+    scale = np.sqrt(d)
+    np.testing.assert_allclose(out, exact, atol=1e-5 * scale, rtol=0)
+    rounded = np.einsum("bkvd,bd->bkv", w64,
+                        np.asarray(h.astype(jnp.bfloat16), np.float64))
+    assert np.max(np.abs(rounded - exact)) > 1e-3 * scale
+    layers = np.stack([np.asarray(wdot("d,vd->v", h[i], W[ids[i, k]]))
+                       for i in range(B) for k in range(K)])
+    np.testing.assert_allclose(out.reshape(B * K, 128), layers,
+                               atol=1e-5 * scale, rtol=0)

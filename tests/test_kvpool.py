@@ -224,6 +224,41 @@ def test_dense_paged_stream_parity(dense_engine):
     assert cts.get(("exact", "greedy-paged"), 0) >= 1
 
 
+def test_capacity_moe_paged_join_parity():
+    """A capacity-bounded expert layer (phi3.5-moe) drops the tokens over
+    its capacity, which the prefill's length sets: a paged join must
+    prefill at the prompt's own length, so it keeps exactly the tokens the
+    contiguous prefill keeps. A zero router sends every token to experts 0
+    and 1, so a 20-token prompt overflows the capacity of 16 that its own
+    length gives (a 32-token bucket's would be 24, keeping all 20)."""
+    cfg = get_config("phi3.5-moe-42b-a6.6b").reduced()
+    m = build_model(cfg)
+    params = m.init(jax.random.key(2), dtype=jnp.float32)
+    moe = params["stack"]["blocks"]["moe"]
+    moe["w_router"] = jnp.zeros_like(moe["w_router"])
+    eng = DecodeEngine(m, params, max_len=32)
+    stream = eng.open_paged_stream(PagePool(32, 4), width=2)
+    assert stream.bucket(20) == 20
+    rng = np.random.default_rng(4)
+    reqs = [ServeRequest(prompt=rng.integers(0, cfg.vocab_size, n).astype(
+        np.int32), max_new=4) for n in (20, 13)]
+    got = _run_stream(stream, reqs)
+    for i, r in enumerate(reqs):
+        ref = eng.generate(r.prompt[None], r.max_new).tokens[0]
+        assert np.array_equal(got[i], ref), f"request {i} diverged"
+    # the join's state at the last position is the contiguous prefill's
+    prompt = reqs[0].prompt
+    h, _ = eng._jit_prefill(
+        params, {"tokens": jnp.asarray(prompt[None])},
+        m.init_cache(1, eng.max_len, dtype=eng.cache_dtype))
+    store = stream.pool.store
+    dest = np.stack([np.full(20, store.num_pages),
+                     np.arange(20) % 4]).astype(np.int32)
+    h_join, store.arrays = eng._jit_attn_join(
+        params, prompt[None], np.int32(20), store.arrays, dest)
+    np.testing.assert_allclose(h_join, h[:, -1], atol=1e-5, rtol=1e-5)
+
+
 def test_dense_stale_page_rows_never_leak(dense_engine):
     """Satellite audit: POISON every pool page with large finite garbage,
     then decode on freshly-allocated pages. The paged attention mask must
@@ -240,8 +275,8 @@ def test_dense_stale_page_rows_never_leak(dense_engine):
     pool.radix.clear()
     assert pool.pages_in_use == 0
     # poison EVERY non-trash page with large-but-finite junk
-    pool.store.k = pool.store.k.at[:, 1:].set(1e3)
-    pool.store.v = pool.store.v.at[:, 1:].set(1e3)
+    pool.store.arrays = {k: a.at[:, 1:].set(1e3)
+                         for k, a in pool.store.arrays.items()}
     reqs2 = _prefix_requests(cfg, 3, template_len=8, suffix_len=4,
                              max_new=5, seed=11)
     got = _run_stream(stream, reqs2)

@@ -149,3 +149,61 @@ def test_lstm_join_compiles(one_chip, bucket):
         lambda p, i, s, g: _lstm_join(model, p, i, s, g)).lower(
         params, ints, src, group).compile()
     assert compiled.as_text().count(" while(") >= layers
+
+
+def _moonlight_stage():
+    """moonlight-16b-a3b-ep8 at its published widths with the dense layer
+    and one scanned expert layer (every kind of layer, compiled once)."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.models import build_model
+    return build_model(dataclasses.replace(
+        get_config("moonlight-16b-a3b-ep8"), num_layers=2))
+
+
+@pytest.fixture(scope="module")
+def moonlight(one_chip):
+    model = _moonlight_stage()
+    params = jax.tree.map(lambda s: _spec(s.shape, one_chip, s.dtype),
+                          model.init_shapes(jnp.bfloat16))
+    pool = {"latent": _spec((2, 6400, 16, model.cfg.mla_latent_dim),
+                            one_chip)}
+    return model, params, pool
+
+
+def test_mla_moe_decode_step_compiles(one_chip, moonlight):
+    """The moonlight cell's decode step — latent pages of 16 at 128 rows
+    of 768 positions, the held experts' grouped products, then the fused
+    screened head over bfloat16 tiles (V=163,840, 16 blocks a cluster) —
+    compiles as one executable with the Mosaic kernel in it."""
+    from repro.kernels.ops import screened_fused_topk_tpu
+    model, params, pool = moonlight
+    B, d, n_blk = 128, model.cfg.d_model, 163_840 // 128
+
+    def step(p, tok, pool, table, pos, wb, bb, v, cand):
+        h, pool = model.decode_step_paged(p, tok, pool, table, pos)
+        ids, _, _ = screened_fused_topk_tpu(wb, bb, v, cand, h, k=1,
+                                            interpret=False)
+        return ids, pool
+
+    compiled = jax.jit(step).lower(
+        params, _spec((B,), one_chip, jnp.int32), pool,
+        _spec((B, 48), one_chip, jnp.int32), _spec((B,), one_chip, jnp.int32),
+        _spec((n_blk, 128, d), one_chip, jnp.bfloat16),
+        _spec((n_blk, 128), one_chip, jnp.bfloat16),
+        _spec((100, d), one_chip), _spec((100, 16), one_chip, jnp.int32),
+    ).compile()
+    _assert_kernel(compiled)
+
+
+def test_mla_moe_bucketed_join_compiles(one_chip, moonlight):
+    """One bucketed join of the moonlight cell (512 positions): prefill of
+    the padded prompt and the scatter of its rows into the latent pages."""
+    from repro.serving.engine import _attn_join
+    model, params, pool = moonlight
+    compiled = jax.jit(
+        lambda p, t, n, pool, dest: _attn_join(model, p, t, n, pool, dest)
+    ).lower(params, _spec((1, 512), one_chip, jnp.int32),
+            _spec((), one_chip, jnp.int32), pool,
+            _spec((2, 512), one_chip, jnp.int32)).compile()
+    assert compiled.as_text().count(" while(") >= 1
