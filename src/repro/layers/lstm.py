@@ -73,14 +73,18 @@ def lstm_forward_steps(params, x, state, n) -> Tuple[list, list]:
     (T, B, d), where entry k is the state after step k (entries from ``n``
     on are zeros). Each step is the same ``_cell`` as ``lstm_forward``'s,
     and the two agree bit for bit; a select on a step mask inside the
-    loop would not, since it changes how the compiler fuses the cell."""
+    loop would not, since it changes how the compiler fuses the cell. The
+    step index is always in range, so the states are written with no
+    bounds check: the check's constant is a loop input that a TPU compile
+    may copy into fast memory on every step."""
     out = jnp.moveaxis(x, 0, 1)
     final, steps = [], []
     for li, p in enumerate(params["layers"]):
         def body(i, carry, p=p, xs=out):
             h, c, hs, cs = carry
             h, c = _cell(p, xs[i], h, c)
-            return h, c, hs.at[i].set(h), cs.at[i].set(c)
+            return (h, c, jax.lax.dynamic_update_index_in_dim(hs, h, i, 0),
+                    jax.lax.dynamic_update_index_in_dim(cs, c, i, 0))
         h0 = state[li]["h"]
         zeros = jnp.zeros((x.shape[1], *h0.shape), h0.dtype)
         hT, cT, hs, cs = jax.lax.fori_loop(

@@ -108,7 +108,9 @@ class DecodeEngine:
                                                   resume=True))
         # the paged LSTM join as one executable (see _lstm_join): suffix
         # prefill padded to the page grid, its page snapshots, and the splice
-        # of its final state into the stream's cache
+        # of its final state into the stream's cache. A jittable head's join
+        # also computes the first token (_lstm_join_step); this one serves
+        # heads that run on the host
         self._jit_lstm_join = jax.jit(
             lambda p, ints, src, group: _lstm_join(model, p, ints, src,
                                                    group))
@@ -265,6 +267,44 @@ class DecodeEngine:
                     return nxt, h, cache
             self._put_step(key, fn)
         return self._step_cache[key]
+
+    def _lstm_join_step(self, head: SoftmaxHead,
+                        temperature: Optional[float] = None,
+                        top_p: float = 1.0):
+        """The paged LSTM join with its first token, for a jittable head:
+        ``_lstm_join`` then the head on its ``h_last``, one executable per
+        page-grid bucket, as ``_greedy_step`` composes the decode step and
+        ``hd.next``. Greedy: ``fn(params, ints, src, group) -> (first (1,),
+        snapshots, group)``. Sampled (``temperature`` set): ``fn(params,
+        rkey, ints, src, group) -> (first, rkey', snapshots, group)``,
+        drawing with the second half of ``rkey``'s split and returning the
+        first, as the stream splits its key for an eager first token.
+        Cached under ``(head.step_key(), "join", ...)`` with the same
+        LRU/mesh discipline as every other composed step; the caller
+        commits the key and the cache only after the token's guard."""
+        if temperature is None:
+            key = (head.step_key(), "join", "greedy")
+        else:
+            key = (head.step_key(), "join", "sample", float(temperature),
+                   float(top_p))
+        if key in self._step_cache:
+            self._step_cache.move_to_end(key)       # LRU hit → most recent
+            return self._step_cache[key]
+        model = self.model
+        if temperature is None:
+            def step(hd, params, ints, src, group):
+                h, snaps, group = _lstm_join(model, params, ints, src, group)
+                return hd.next(h), snaps, group
+            fn = self._head_jit((head,), step, n_placed=4)
+        else:
+            def step(hd, params, rkey, ints, src, group):
+                h, snaps, group = _lstm_join(model, params, ints, src, group)
+                rkey, k0 = jax.random.split(rkey)
+                return (hd.sample(k0, h, temperature, top_p), rkey, snaps,
+                        group)
+            fn = self._head_jit((head,), step, n_placed=5)
+        self._put_step(key, fn)
+        return fn
 
     # -- speculative decode steps (repro.serving.spec) -----------------------
     def _spec_verify_step(self, head: SoftmaxHead, n_max: int):

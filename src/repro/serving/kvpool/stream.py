@@ -26,9 +26,11 @@ of pool pages per slot:
     from the deepest snapshot and runs only the suffix, bit-exactly (a
     restarted scan is the same cell sequence). The whole join — suffix
     prefill padded to the page grid, the state at every page boundary,
-    and the splice into the slot's row — is ONE device dispatch
-    (``engine._lstm_join``); each new node's payload is a
-    ``SnapshotHandle`` into that dispatch's stacked snapshots.
+    the splice into the slot's row and, on a jittable head, the first
+    token — is ONE device dispatch (``engine._lstm_join``, composed with
+    the head by ``DecodeEngine._lstm_join_step``) and one fetch; each new
+    node's payload is a ``SnapshotHandle`` into that dispatch's stacked
+    snapshots.
 
 Sharing is copy-on-write: a slot's first write into a page with other
 holders (a cache-pinned prompt tail, a sibling slot's shared prefix)
@@ -162,17 +164,24 @@ class PagedDecodeStream:
         raise RuntimeError("PagedDecodeStream is full — check free_slots")
 
     def _first_token(self, h_last) -> int:
+        """The first token by an eager head call: an attention join's, or
+        an LSTM join's on a head its executable cannot compose."""
         hd = self.head
-        tr = self.tracer
-        with tr.scope("serve.join.first", "join"):
+        key = None
+        with self.tracer.scope("serve.join.first", "join"):
             h_in = h_last if hd.is_jittable else np.asarray(h_last)
             if self.sampled:
                 key, k0 = jax.random.split(self._key)
                 first = hd.sample(k0, h_in, self.temperature, self.top_p)
             else:
                 first = hd.next(h_in)
+        return self._commit_first(first, key)
+
+    def _commit_first(self, first, key) -> int:
+        """Fetch and guard the first token, then commit the PRNG key the
+        draw advanced to (``key``; None on a greedy stream)."""
         # the join's one device sync: everything before it only dispatched
-        with tr.scope("serve.join.wait", "join"):
+        with self.tracer.scope("serve.join.wait", "join"):
             first = np.asarray(first)
         # guard before the PRNG key (or any stream state) commits — join's
         # page rollback plus an unconsumed key make the retry bit-identical
@@ -230,10 +239,11 @@ class PagedDecodeStream:
     def _join_lstm(self, slot, request, toks, match, held) -> int:
         """Resume prefill from the deepest cached snapshot over the suffix,
         padded up to the page grid, snapshotting at every page boundary so
-        the whole prompt inserts as radix nodes, and splice the final state
-        into ``slot``: one dispatch. The new cache commits only after the
-        first token's guard and the page bookkeeping pass, so a fault
-        leaves the stream as it was. Returns the first token."""
+        the whole prompt inserts as radix nodes, splice the final state
+        into ``slot`` and, on a jittable head, compute the first token: one
+        dispatch. The new cache (and a sampled stream's key) commits only
+        after the first token's guard and the page bookkeeping pass, so a
+        fault leaves the stream as it was. Returns the first token."""
         eng, pool, tr = self.engine, self.pool, self.tracer
         P, Tp, S = pool.page_size, len(toks), self.max_pages
         t = match.n_full                      # snapshot exists exactly here
@@ -253,13 +263,26 @@ class PagedDecodeStream:
             ints[:3] = n, k, slot
             ints[3:3 + len(ends)] = np.asarray(ends, np.int32) - t - 1
             ints[3 + S:3 + S + n] = request.prompt[t:]
-            h_last, snaps, cache = eng._jit_lstm_join(eng.params, ints,
-                                                      states, self.cache)
+            args = (ints, states, self.cache)
+            # a jittable head's first token comes out of the join's own
+            # executable, as the decode step composes its head
+            folded, key = self.head.is_jittable, None
+            if not folded:
+                h_last, snaps, cache = eng._jit_lstm_join(eng.params, *args)
+            elif self.sampled:
+                fn = eng._lstm_join_step(self.head, self.temperature,
+                                         self.top_p)
+                first, key, snaps, cache = fn(eng.params, self._key, *args)
+            else:
+                fn = eng._lstm_join_step(self.head)
+                first, snaps, cache = fn(eng.params, *args)
             if tr.enabled:
-                tr.note(bucket=bucket, snapshots=len(ends))
+                tr.note(bucket=bucket, snapshots=len(ends),
+                        first_in_join=int(folded))
         if tr.enabled:
             tr.note(cached_tokens=t, chunks=int(n > 0))
-        first = self._first_token(h_last)
+        first = self._commit_first(first, key) if folded \
+            else self._first_token(h_last)
         with tr.scope("serve.join.splice", "join"):
             # page chain: a partially-covered grid slot being EXTENDED must
             # go private now (logical COW — its node's snapshot stops at t,

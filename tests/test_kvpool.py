@@ -13,12 +13,15 @@ The load-bearing guarantees pinned here:
   * ``PoolExhausted`` is rollback-safe at join, non-consuming at step, and
     surfaces through the scheduler as typed preemption/rejection.
 """
+import warnings
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_config
+from repro.configs import L2SConfig, get_config
+from repro.core import fit_l2s
 from repro.models import build_model
 from repro.serving import (BudgetAdmission, ContinuousScheduler,
                            DecodeEngine, PagePool, PoolExhausted,
@@ -33,6 +36,21 @@ def lstm_engine():
     m = build_model(cfg)
     params = m.init(jax.random.key(0), dtype=jnp.float32)
     return cfg, DecodeEngine(m, params, max_len=24)
+
+
+@pytest.fixture(scope="module")
+def pallas_engine(lstm_engine):
+    """``lstm_engine``'s model and weights behind a screen fit in 128-word
+    blocks, so both ``exact`` and ``screened-pallas`` resolve."""
+    cfg, eng = lstm_engine
+    H = np.random.default_rng(0).standard_normal(
+        (64, cfg.d_model)).astype(np.float32)
+    y = np.asarray(jnp.argsort(-(H @ eng.W.T + eng.b), axis=-1)[:, :5])
+    screen = fit_l2s(H, y, cfg.vocab_size,
+                     L2SConfig(num_clusters=4, budget=256, outer_iters=1,
+                               sgd_steps=5, vocab_block=128)).screen
+    return cfg, DecodeEngine(eng.model, eng.params, screen=screen,
+                             max_len=eng.max_len)
 
 
 @pytest.fixture(scope="module")
@@ -190,8 +208,10 @@ def test_lstm_paged_stream_parity_and_hits(lstm_engine):
     # all stream chains released; only radix pins remain
     assert stream.pages_held == 0
     assert pool.pages_in_use == pool.radix.nodes
-    # LSTM paged streams reuse the DENSE greedy step — no paged step kinds
-    assert all(kind == "greedy" for _, kind in eng.compiled_step_counts())
+    # LSTM paged streams reuse the DENSE greedy step — no paged step
+    # kinds; the join executable with its first token is the only other
+    assert {kind for _, kind in eng.compiled_step_counts()} == {"greedy",
+                                                                "join"}
 
 
 def test_lstm_mixed_prompt_lengths_parity(lstm_engine):
@@ -429,7 +449,9 @@ def test_scheduler_paged_join_zero_recompiles(lstm_engine):
 
     ContinuousScheduler(eng, max_slots=3,
                         kv_pool=PagePool(64, 4)).serve(batch(43))
-    n_join = eng._jit_lstm_join._cache_size()
+    join = eng._lstm_join_step(eng.resolve_head(None))._inner_jit
+    n_join = join._cache_size()
+    assert n_join > 0
     compiles = []
 
     def on_duration(event, duration, **_):
@@ -444,7 +466,7 @@ def test_scheduler_paged_join_zero_recompiles(lstm_engine):
         jax.monitoring.unregister_event_duration_listener(on_duration)
     assert all(isinstance(r, ServeResult) for r in res)
     assert compiles == []
-    assert eng._jit_lstm_join._cache_size() == n_join
+    assert join._cache_size() == n_join
 
 
 # -- fused LSTM join: one dispatch, page-grid buckets, lazy snapshots ---------
@@ -504,23 +526,27 @@ def test_lstm_fused_join_parity(lstm_engine, kind, t, n):
                     err_msg=f"end {end} layer {layer} {k}")
 
 
+def _count_calls(calls, name, fn):
+    def wrapped(*args, **kw):
+        calls[name] += 1
+        return fn(*args, **kw)
+    return wrapped
+
+
 def test_lstm_join_is_one_dispatch(lstm_engine, monkeypatch):
     """Through the scheduler, every paged LSTM join is exactly one call of
-    the join executable — cold, resumed or whole-prompt hit — and the
-    chunked ``_jit_resume_prefill`` is never called."""
+    the head's join executable — cold, resumed or whole-prompt hit — and
+    neither the head-less ``_jit_lstm_join`` nor the chunked
+    ``_jit_resume_prefill`` is ever called."""
     cfg, eng = lstm_engine
-    calls = {"join": 0, "resume": 0}
-
-    def counted(name, fn):
-        def wrapped(*args, **kw):
-            calls[name] += 1
-            return fn(*args, **kw)
-        return wrapped
-
-    monkeypatch.setattr(eng, "_jit_lstm_join",
-                        counted("join", eng._jit_lstm_join))
-    monkeypatch.setattr(eng, "_jit_resume_prefill",
-                        counted("resume", eng._jit_resume_prefill))
+    calls = {"join": 0, "headless": 0, "resume": 0}
+    join_step = eng._lstm_join_step
+    monkeypatch.setattr(eng, "_lstm_join_step", lambda *a: _count_calls(
+        calls, "join", join_step(*a)))
+    monkeypatch.setattr(eng, "_jit_lstm_join", _count_calls(
+        calls, "headless", eng._jit_lstm_join))
+    monkeypatch.setattr(eng, "_jit_resume_prefill", _count_calls(
+        calls, "resume", eng._jit_resume_prefill))
     rng = np.random.default_rng(53)
     base = rng.integers(0, cfg.vocab_size, size=13).astype(np.int32)
     prompts = [base, base[:6], base[:8]]            # resumed, whole hits
@@ -533,12 +559,141 @@ def test_lstm_join_is_one_dispatch(lstm_engine, monkeypatch):
     assert all(isinstance(r, ServeResult) for r in res)
     joins = [e for e in tr.events() if e["name"] == "serve.join"]
     assert len(joins) == len(prompts)
-    assert calls == {"join": len(joins), "resume": 0}
+    assert calls == {"join": len(joins), "headless": 0, "resume": 0}
     forward = [j for j in joins if j["args"]["chunks"] == 1]
     whole = [j for j in joins if j["args"]["chunks"] == 0]
     assert len(forward) + len(whole) == len(joins)
     assert whole and all(j["args"]["cached_tokens"] == j["args"]["prompt_len"]
                          for j in whole)
+
+
+# -- the first token inside the join executable --------------------------------
+
+def _join_prompts(cfg):
+    """Cold, whole-prompt hits (page-aligned and not), a resume from a
+    page boundary inside a shared chain, and a short cold prompt."""
+    rng = np.random.default_rng(59)
+    base = rng.integers(0, cfg.vocab_size, size=13).astype(np.int32)
+    other = rng.integers(0, cfg.vocab_size, size=5).astype(np.int32)
+    return [base, base[:8], base, np.concatenate([base[:6], other]),
+            other[:3]]
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("head", ["exact", "screened-pallas"])
+def test_lstm_join_first_token_parity(pallas_engine, monkeypatch, head,
+                                      sampled):
+    """The head's join executable gives the first token, the page
+    snapshots and the new cache bit-identical to the head-less
+    ``_jit_lstm_join`` followed by the eager ``_first_token``, on cold,
+    resumed and whole-prompt joins at P=4; a sampled join leaves the
+    stream's key where the eager draw leaves it."""
+    cfg, eng = pallas_engine
+    kw = dict(temperature=0.8, seed=5) if sampled else {}
+    stream = eng.open_paged_stream(PagePool(64, P_FUSED), head=head,
+                                   width=2, **kw)
+    join_step, suffixes, keys = eng._lstm_join_step, [], []
+
+    def checked(*a):
+        fn = join_step(*a)
+
+        def run(params, *args):
+            out = fn(params, *args)
+            ints, src, group = args[-3:]
+            h_last, snaps, cache = eng._jit_lstm_join(params, ints, src,
+                                                      group)
+            key = stream._key if sampled else None
+            first = stream._first_token(h_last)
+            if sampled:
+                np.testing.assert_array_equal(
+                    jax.random.key_data(out[1]),
+                    jax.random.key_data(stream._key))
+                keys.append(stream._key)
+                stream._key = key        # the join itself commits it
+            assert int(np.asarray(out[0]).ravel()[0]) == first
+            for g, w in zip(jax.tree.leaves(out[-2:]),
+                            jax.tree.leaves((snaps, cache))):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+            suffixes.append(int(ints[0]))
+            return out
+        return run
+
+    monkeypatch.setattr(eng, "_lstm_join_step", checked)
+    prompts = _join_prompts(cfg)
+    for p in prompts:
+        stream.join(ServeRequest(prompt=p, max_new=2))
+        if sampled:
+            np.testing.assert_array_equal(jax.random.key_data(stream._key),
+                                          jax.random.key_data(keys[-1]))
+        stream.step()
+    assert len(suffixes) == len(prompts)
+    kinds = {"cold" if n == len(p) else "whole" if n == 0 else "resumed"
+             for n, p in zip(suffixes, prompts)}
+    assert kinds == {"cold", "whole", "resumed"}
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+def test_lstm_join_head_runs_inside_join(lstm_engine, monkeypatch, sampled):
+    """Through the scheduler, a paged LSTM join on a jittable head is one
+    call of the head's join executable and nothing else: no head-less
+    join, no ``head.next`` or ``head.sample`` outside an executable, no
+    ``serve.join.first`` span, and its prefill notes ``first_in_join``."""
+    cfg, eng = lstm_engine
+    head = eng.resolve_head(None)
+    assert head.is_jittable
+    calls = {"join": 0, "headless": 0, "eager_head": 0}
+    join_step = eng._lstm_join_step
+    monkeypatch.setattr(eng, "_lstm_join_step", lambda *a: _count_calls(
+        calls, "join", join_step(*a)))
+    monkeypatch.setattr(eng, "_jit_lstm_join", _count_calls(
+        calls, "headless", eng._jit_lstm_join))
+    for name, argnum in (("next", 1), ("sample", 2)):
+        method = getattr(type(head), name)
+
+        def eager_counted(*a, _method=method, _argnum=argnum, **kw):
+            if not isinstance(a[_argnum], jax.core.Tracer):
+                calls["eager_head"] += 1
+            return _method(*a, **kw)
+        monkeypatch.setattr(type(head), name, eager_counted)
+    rng = np.random.default_rng(61)
+    base = rng.integers(0, cfg.vocab_size, size=10).astype(np.int32)
+    prompts = [base, base[:8], base[:5]]
+    prompts += [rng.integers(0, cfg.vocab_size, size=m).astype(np.int32)
+                for m in (3, 7)]
+    sampling = dict(temperature=0.7, seed=3) if sampled else {}
+    tr = Tracer()
+    res = ContinuousScheduler(eng, max_slots=2, kv_pool=PagePool(64, 4),
+                              tracer=tr).serve(
+        [ServeRequest(prompt=p, max_new=3, **sampling) for p in prompts])
+    assert all(isinstance(r, ServeResult) for r in res)
+    joins = [e for e in tr.events() if e["name"] == "serve.join"]
+    assert len(joins) == len(prompts)
+    assert calls == {"join": len(joins), "headless": 0, "eager_head": 0}
+    prefills = [e for e in tr.events() if e["name"] == "serve.join.prefill"]
+    assert [e["args"]["first_in_join"] for e in prefills] == [1] * len(joins)
+    assert not [e for e in tr.events() if e["name"] == "serve.join.first"]
+
+
+@pytest.mark.parametrize("head", ["exact", "screened-pallas"])
+def test_lstm_join_step_takes_head_weights_as_arguments(pallas_engine, head):
+    """The head's join executable lowers with the head's arrays as
+    arguments, never as captured constants."""
+    cfg, eng = pallas_engine
+    S = eng.max_len // P_FUSED
+    cache = eng.model.init_cache(2, eng.max_len, dtype=eng.cache_dtype)
+    src = jax.tree.map(lambda a: jnp.zeros((S, 1, *a.shape[1:]), a.dtype),
+                       cache["lstm"])
+    ints = np.zeros(3 + S + P_FUSED, np.int32)
+    fn = eng._lstm_join_step(eng.resolve_head(head))
+    was = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", 1024)
+    try:
+        with warnings.catch_warnings():
+            warnings.filterwarnings("error", message=".*constants were "
+                                    "captured")
+            fn.lower(eng.params, ints, src, cache)
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", was)
 
 
 # -- multidevice: paged decode under a vocab-sharded head ---------------------

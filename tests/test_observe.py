@@ -448,7 +448,8 @@ def _kids(tr):
     return out
 
 
-def _paged_run(trained, tracer=None, pool_pages=64, n=6, eng=None):
+def _paged_run(trained, tracer=None, pool_pages=64, n=6, eng=None,
+               head="screened"):
     """Greedy requests through a paged scheduler (the served path), some
     sharing a two-page prefix so the radix cache hits."""
     corpus = trained[3]
@@ -458,18 +459,31 @@ def _paged_run(trained, tracer=None, pool_pages=64, n=6, eng=None):
     reqs = [ServeRequest(prompt=np.concatenate([shared, p[:2 + i % 3]])
                          if i % 2 else p[:5 + i], max_new=3 + i % 3)
             for i, p in enumerate(base)]
-    sched = ContinuousScheduler(eng, policy=StaticPolicy("screened"),
+    sched = ContinuousScheduler(eng, policy=StaticPolicy(head),
                                 max_slots=2, kv_pool=PagePool(pool_pages, 4),
                                 tracer=tracer)
     return eng, sched, sched.serve(reqs)
 
 
+def _join_kids(kids, join):
+    """A join's child spans, and the ones it should have: a first token
+    that came out of the join's executable (``first_in_join`` 1 on its
+    prefill) has no ``serve.join.first`` head call."""
+    got = kids[join["args"]["id"]]
+    folded = [c["args"].get("first_in_join", 0) for c in got
+              if c["name"] == "serve.join.prefill"] == [1]
+    want = [n for n in JOIN_KIDS if not (folded and n == "serve.join.first")]
+    return sorted(c["name"] for c in got), want, folded
+
+
 def test_scheduler_span_tree_paged(trained):
     """On the paged LSTM path every ``serve.join`` sits in a ``serve.tick``
-    and has exactly one each of match, prefill, first-token head call,
-    first-token wait and splice; every ``serve.step`` has its page growth
-    and its wait; ticks count their joins and steps; submissions carry the
-    admission load as a child."""
+    and has exactly one each of match, prefill, first-token wait and
+    splice, and a first-token head call unless the prefill's executable
+    computed the token (``first_in_join`` 1, as on this jittable head);
+    every ``serve.step`` has its page growth and its wait; ticks count
+    their joins and steps; submissions carry the admission load as a
+    child."""
     tr = Tracer(clock=FakeClock(dt=1e-4))
     _, sched, out = _paged_run(trained, tr)
     assert all(isinstance(r, ServeResult) for r in out)
@@ -479,7 +493,8 @@ def test_scheduler_span_tree_paged(trained):
     joins = _by_name(tr, "serve.join")
     assert len(joins) == len(out)
     for j in joins:
-        assert sorted(c["name"] for c in kids[j["args"]["id"]]) == JOIN_KIDS
+        got, want, folded = _join_kids(kids, j)
+        assert got == want and folded
         assert by_id[j["args"]["parent"]]["name"] == "serve.tick"
         assert j["tid"] >= 0                    # the request's own lane
         a = j["args"]
@@ -507,6 +522,21 @@ def test_scheduler_span_tree_paged(trained):
     for s in subs:
         assert [c["name"] for c in kids[s["args"]["id"]]] == [
             "serve.submit.load"]
+
+
+def test_scheduler_span_tree_paged_host_head(trained):
+    """A head that runs on the host keeps the eager first-token call: each
+    paged join notes ``first_in_join`` 0 and has its ``serve.join.first``
+    beside match, prefill, wait and splice."""
+    tr = Tracer(clock=FakeClock(dt=1e-4))
+    _, _, out = _paged_run(trained, tr, n=3, head="svd")
+    assert all(isinstance(r, ServeResult) for r in out)
+    kids = _kids(tr)
+    joins = _by_name(tr, "serve.join")
+    assert len(joins) == len(out)
+    for j in joins:
+        got, want, folded = _join_kids(kids, j)
+        assert got == want == JOIN_KIDS and not folded
 
 
 def test_paged_lstm_prefill_span_args(trained):

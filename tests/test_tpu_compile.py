@@ -1,5 +1,6 @@
 """The served Pallas kernels compile for a TPU v5e at nmt-deen-lstm widths,
-and the paged LSTM join at ptb-large-lstm widths.
+the paged LSTM join at ptb-large-lstm widths, and the join with its first
+token on the Pallas head at nmt-deen-lstm widths.
 
 The TPU compiler runs without a chip: each test lowers a kernel entry point
 with shapes placed on a described ``v5e:2x2`` device and compiles it, which
@@ -8,13 +9,15 @@ stores into vector memory). Kernel widths are the paper's DE→EN model: d=500
 and a 25,000-word vocabulary, i.e. 196 blocks of 128; batches are the
 scheduler's decode width and a single query. Every kernel compile must
 contain a Mosaic kernel (``tpu_custom_call``): interpreted kernels would
-lower to plain XLA. The join is plain XLA and has none.
+lower to plain XLA. The head-less join is plain XLA and has none; the join
+with its first token on the Pallas head carries the kernel.
 
 The topology is described inside a fixture, never at import, so every test
 worker collects the same tests and only the one running this file loads the
 TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -149,6 +152,69 @@ def test_lstm_join_compiles(one_chip, bucket):
         lambda p, i, s, g: _lstm_join(model, p, i, s, g)).lower(
         params, ints, src, group).compile()
     assert compiled.as_text().count(" while(") >= layers
+
+
+def test_lstm_join_with_pallas_head_compiles(one_chip, monkeypatch):
+    """The paged LSTM join with its first token on ``screened-pallas``, as
+    ``DecodeEngine._lstm_join_step`` composes it, compiles as one
+    executable at the DE→EN widths (d=500, V=25,000; r=50, 8 blocks a
+    cluster) with pages of 8, a 96-slot cache (12 snapshot rows) of the
+    closed-loop cell's width 64 and the largest bucket (48): the Mosaic
+    kernel lowers inside the join, and the prefill keeps one device loop
+    per layer, none of which copies into fast memory on every step. The
+    head is built small and traced over full-size state, as the engine
+    passes it in."""
+    from collections import OrderedDict
+    import repro.kernels.fused_topk as fused_topk
+    import repro.kernels.route as route
+    from repro.configs import get_config
+    from repro.core.screening import ScreenParams
+    from repro.heads.pallas import ScreenedPallasHead
+    from repro.models import build_model
+    from repro.serving.engine import DecodeEngine
+    # the kernels resolve their mode from this process's CPU backend; the
+    # target here is the chip
+    for mod in (fused_topk, route):
+        monkeypatch.setattr(mod, "interpret_mode",
+                            lambda interpret=None: False)
+    model = build_model(get_config("nmt-deen-lstm"))
+    layers, d = model.cfg.num_layers, model.cfg.d_model
+    assert (d, model.cfg.vocab_size) == (D, VOCAB)
+    params = jax.tree.map(lambda s: _spec(s.shape, one_chip, s.dtype),
+                          model.init_shapes(jnp.float32))
+    small = ScreenParams(v=jnp.zeros((R, D)),
+                         cand_idx=jnp.zeros((R, K_SLOTS), jnp.int32),
+                         cand_len=jnp.zeros((R,), jnp.int32),
+                         vocab_size=VOCAB, block=128)
+    head = ScreenedPallasHead(jnp.zeros((128, D)), jnp.zeros((128,)),
+                              small).prepare()
+    state = {"W": _spec((VOCAB, D), one_chip), "b": _spec((VOCAB,), one_chip),
+             "_Wb": _spec((N_BLK, 128, D), one_chip),
+             "_bb": _spec((N_BLK, 128), one_chip),
+             "screen": ScreenParams(
+                 v=_spec((R, D), one_chip),
+                 cand_idx=_spec((R, K_SLOTS), one_chip, jnp.int32),
+                 cand_len=_spec((R,), one_chip, jnp.int32),
+                 vocab_size=VOCAB, block=128)}
+    assert set(state) == set(head.device_state())
+    eng = DecodeEngine.__new__(DecodeEngine)
+    eng.model, eng._step_cache, eng._step_cache_max = model, OrderedDict(), 4
+    fn = eng._lstm_join_step(head)
+    S, width, bucket = 96 // 8, 64, 48
+    src = [{k: _spec((S, 1, d), one_chip) for k in "hc"}] * layers
+    group = {"lstm": [{k: _spec((width, d), one_chip) for k in "hc"}]
+             * layers}
+    ints = _spec((3 + S + bucket,), one_chip, jnp.int32)
+    compiled = fn._inner_jit.lower((state,), (params, ints, src, group),
+                                   ()).compile()
+    _assert_kernel(compiled)
+    text = compiled.as_text()
+    bodies = re.findall(r"body=%([\w.\-]+)", text)
+    assert len(bodies) >= layers
+    for name in bodies:
+        body = re.search(rf"^%{re.escape(name)} .*?^}}", text,
+                         re.S | re.M).group(0)
+        assert "copy-start" not in body, f"{name} copies on every step"
 
 
 def _moonlight_stage():
