@@ -160,7 +160,8 @@ def main(argv=None):
         warmup.append(ServeRequest(prompt=warm_p[1], max_new=2, head=name,
                                    temperature=0.8, top_p=0.95, seed=7))
     ContinuousScheduler(engine, policy=policy, max_slots=args.max_slots,
-                        max_streams=2 * len(catalog)).serve(warmup)
+                        max_streams=2 * len(catalog),
+                        kv_pool=_pool(engine, len(warmup))).serve(warmup)
     counts0 = engine.compiled_step_counts()
 
     deadlines = {t: s * args.deadline_scale for t, s in TIER_DEADLINES.items()}
@@ -171,7 +172,7 @@ def main(argv=None):
         engine, policy=policy,
         admission=BudgetAdmission(flops_budget=budget),
         max_slots=args.max_slots, max_streams=8, deadlines=deadlines,
-        tracer=tracer)
+        kv_pool=_pool(engine, len(requests)), tracer=tracer)
     wall = _drive(sched, requests, rate, args.seed)
     counts1 = engine.compiled_step_counts()
     recompiles = sum(counts1.values()) - sum(counts0.values())
@@ -228,6 +229,14 @@ def _drive(sched, requests, rate, seed):
     return time.perf_counter() - t0
 
 
+def _pool(engine, sequences):
+    """A page pool of ``max_len`` pages with room for every sequence at
+    once, so the pool adds no pressure of its own: each sequence holds one
+    page (a dense cache's footprint) plus the private copy its first decode
+    write makes of the prompt page the radix cache keeps."""
+    return PagePool(num_pages=1 + 2 * sequences, page_size=engine.max_len)
+
+
 def _chaos(args, cfg, corpus, engine, n_req):
     """--chaos: the resilience layer under deterministic fire.
 
@@ -264,7 +273,8 @@ def _chaos(args, cfg, corpus, engine, n_req):
     warmup = [ServeRequest(prompt=warm_p[0], max_new=2, head=name)
               for name in catalog]
     ContinuousScheduler(engine, policy=policy, max_slots=args.max_slots,
-                        max_streams=len(catalog) + 1).serve(warmup)
+                        max_streams=len(catalog) + 1,
+                        kv_pool=_pool(engine, len(warmup))).serve(warmup)
     counts0 = engine.compiled_step_counts()
 
     clock = LogicalClock(0.0, dt_per_read=1e-3)
@@ -286,8 +296,9 @@ def _chaos(args, cfg, corpus, engine, n_req):
     tracer = Tracer(clock=lambda: clock.t) if args.trace else None
     sched = ContinuousScheduler(
         engine, policy=policy, max_slots=args.max_slots, max_streams=8,
-        deadlines=deadlines, clock=clock, fault_injector=injector,
-        breaker=breaker, watchdog=watchdog, max_retries=2, tracer=tracer)
+        deadlines=deadlines, clock=clock, kv_pool=_pool(engine, len(requests)),
+        fault_injector=injector, breaker=breaker, watchdog=watchdog,
+        max_retries=2, tracer=tracer)
     t0 = time.perf_counter()
     unhandled = None
     try:

@@ -45,17 +45,22 @@ except ImportError:                      # script's own dir is sys.path[0]
     from common import update_bench_json
     from serve_mixed import build_engine
 
-from repro.serving import (ContinuousScheduler, ServeRequest, ServeResult,
-                           SpecPolicy, StaticPolicy)
+from repro.serving import (ContinuousScheduler, PagePool, ServeRequest,
+                           ServeResult, SpecPolicy, StaticPolicy)
 from repro.utils.compile_cache import enable_compile_cache
 
 DRAFTS = ("screened", "screened-pallas", "adaptive")
 
 
 def _serve_timed(engine, requests, verify, spec=None):
-    """One fresh scheduler drain; returns (results, wall seconds, stats)."""
+    """One fresh scheduler drain over a fresh page pool with room for
+    every request at once (two ``max_len`` pages each: its own, and the
+    copy its first decode write makes of the prompt page the radix cache
+    keeps); returns (results, wall seconds, stats)."""
+    pool = PagePool(num_pages=1 + 2 * len(requests),
+                    page_size=engine.max_len)
     sched = ContinuousScheduler(engine, policy=StaticPolicy(verify),
-                                spec=spec)
+                                kv_pool=pool, spec=spec)
     t0 = time.perf_counter()
     results = sched.serve(requests)
     return results, time.perf_counter() - t0, sched.stats

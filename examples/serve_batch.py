@@ -20,7 +20,7 @@ from repro.models import build_model
 from repro.optim import adamw_init
 from repro.serving import (AdmissionRejected, BudgetAdmission,
                            ContinuousScheduler, CostAwarePolicy,
-                           DecodeEngine, ServeRequest, TierPolicy)
+                           DecodeEngine, PagePool, ServeRequest, TierPolicy)
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--reduced", action="store_true",
@@ -107,14 +107,19 @@ print(f"tier policy routes: "
 #    a flops budget from the head catalog (over-budget arrivals come back as
 #    typed AdmissionRejected results — here the budget is roomy), joins them
 #    into running fixed-width decode streams at sequence boundaries, and
-#    retires them as they finish. Greedy tokens are bit-identical to the
-#    serve_batch results above.
+#    retires them as they finish. Each stream slot holds its request's cache
+#    as pages of a PagePool: two pages of max_len per request (its own, and
+#    the copy its first decode write makes of the prompt page the radix
+#    cache keeps) let every request hold its pages at once. Greedy tokens
+#    are bit-identical to the serve_batch results above.
 catalog = engine.head_catalog(("screened", "exact"))
 sched = ContinuousScheduler(
     engine, policy=tier_policy,
     admission=BudgetAdmission(
         flops_budget=8 * max(m["flops_per_query"] for m in catalog.values())),
-    max_slots=4)
+    max_slots=4,
+    kv_pool=PagePool(num_pages=1 + 2 * len(requests),
+                     page_size=engine.max_len))
 t0 = time.perf_counter()
 res3 = sched.serve(requests)
 t_sched = time.perf_counter() - t0

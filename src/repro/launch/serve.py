@@ -278,12 +278,12 @@ def serve_scheduler(engine, requests, head_name, draft=None,
     (realtime / standard / batch); the fast head serves the realtime
     tier, "exact" everything else. The flops budget is sized
     to the catalog so a burst sheds load through the typed reject path.
-    Families the paged KV pool supports additionally serve over a
-    ``PagePool`` (shared-prefix radix cache + COW pages) and report pool
-    utilization in the log. With ``draft`` set (--draft-head) every
-    request carries it explicitly and exact-routed traffic decodes
-    speculatively on ``SpecDecodeStream`` lanes — same tokens, fewer
-    exact-head weight streams. The caller has already resolved ``head_name``
+    Serving runs over a ``PagePool`` (shared-prefix radix cache + COW
+    pages) and reports pool utilization in the log; on a family the pool
+    cannot page, the pool's ``NotImplementedError`` reaches the caller.
+    With ``draft`` set (--draft-head) every request carries it explicitly
+    and exact-routed traffic decodes speculatively on ``SpecDecodeStream``
+    lanes — same tokens, fewer exact-head weight streams. The caller has already resolved ``head_name``
     and ``draft`` (``main`` exits 2 on a head it cannot build). Returns the
     drained ``ContinuousScheduler`` (``.stats``, ``.results()``)."""
     import dataclasses
@@ -302,14 +302,11 @@ def serve_scheduler(engine, requests, head_name, draft=None,
                for i, r in enumerate(requests)]
     spec = SpecPolicy(drafts=(draft,)) if draft is not None else None
 
-    kv_pool = None
-    if engine.model.cfg.family in ("lstm", "dense", "moe") \
-            and engine.model.cfg.sliding_window is None:
-        page = 8 if engine.max_len % 8 == 0 else 4
-        while engine.max_len % page:
-            page //= 2                     # max_len is even in practice
-        kv_pool = PagePool(num_pages=4 * (engine.max_len // page),
-                           page_size=page)
+    page = 8 if engine.max_len % 8 == 0 else 4
+    while engine.max_len % page:
+        page //= 2                         # max_len is even in practice
+    kv_pool = PagePool(num_pages=4 * (engine.max_len // page),
+                       page_size=page)
     sched = ContinuousScheduler(engine, policy=policy,
                                 admission=BudgetAdmission(flops_budget=budget),
                                 max_slots=MAX_SLOTS, kv_pool=kv_pool,

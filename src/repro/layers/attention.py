@@ -206,7 +206,7 @@ def attn_decode(params, x1, cache, pos, cfg: ModelConfig,
     """One-token decode. x1: (B, 1, d); pos: scalar int32 absolute position,
     or a (B,) int32 vector of PER-ROW positions (continuous batching: rows
     of one batched decode step may sit at different depths after a request
-    joined mid-stream — see ``DecodeStream`` in repro.serving.engine).
+    joined mid-stream — see ``SpecDecodeStream`` in repro.serving.spec).
 
     Returns (out (B, 1, d), new_cache). Ring-buffer semantics when ``window``
     (or cfg.sliding_window) is set and the cache S equals that window.
@@ -216,7 +216,7 @@ def attn_decode(params, x1, cache, pos, cfg: ModelConfig,
 
     Slot-reuse audit: when a stream evicts a slot and a later request
     reuses it, the old occupant's K/V rows persist in the cache until the
-    new join's splice overwrites the ENTIRE row (engine._splice_cache
+    new join's splice overwrites the ENTIRE row (spec.stream._splice_cache
     replaces all S slots). Between eviction and reuse the idle row keeps
     decoding parked at pos 0 — its write lands in slot 0 of its own row
     and its output is discarded, so the ``arange(S) <= pos`` mask plus

@@ -1,4 +1,4 @@
-from repro.serving.engine import DecodeEngine, DecodeStream, GenerationResult
+from repro.serving.engine import DecodeEngine, GenerationResult
 from repro.serving.kvpool import (PagedDecodeStream, PagePool, PoolExhausted,
                                   RadixCache)
 from repro.serving.observe import (NULL_TRACER, Counter, Gauge, Histogram,
@@ -16,7 +16,7 @@ from repro.serving.router import (DEFAULT_ACCURACY, CostAwarePolicy,
 from repro.serving.spec import (DraftLenController, SpecDecodeStream,
                                 SpecPolicy, spec_step_flops)
 
-__all__ = ["DecodeEngine", "DecodeStream", "GenerationResult",
+__all__ = ["DecodeEngine", "GenerationResult",
            "PagePool", "PagedDecodeStream", "PoolExhausted", "RadixCache",
            "ServeRequest", "ServeResult",
            "RoutingPolicy", "StaticPolicy", "TierPolicy", "CostAwarePolicy",

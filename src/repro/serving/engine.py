@@ -30,14 +30,15 @@ batched decode over the SAME cached jitted steps ``generate`` uses — so a
 mixed batch causes zero new step compilations after warmup — and scatters
 ``ServeResult``s back in request order.
 
-Continuous batching: ``open_stream(head, width)`` returns a
-``DecodeStream`` — a FIXED-width batched decode whose pad slots are live
-capacity. ``join`` prefills one request solo and splices its cache rows
-into a free slot mid-decode (per-row positions ride the vector-``pos``
-branch of ``attn_decode``); ``step`` advances every active slot one token
-through the same cached jitted steps; finished slots retire and free
-their pad slot for the next join. The ``repro.serving.scheduler``
-subsystem builds its tick loop on exactly these three hooks.
+Continuous batching: ``open_paged_stream(pool, head, width)`` returns a
+``PagedDecodeStream`` (repro.serving.kvpool) — a FIXED-width batched
+decode over a ``PagePool`` whose pad slots are live capacity. ``join``
+prefills one request in one dispatch (``_lstm_join`` / ``_attn_join``
+below) and places its state in a free slot mid-decode; ``step`` advances
+every active slot one token through the cached jitted steps; finished
+slots retire and free their pad slot and pages for the next join. The
+``repro.serving.scheduler`` subsystem builds its tick loop on exactly
+these three hooks.
 """
 from __future__ import annotations
 
@@ -53,9 +54,7 @@ from repro import heads as heads_registry
 from repro.core.screening import ScreenParams
 from repro.heads.base import MissingScreenError, SoftmaxHead
 from repro.models.model import Model
-from repro.serving.observe.trace import NULL_TRACER
 from repro.serving.request import ServeRequest, ServeResult
-from repro.serving.resilience.faults import guard_tokens
 
 HeadLike = Union[str, SoftmaxHead]
 
@@ -531,35 +530,18 @@ class DecodeEngine:
                     head=served, request=requests[i], group_size=len(idxs))
         return results
 
-    # -- continuous batching: fixed-width streams ---------------------------
-    def open_stream(self, head: Optional[HeadLike] = None, width: int = 4,
-                    temperature: Optional[float] = None, top_p: float = 1.0,
-                    seed: int = 0) -> "DecodeStream":
-        """Open a fixed-width continuous decode stream on this engine.
-
-        The stream shares the engine's cached jitted steps (one vector-pos
-        executable per (head, step kind, width) — compiled at the stream's
-        first step, reused forever after) and its compiled prefill. Greedy
-        tokens produced through a stream are bit-identical to solo
-        ``generate`` calls; see ``DecodeStream``."""
-        name = head if isinstance(head, str) else None
-        hd = self.resolve_head(head)
-        if name is None:
-            name = getattr(hd, "name", "custom")
-        return DecodeStream(self, hd, width, temperature=temperature,
-                            top_p=top_p, seed=seed, head_name=name)
-
+    # -- continuous batching: fixed-width streams over a page pool ----------
     def open_paged_stream(self, pool, head: Optional[HeadLike] = None,
                           width: int = 4,
                           temperature: Optional[float] = None,
                           top_p: float = 1.0, seed: int = 0):
-        """Open a continuous decode stream backed by a ``PagePool`` instead
-        of a private contiguous cache: per-slot KV (or logical LSTM) pages
-        with shared-prefix radix reuse and copy-on-write. Same contract as
-        ``open_stream`` — greedy tokens stay bit-identical and attention
-        streams add at most one paged executable per (head, kind, width);
-        LSTM streams reuse the dense steps outright. See
-        ``repro.serving.kvpool.PagedDecodeStream``."""
+        """Open a fixed-width continuous decode stream on this engine over
+        ``pool``: per-slot KV (or logical LSTM) pages with shared-prefix
+        radix reuse and copy-on-write. Greedy tokens are bit-identical to
+        solo ``generate`` calls; attention streams add at most one paged
+        executable per (head, kind, width) and one join per bucket, LSTM
+        streams reuse the dense steps outright and add one join per
+        bucket. See ``repro.serving.kvpool.PagedDecodeStream``."""
         from repro.serving.kvpool.stream import PagedDecodeStream
         name = head if isinstance(head, str) else None
         hd = self.resolve_head(head)
@@ -573,8 +555,8 @@ class DecodeEngine:
                          verify_head: Optional[HeadLike] = None,
                          width: int = 4, draft_len: int = 4,
                          temperature: Optional[float] = None,
-                         top_p: float = 1.0, seed: int = 0,
-                         kv_pool=None, adaptive: bool = True):
+                         top_p: float = 1.0, seed: int = 0, *,
+                         kv_pool, adaptive: bool = True):
         """Open a continuous SPECULATIVE decode stream: ``draft_head``
         drafts up to ``draft_len`` tokens per round through the engine's
         cached decode steps, ``verify_head`` (default: the engine's default
@@ -583,7 +565,8 @@ class DecodeEngine:
         output is bit-identical to a plain ``verify_head`` stream. With
         ``adaptive`` a per-stream ``DraftLenController`` shrinks the live
         draft length when measured acceptance drops (shapes stay padded to
-        ``draft_len``; nothing re-traces). See
+        ``draft_len``; nothing re-traces). Each slot reserves its pages
+        from ``kv_pool`` (a ``PagePool``). See
         ``repro.serving.spec.SpecDecodeStream``."""
         from repro.serving.spec.policy import DraftLenController
         from repro.serving.spec.stream import SpecDecodeStream
@@ -651,256 +634,6 @@ class DecodeEngine:
         return GenerationResult(tokens=np.asarray(beam_tokens[best])[None],
                                 scores=beam_scores[best:best + 1],
                                 steps=max_new)
-
-
-@dataclass
-class _StreamSlot:
-    """One occupied pad slot of a DecodeStream."""
-    tag: object                      # opaque caller handle (scheduler bookkeeping)
-    request: ServeRequest
-    tokens: list                     # generated ids so far (python ints)
-    remaining: int                   # tokens still to decode
-
-
-class DecodeStream:
-    """A fixed-width continuously-batched decode: join-at-step over pad slots.
-
-    The stream owns one width-W decode cache plus per-slot (token, position)
-    state. ``join(request)`` prefills the request SOLO (B=1 — the compiled
-    prefill for its prompt length), computes its first token exactly the way
-    ``generate`` does, and splices the prefilled cache rows into a free
-    slot; ``step()`` advances every occupied slot one token through the SAME
-    cached jitted step ``generate``/``serve_batch`` use, passing a (W,)
-    vector of per-row positions (the vector-``pos`` branch of
-    ``attn_decode`` — LSTM/SSM states ignore position entirely). Because
-    every row of a batched decode step is computed independently, a greedy
-    request's tokens are bit-identical to a solo ``generate`` call no matter
-    when it joined or who shares the stream.
-
-    Compile discipline: the batch width is FIXED at ``width`` — empty slots
-    are padding, so join/retire churn never changes the step's shapes. One
-    vector-pos executable per (head, step kind, width) is traced at the
-    stream's first step and reused for the stream's whole life; repeated
-    streams of the same shape add zero executables
-    (``engine.compiled_step_counts()`` is the audit).
-
-    Sampling: one stream carries ONE sampling static tuple (temperature,
-    top_p, seed) — the scheduler keys streams so this holds. The stream
-    advances a single PRNG chain exactly like ``generate`` (split per step,
-    one batch-wide draw), so an isolated width-1 sampled stream reproduces
-    solo ``generate``; at width > 1 draws depend on stream width and join
-    composition, the same contract ``serve_batch`` documents for group
-    composition.
-    """
-
-    def __init__(self, engine: DecodeEngine, head: SoftmaxHead, width: int,
-                 temperature: Optional[float] = None, top_p: float = 1.0,
-                 seed: int = 0, head_name: str = "custom"):
-        if width < 1:
-            raise ValueError(f"stream width must be >= 1: {width}")
-        self.engine = engine
-        self.head = engine.resolve_head(head)
-        self.head_name = head_name
-        # resilience hooks: the scheduler arms an injector on streams it
-        # opens; the vocab bound backs the always-on output guard (a head
-        # emitting sentinel/NaN ids raises a typed HeadFault instead of
-        # feeding garbage back into the decode)
-        self.fault_injector = None
-        # observability: the scheduler arms its tracer here too; join and
-        # step scopes split host work from the one explicit device fetch
-        self.tracer = NULL_TRACER
-        self.vocab = int(engine.W.shape[0])
-        self.width = int(width)
-        self.temperature = temperature
-        self.top_p = float(top_p)
-        self.seed = int(seed)
-        self.sampled = temperature is not None
-        if self.sampled:
-            self._key = jax.random.key(self.seed)
-        self.cache = engine.model.init_cache(self.width, engine.max_len,
-                                             dtype=engine.cache_dtype)
-        self._repl = None
-        if self.head.mesh is not None:
-            # mesh-placed stream: splices must not mix device-0-committed
-            # solo rows into a mesh-replicated cache (same discipline as
-            # the engine's mesh-aware step wrapper)
-            from jax.sharding import NamedSharding, PartitionSpec
-            self._repl = NamedSharding(self.head.mesh, PartitionSpec())
-            self.cache = jax.device_put(self.cache, self._repl)
-        self.tok = np.zeros((self.width,), np.int32)
-        self.pos = np.zeros((self.width,), np.int32)
-        self.slots: List[Optional[_StreamSlot]] = [None] * self.width
-        self._finished: List[tuple] = []
-
-    # -- capacity ------------------------------------------------------------
-    @property
-    def n_active(self) -> int:
-        return sum(s is not None for s in self.slots)
-
-    @property
-    def free_slots(self) -> int:
-        return self.width - self.n_active
-
-    @property
-    def idle(self) -> bool:
-        """No occupied slots and no completions waiting to be drained —
-        safe for a scheduler to close and replace."""
-        return self.n_active == 0 and not self._finished
-
-    def occupied(self) -> List[tuple]:
-        """[(slot index, tag)] for every occupied slot — what a scheduler
-        scans when deciding whom to preempt."""
-        return [(i, s.tag) for i, s in enumerate(self.slots) if s is not None]
-
-    def _first_free(self) -> int:
-        for i, s in enumerate(self.slots):
-            if s is None:
-                return i
-        raise RuntimeError("DecodeStream is full — check free_slots first")
-
-    # -- join: solo prefill + cache splice into a pad slot -------------------
-    def join(self, request: ServeRequest, tag: object = None) -> int:
-        """Admit one request into a free slot mid-decode. Returns the slot.
-
-        The request's first token comes from its own solo prefill (identical
-        to ``generate``'s first-token path); subsequent tokens come from the
-        shared batched ``step``. A ``max_new == 1`` request completes here
-        and surfaces from the next ``step()``/``pop_finished()``."""
-        eng = self.engine
-        Tp = int(request.prompt.shape[0])
-        if Tp + request.max_new > eng.max_len:
-            raise ValueError(
-                f"request needs {Tp + request.max_new} cache slots, stream "
-                f"max_len is {eng.max_len}")
-        slot = self._first_free()
-        tr = self.tracer
-        with tr.scope("serve.join.prefill", "join"):
-            cache1 = eng.model.init_cache(1, eng.max_len,
-                                          dtype=eng.cache_dtype)
-            h, cache1 = eng._jit_prefill(
-                eng.params, {"tokens": jnp.asarray(request.prompt[None])},
-                cache1)
-            h_last = h[:, -1]
-        if tr.enabled:
-            tr.note(cached_tokens=0, chunks=1)
-        hd = self.head
-        with tr.scope("serve.join.first", "join"):
-            h_in = h_last if hd.is_jittable else np.asarray(h_last)
-            if self.sampled:
-                self._key, k0 = jax.random.split(self._key)
-                first = hd.sample(k0, h_in, self.temperature, self.top_p)
-            else:
-                first = hd.next(h_in)
-        with tr.scope("serve.join.wait", "join"):
-            first = np.asarray(first)
-        # guard BEFORE any stream state mutates: a join-boundary fault
-        # (injected or an honestly degenerate first token) leaves the
-        # stream exactly as it was, so the scheduler can retry or re-route
-        first = int(guard_tokens(self.fault_injector, "join",
-                                 self.head_name, first,
-                                 self.vocab).ravel()[0])
-        with tr.scope("serve.join.splice", "join"):
-            if self._repl is not None:
-                cache1 = jax.device_put(cache1, self._repl)
-            self.cache = _splice_cache(self.cache, cache1, slot,
-                                       eng.model.cfg)
-        self.tok[slot] = first
-        self.pos[slot] = Tp
-        entry = _StreamSlot(tag=tag, request=request, tokens=[first],
-                            remaining=request.max_new - 1)
-        if entry.remaining == 0:
-            self._finished.append(
-                (entry.tag, entry.request,
-                 np.asarray(entry.tokens, np.int32)))
-        else:
-            self.slots[slot] = entry
-        return slot
-
-    # -- step: advance every occupied slot one token -------------------------
-    def step(self) -> List[tuple]:
-        """One batched decode tick. Returns retired ``(tag, request,
-        tokens)`` triples — requests that hit their ``max_new`` this tick
-        (plus any that completed at join). Idle slots decode padding that is
-        never read and is overwritten by the next join's splice."""
-        out = self._finished
-        self._finished = []
-        idx = [i for i, s in enumerate(self.slots) if s is not None]
-        if not idx:
-            return out
-        tr = self.tracer
-        with tr.scope("serve.step", "step"):
-            if tr.enabled:
-                tr.note(head=self.head_name, active=len(idx),
-                        rids=[_tag_id(self.slots[i].tag) for i in idx])
-            eng = self.engine
-            tok = jnp.asarray(self.tok)
-            pos = jnp.asarray(self.pos)
-            # compute into locals and commit (cache, PRNG) only after the
-            # guard: a step-boundary fault leaves the stream untouched, so
-            # the scheduler's retry re-runs the identical step bit-for-bit
-            # (jax caches are immutable pytrees — holding the old reference
-            # IS the rollback, recurrent LSTM state included)
-            if self.sampled:
-                fn = eng._sample_step(self.head, self.temperature,
-                                      self.top_p)
-                key, ki = jax.random.split(self._key)
-                nxt, _, cache = fn(eng.params, ki, tok, self.cache, pos)
-            else:
-                fn = eng._greedy_step(self.head)
-                nxt, _, cache = fn(eng.params, tok, self.cache, pos)
-            with tr.scope("serve.step.wait", "step"):
-                nxt = np.asarray(nxt)
-            nxt = guard_tokens(self.fault_injector, "step", self.head_name,
-                               nxt, self.vocab, rows=idx)
-            if self.sampled:
-                self._key = key
-            self.cache = cache
-            for i in idx:
-                s = self.slots[i]
-                t = int(nxt[i])
-                s.tokens.append(t)
-                s.remaining -= 1
-                self.tok[i] = t
-                self.pos[i] += 1
-                if s.remaining == 0:
-                    out.append((s.tag, s.request,
-                                np.asarray(s.tokens, np.int32)))
-                    self.slots[i] = None
-        return out
-
-    def pop_finished(self) -> List[tuple]:
-        """Drain completions that happened outside ``step`` (max_new == 1
-        joins) without advancing the decode."""
-        out = self._finished
-        self._finished = []
-        return out
-
-    # -- evict: preemption hook ----------------------------------------------
-    def evict(self, slot: int) -> tuple:
-        """Forcibly retire a slot (scheduler preemption). Returns ``(tag,
-        request, partial_tokens)``; the slot is free for the next join."""
-        s = self.slots[slot]
-        if s is None:
-            raise ValueError(f"slot {slot} is not occupied")
-        self.slots[slot] = None
-        return (s.tag, s.request, np.asarray(s.tokens, np.int32))
-
-
-def _tag_id(tag):
-    """The request id of a slot's tag: a scheduler tags each slot with its
-    queued request, whose ``id`` is the result id; None for other tags."""
-    return getattr(tag, "id", None)
-
-
-def _splice_cache(group, solo, slot, cfg):
-    """Write a solo (B=1) prefilled cache into row ``slot`` of a width-W
-    stream cache. Batch axis mirrors ``_reorder_cache``: LSTM state lists
-    carry batch at axis 0; stacked attention/ssm caches at axis 1."""
-    if cfg.family == "lstm":
-        return {"lstm": [{k: g[k].at[slot].set(s[k][0]) for k in g}
-                         for g, s in zip(group["lstm"], solo["lstm"])]}
-    return jax.tree_util.tree_map(lambda g, s: g.at[:, slot].set(s[:, 0]),
-                                  group, solo)
 
 
 def _lstm_join(model, params, ints, src, group):

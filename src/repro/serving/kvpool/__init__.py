@@ -9,8 +9,8 @@ Layout:
                 recurrent-state snapshots), LRU leaf reclamation.
   * store.py  — ``PagedKVStore``: device tensors holding attention K/V
                 pages, join-time prompt scatter, physical COW copy.
-  * stream.py — ``PagedDecodeStream``: the ``DecodeStream``-compatible
-                continuous-batching stream running over pool pages.
+  * stream.py — ``PagedDecodeStream``: the continuous-batching decode
+                stream the scheduler serves on, over pool pages.
 """
 from repro.serving.kvpool.pool import TRASH_PAGE, PagePool, PoolExhausted
 from repro.serving.kvpool.radix import PrefixMatch, RadixCache

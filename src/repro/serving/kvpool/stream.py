@@ -1,9 +1,9 @@
 """Paged continuous-batching decode stream: page tables instead of padding.
 
-``PagedDecodeStream`` is ``DecodeStream``'s drop-in sibling (same
-``join``/``step``/``evict``/``pop_finished`` surface, same fixed width and
-compile discipline) with per-stream contiguous caches replaced by a chain
-of pool pages per slot:
+``PagedDecodeStream`` is the scheduler's plain decode lane: a fixed-width
+batch whose slots each hold a chain of pool pages, driven through
+``join``/``step``/``evict``/``pop_finished`` (the contract is in the class
+docstring):
 
   * ATTENTION families (dense/moe/mla_moe): K/V rows (or MLA latents)
     live in the engine-wide ``PagedKVStore``; each slot owns a page chain
@@ -53,11 +53,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.serving.engine import _StreamSlot, _tag_id
 from repro.serving.kvpool.pool import PagePool, PoolExhausted
 from repro.serving.request import ServeRequest
 from repro.serving.observe.trace import NULL_TRACER
 from repro.serving.resilience.faults import HeadFault, guard_tokens
+
+
+@dataclass
+class _StreamSlot:
+    """One occupied pad slot of a stream."""
+    tag: object                      # opaque caller handle (the scheduler's)
+    request: ServeRequest
+    tokens: list                     # generated ids so far (python ints)
+    remaining: int                   # tokens still to decode
+
+
+def _tag_id(tag):
+    """The request id of a slot's tag: a scheduler tags each slot with its
+    queued request, whose ``id`` is the result id; None for other tags."""
+    return getattr(tag, "id", None)
 
 
 @dataclass(frozen=True)
@@ -77,8 +91,40 @@ class SnapshotHandle:
 
 
 class PagedDecodeStream:
-    """Fixed-width continuous decode over pool pages. See module docstring;
-    ``DecodeStream`` documents the shared join/step/evict contract."""
+    """A fixed-width continuously-batched decode over pool pages:
+    join-at-step over pad slots.
+
+    ``join(request, tag)`` places one request in a free slot mid-decode:
+    its prompt is prefilled in one dispatch (module docstring), its first
+    token is the one ``generate`` would compute, and its pages come from
+    the pool. A ``max_new == 1`` request completes at its join
+    and surfaces from the next ``step()``/``pop_finished()``. ``step()``
+    advances every occupied slot one token in one batched dispatch, with a
+    (W,) vector of per-row positions, and returns the ``(tag, request,
+    tokens)`` triples that reached their ``max_new``. ``pop_finished()``
+    drains completions without advancing the decode; ``evict(slot)``
+    retires a slot early (preemption) and returns its partial tokens.
+    Every row of a batched step is computed independently, so a greedy
+    request's tokens are bit-identical to a solo ``generate`` call no
+    matter when it joined or who shares the stream. A join or step that
+    fails — a ``HeadFault`` at a token guard, ``PoolExhausted`` at a page
+    allocation — commits no token, position, cache or PRNG key, so its
+    retry is bit-identical.
+
+    Compile discipline: the batch width is FIXED at ``width`` — empty slots
+    are padding, so join/retire churn never changes the step's shapes. One
+    step executable per (head, step kind, width) and one join executable
+    per page-grid bucket are traced on first use and reused after
+    (``engine.compiled_step_counts()`` is the audit).
+
+    Sampling: one stream carries ONE sampling static tuple (temperature,
+    top_p, seed) — the scheduler keys streams so this holds. The stream
+    advances a single PRNG chain exactly like ``generate`` (split per step,
+    one batch-wide draw), so an isolated width-1 sampled stream reproduces
+    solo ``generate``; at width > 1 draws depend on stream width and join
+    composition, the same contract ``serve_batch`` documents for group
+    composition.
+    """
 
     def __init__(self, engine, head, width: int, pool: PagePool,
                  temperature: Optional[float] = None, top_p: float = 1.0,
@@ -137,7 +183,7 @@ class PagedDecodeStream:
         self._pages: List[List[int]] = [[] for _ in range(self.width)]
         self._finished: List[tuple] = []
 
-    # -- capacity (DecodeStream contract) ------------------------------------
+    # -- capacity ------------------------------------------------------------
     @property
     def n_active(self) -> int:
         return sum(s is not None for s in self.slots)
@@ -148,6 +194,8 @@ class PagedDecodeStream:
 
     @property
     def idle(self) -> bool:
+        """No occupied slots and no completions waiting to be drained —
+        safe for a scheduler to close and replace."""
         return self.n_active == 0 and not self._finished
 
     @property
@@ -155,6 +203,8 @@ class PagedDecodeStream:
         return sum(len(c) for c in self._pages)
 
     def occupied(self) -> List[tuple]:
+        """[(slot index, tag)] for every occupied slot — what a scheduler
+        scans when deciding whom to preempt."""
         return [(i, s.tag) for i, s in enumerate(self.slots) if s is not None]
 
     def _first_free(self) -> int:
@@ -382,9 +432,10 @@ class PagedDecodeStream:
                 self.table[i, j] = chain[j]
 
     def step(self) -> List[tuple]:
-        """One batched decode tick; same contract as ``DecodeStream.step``.
-        May raise ``PoolExhausted`` BEFORE any state advances — the
-        scheduler frees pages (cache eviction / preemption) and re-ticks."""
+        """One batched decode tick: returns the retired ``(tag, request,
+        tokens)`` triples (class docstring). May raise ``PoolExhausted``
+        BEFORE any state advances — the scheduler frees pages (cache
+        eviction / preemption) and re-ticks."""
         idx = [i for i, s in enumerate(self.slots) if s is not None]
         if not idx:
             return self.pop_finished()
@@ -411,7 +462,7 @@ class PagedDecodeStream:
             # retry rewrites with the same values
             key = cache = None
             if self.family == "lstm":
-                # the SAME cached dense step DecodeStream uses — the paged
+                # the SAME cached dense step generate uses — the paged
                 # LSTM path adds zero step executables by construction
                 if self.sampled:
                     fn = eng._sample_step(self.head, self.temperature,

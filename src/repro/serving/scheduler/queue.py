@@ -144,15 +144,13 @@ class RequestQueue:
 class SchedulerLoad:
     """What the scheduler is already committed to, as admission sees it.
 
-    The ``pages_*`` fields exist only under a paged scheduler
-    (``ContinuousScheduler(kv_pool=...)``): ``pages_free is None`` means no
-    pool is attached, and page-aware policies must not reject on memory.
+    The ``pages_*`` fields describe the scheduler's ``PagePool``.
     ``request_pages`` is THIS submission's marginal page demand (prompt +
     max_new pages minus shared-prefix pages already resident)."""
     flops_in_flight: float = 0.0     # per-step flops of queued + running work
     queued: int = 0                  # admitted requests not yet in a slot
     active: int = 0                  # occupied decode slots
-    pages_free: Optional[int] = None   # pool pages on the free list
+    pages_free: int = 0                # pool pages on the free list
     pages_evictable: int = 0           # cache-held pages reclaimable under pressure
     pages_queued: int = 0              # marginal pages of admitted-unplaced work
     request_pages: int = 0             # marginal pages of the request being admitted
@@ -281,7 +279,7 @@ class BudgetAdmission(AdmissionPolicy):
         # pool pressure first: KV pages are head-independent, so when the
         # pool (free + cache-reclaimable, net of already-queued demand)
         # cannot back this request's marginal pages, no downgrade helps
-        if load.pages_free is not None and load.request_pages > 0:
+        if load.request_pages > 0:
             headroom = (load.pages_free + load.pages_evictable
                         - load.pages_queued)
             if load.request_pages > headroom:

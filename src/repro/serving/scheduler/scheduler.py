@@ -7,7 +7,8 @@ through the ``AdmissionPolicy`` against the current load, and — if admitted
 — queued with an arrival stamp and tier deadline. Each ``step()`` tick
 then:
 
-  1. PLACES waiting requests into head-keyed ``DecodeStream`` microbatches
+  1. PLACES waiting requests into head-keyed ``PagedDecodeStream``
+     microbatches over the scheduler's ``PagePool``
      (fixed width ``max_slots``; join-at-step — a request enters a RUNNING
      stream's free pad slot at a sequence boundary, no recompile, no wait
      for the stream to drain);
@@ -42,9 +43,10 @@ from __future__ import annotations
 import math
 import time
 from collections import OrderedDict
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import TYPE_CHECKING, Callable, Dict, List, Optional, Sequence, \
+    Union
 
-from repro.serving.engine import DecodeEngine, DecodeStream
+from repro.serving.engine import DecodeEngine
 from repro.serving.kvpool.pool import PoolExhausted
 from repro.serving.observe.trace import NULL_TRACER
 from repro.serving.request import ServeRequest, ServeResult
@@ -57,6 +59,11 @@ from repro.serving.scheduler.queue import (AcceptAll, AdmissionPolicy,
                                            head_flops, head_flops_modeled,
                                            tier_priority)
 from repro.serving.scheduler.stats import ServerStats
+
+if TYPE_CHECKING:
+    from repro.serving.kvpool.stream import PagedDecodeStream
+    from repro.serving.spec.stream import SpecDecodeStream
+    Stream = Union[PagedDecodeStream, SpecDecodeStream]
 
 
 class SchedulerStalled(RuntimeError):
@@ -87,9 +94,11 @@ class ContinuousScheduler:
     ``clock``       injectable monotonic clock for arrival/deadline/latency
                     bookkeeping (tests pass a fake; throughput telemetry
                     always uses the real wall clock).
-    ``kv_pool``     optional ``repro.serving.kvpool.PagePool``: streams
-                    become ``PagedDecodeStream``s sharing the pool's pages
-                    and shared-prefix radix cache; admission prices each
+    ``kv_pool``     the ``repro.serving.kvpool.PagePool`` every stream
+                    serves over (required; its size is the caller's
+                    memory decision): plain streams are
+                    ``PagedDecodeStream``s sharing the pool's pages and
+                    shared-prefix radix cache; admission prices each
                     request by its MARGINAL pages (prompt + max_new pages
                     minus radix-resident prefix pages); ``PoolExhausted``
                     at placement or step becomes a first-class pressure
@@ -104,8 +113,8 @@ class ContinuousScheduler:
                     (emitted tokens stay the verify head's — speculation
                     never changes output). Admission prices the draft
                     head's extra per-step flops
-                    (``SchedulerLoad.request_extra_flops``) and, under a
-                    pool, the ``draft_len − 1`` rollback pages a round can
+                    (``SchedulerLoad.request_extra_flops``) and the
+                    ``draft_len − 1`` rollback pages a round can
                     transiently write; a DOWNGRADE drops the spec
                     assignment along with the routed head.
     ``fault_injector`` optional ``resilience.FaultInjector`` armed on every
@@ -136,8 +145,8 @@ class ContinuousScheduler:
                  admission: Optional[AdmissionPolicy] = None,
                  max_slots: int = 4, max_streams: int = 8,
                  deadlines: Optional[Dict[str, float]] = None,
-                 clock: Callable[[], float] = time.monotonic,
-                 kv_pool=None, spec=None, fault_injector=None,
+                 clock: Callable[[], float] = time.monotonic, *,
+                 kv_pool, spec=None, fault_injector=None,
                  breaker=None, watchdog=None, max_retries: int = 2,
                  tracer=None):
         if max_slots < 1 or max_streams < 1:
@@ -155,7 +164,7 @@ class ContinuousScheduler:
         self.clock = clock
         self.queue = RequestQueue(clock=clock, deadlines=deadlines)
         self.stats = ServerStats()
-        self._streams: "OrderedDict[tuple, DecodeStream]" = OrderedDict()
+        self._streams: "OrderedDict[tuple, Stream]" = OrderedDict()
         self._results: Dict[int, object] = {}
         self._order: List[int] = []
         self._next_rid = 0          # monotonic even after pop_results()
@@ -170,8 +179,7 @@ class ContinuousScheduler:
         self._retry_at: Dict[tuple, int] = {}   # stream sig -> resume tick
         self._fail_count: Dict[tuple, int] = {}  # sig -> consecutive faults
         self.tracer = tracer if tracer is not None else NULL_TRACER
-        if kv_pool is not None:
-            kv_pool.tracer = self.tracer
+        kv_pool.tracer = self.tracer
         self._trace_t0: Dict[int, float] = {}   # rid -> submit stamp
         if breaker is not None:
             # chain the breaker's transition hook through ServerStats so
@@ -266,17 +274,15 @@ class ContinuousScheduler:
 
     def _load(self) -> SchedulerLoad:
         running = sum(qr.cost for qr in self._inflight.values())
-        load = SchedulerLoad(
+        pool = self.kv_pool
+        return SchedulerLoad(
             flops_in_flight=self.queue.flops_pending + running,
             queued=len(self.queue),
-            active=sum(s.n_active for s in self._streams.values()))
-        pool = self.kv_pool
-        if pool is not None:
-            load.pages_free = pool.pages_free
-            load.pages_evictable = pool.radix.evictable_pages() \
-                if pool.radix is not None else 0
-            load.pages_queued = sum(qr.pages for qr in self.queue)
-        return load
+            active=sum(s.n_active for s in self._streams.values()),
+            pages_free=pool.pages_free,
+            pages_evictable=pool.radix.evictable_pages()
+            if pool.radix is not None else 0,
+            pages_queued=sum(qr.pages for qr in self.queue))
 
     def _marginal_pages(self, request: ServeRequest,
                         draft_slack: int = 0) -> int:
@@ -351,9 +357,8 @@ class ContinuousScheduler:
                                                     self.engine.max_len)
         with tr.scope("serve.submit.load", "admission"):
             load = self._load()
-            if self.kv_pool is not None:
-                load.request_pages = self._marginal_pages(
-                    request, draft_slack=draft_len - 1 if draft else 0)
+            load.request_pages = self._marginal_pages(
+                request, draft_slack=draft_len - 1 if draft else 0)
         if draft is not None:
             load.request_extra_flops = head_flops(catalog, draft)
         decision = self.admission.admit(request, name, catalog, load)
@@ -364,8 +369,7 @@ class ContinuousScheduler:
             # to dropping the head the router chose
             draft, draft_len = None, 0
             load.request_extra_flops = 0.0
-            if self.kv_pool is not None:
-                load.request_pages = self._marginal_pages(request)
+            load.request_pages = self._marginal_pages(request)
             decision = self.admission.admit(request, name, catalog, load)
         if decision.action == "reject":
             self._results[rid] = AdmissionRejected(
@@ -413,7 +417,8 @@ class ContinuousScheduler:
             sig += ("spec", qr.draft, qr.draft_len)
         return sig
 
-    def _stream_for(self, qr: QueuedRequest) -> Optional[DecodeStream]:
+    def _stream_for(self, qr: QueuedRequest
+                    ) -> Optional[Union[PagedDecodeStream, SpecDecodeStream]]:
         sig = self._sig(qr)
         stream = self._streams.get(sig)
         if stream is not None:
@@ -434,13 +439,9 @@ class ContinuousScheduler:
                 temperature=req.temperature, top_p=req.top_p, seed=req.seed,
                 kv_pool=self.kv_pool,
                 adaptive=getattr(self.spec, "adaptive", True))
-        elif self.kv_pool is not None:
+        else:
             stream = self.engine.open_paged_stream(
                 self.kv_pool, head=qr.head, width=self.max_slots,
-                temperature=req.temperature, top_p=req.top_p, seed=req.seed)
-        else:
-            stream = self.engine.open_stream(
-                head=qr.head, width=self.max_slots,
                 temperature=req.temperature, top_p=req.top_p, seed=req.seed)
         stream.fault_injector = self.fault_injector
         stream.tracer = self.tracer
@@ -928,9 +929,8 @@ class ContinuousScheduler:
                 terminal += 1
                 self._trace_terminal(qr.id, "timed_out", head=qr.head)
         with tr.scope("serve.tick.observe", "scheduler"):
-            if self.kv_pool is not None:
-                self.stats.observe_pool(self.kv_pool.telemetry(),
-                                        stalled=pool_blocked)
+            self.stats.observe_pool(self.kv_pool.telemetry(),
+                                    stalled=pool_blocked)
             self.stats.observe_queue(len(self.queue))
         if tr.enabled:
             tr.note(tick=self.stats.ticks, joins=joins, steps=steps,

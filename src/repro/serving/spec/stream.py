@@ -42,8 +42,8 @@ Compile discipline: drafts ride the engine's cached ``_greedy_step`` /
 adaptive ``DraftLenController`` shrinking n never re-traces. Zero new
 executables after warmup (``compiled_step_counts`` is the audit).
 
-KV paging: with a ``kv_pool`` the stream takes a LOGICAL page reservation
-per slot — ``ceil((Tp + max_new + n_max − 1) / page_size)`` pages, the
+KV paging: each slot takes a LOGICAL page reservation from the stream's
+``kv_pool`` — ``ceil((Tp + max_new + n_max − 1) / page_size)`` pages, the
 ``n_max − 1`` slack being the rejected-token positions a round can
 transiently write past the request's final token. Reservations give the
 pool's admission/pressure machinery real numbers (``PoolExhausted``
@@ -85,6 +85,17 @@ def _needs_snapshot(cfg) -> bool:
         getattr(cfg, "sliding_window", None) is not None
 
 
+def _splice_cache(group, solo, slot, cfg):
+    """Write a solo (B=1) prefilled cache into row ``slot`` of a width-W
+    stream cache. LSTM state lists carry batch at axis 0; stacked
+    attention/ssm caches at axis 1."""
+    if cfg.family == "lstm":
+        return {"lstm": [{k: g[k].at[slot].set(s[k][0]) for k in g}
+                         for g, s in zip(group["lstm"], solo["lstm"])]}
+    return jax.tree_util.tree_map(lambda g, s: g.at[:, slot].set(s[:, 0]),
+                                  group, solo)
+
+
 def _select_snapshots(snaps, sel, cfg):
     """Per-row snapshot restore: ``snaps[j]`` is the cache pytree after
     draft step j; row i resumes from ``snaps[sel[i]]``. Batch-axis split
@@ -109,8 +120,9 @@ def _select_snapshots(snaps, sel, cfg):
 
 
 class SpecDecodeStream:
-    """Drop-in ``DecodeStream`` lane (same join/step/evict/pop_finished/
-    occupied surface the scheduler drives) that decodes speculatively.
+    """A scheduler lane (the join/step/evict/pop_finished/occupied contract
+    of ``repro.serving.kvpool.PagedDecodeStream``) that decodes
+    speculatively over its own dense cache.
 
     One ``step()`` is one whole draft/verify ROUND, emitting 1..n tokens
     per active slot (a plain stream emits exactly 1). The first token after
@@ -122,8 +134,8 @@ class SpecDecodeStream:
                  draft_len: int = 4, temperature: Optional[float] = None,
                  top_p: float = 1.0, seed: int = 0,
                  draft_name: str = "draft", verify_name: str = "verify",
-                 controller: Optional[DraftLenController] = None,
-                 kv_pool=None):
+                 controller: Optional[DraftLenController] = None, *,
+                 kv_pool):
         if width < 1:
             raise ValueError(f"stream width must be >= 1: {width}")
         if draft_len < 1:
@@ -194,7 +206,7 @@ class SpecDecodeStream:
         self.verify_queries = 0
         self.verify_flops = 0.0
 
-    # -- capacity (DecodeStream surface) -------------------------------------
+    # -- capacity ------------------------------------------------------------
     @property
     def n_active(self) -> int:
         return sum(s is not None for s in self.slots)
@@ -245,10 +257,9 @@ class SpecDecodeStream:
         # head fault mid-prefill — releases the reservation and leaves the
         # stream untouched (splice/PRNG commit only after the guard passes)
         try:
-            if self.kv_pool is not None:
-                P = self.kv_pool.page_size
-                for _ in range(-(-need // P)):
-                    pages.append(self.kv_pool.alloc())
+            P = self.kv_pool.page_size
+            for _ in range(-(-need // P)):
+                pages.append(self.kv_pool.alloc())
             cache1 = eng.model.init_cache(1, eng.max_len,
                                           dtype=eng.cache_dtype)
             h, cache1 = eng._jit_prefill(
@@ -273,7 +284,6 @@ class SpecDecodeStream:
             self._key = key
         if self._repl is not None:
             cache1 = jax.device_put(cache1, self._repl)
-        from repro.serving.engine import _splice_cache
         self.cache = _splice_cache(self.cache, cache1, slot, eng.model.cfg)
         self.tok[slot] = first
         self.pos[slot] = Tp
@@ -289,15 +299,14 @@ class SpecDecodeStream:
         return slot
 
     def _release_pages(self, entry: _SpecSlot) -> None:
-        if self.kv_pool is not None:
-            for pg in entry.pages:
-                self.kv_pool.release(pg)
-            entry.pages = []
+        for pg in entry.pages:
+            self.kv_pool.release(pg)
+        entry.pages = []
 
     # -- the round -----------------------------------------------------------
     def step(self) -> List[tuple]:
         """One draft/verify round. Returns retired (tag, request, tokens)
-        triples, like ``DecodeStream.step``."""
+        triples, like ``PagedDecodeStream.step``."""
         out = self._finished
         self._finished = []
         idx = [i for i, s in enumerate(self.slots) if s is not None]

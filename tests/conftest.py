@@ -75,3 +75,20 @@ def isolated_compile_cache(monkeypatch, tmp_path):
     for name, value in saved.items():
         jax.config.update(name, value)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="session")
+def page_pool():
+    """Factory of the page pool a scheduler test serves over:
+    ``page_pool(engine, sequences=64)`` gives pages of the engine's
+    ``max_len`` — one page per sequence, a dense cache's own footprint,
+    plus the copy its first decode write makes of the prompt page the
+    radix cache keeps — for ``sequences`` sequences at once, more than any
+    test holds, so a test meets pool pressure only where it builds a
+    smaller pool."""
+    from repro.serving import PagePool
+
+    def make(engine, sequences=64):
+        return PagePool(num_pages=1 + 2 * sequences,
+                        page_size=engine.max_len)
+    return make

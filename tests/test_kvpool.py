@@ -327,23 +327,6 @@ def test_join_rolls_back_on_exhaustion(lstm_engine):
     assert np.array_equal(got[0], ref)
 
 
-# -- sampled streams over pages ----------------------------------------------
-
-def test_lstm_paged_sampled_stream_matches_unpaged(lstm_engine):
-    """A sampled paged stream advances the identical PRNG chain as the
-    dense ``DecodeStream`` — same joins, same width, same draws."""
-    cfg, eng = lstm_engine
-    reqs = _prefix_requests(cfg, 3, max_new=4, seed=13)
-    for r in reqs:
-        r.temperature, r.seed = 0.8, 11
-    kw = dict(width=2, temperature=0.8, top_p=0.95, seed=11)
-    got_plain = _run_stream(eng.open_stream(**kw), reqs)
-    got_paged = _run_stream(
-        eng.open_paged_stream(PagePool(64, 4), **kw), reqs)
-    for i in range(len(reqs)):
-        assert np.array_equal(got_plain[i], got_paged[i])
-
-
 # -- scheduler integration ----------------------------------------------------
 
 def test_scheduler_paged_drain_parity(lstm_engine):

@@ -392,7 +392,7 @@ def _by_name(tr, name):
     return [e for e in tr.events() if e["name"] == name]
 
 
-def test_scheduler_traces_request_lifecycle(trained):
+def test_scheduler_traces_request_lifecycle(trained, page_pool):
     """Every completed request leaves one submit->retire "request" span on
     its own lane plus submit/admit/join instants and a queue.wait span;
     the scheduler lane carries tick spans; kernel dispatch windows are
@@ -404,11 +404,13 @@ def test_scheduler_traces_request_lifecycle(trained):
                          latency_tier=("realtime", "standard")[i % 2])
             for i, p in enumerate(corpus.sample_batch(4, 6, seed=31))]
     # warmup so the traced drain is compile-free
-    ContinuousScheduler(eng, policy=policy, max_slots=2).serve(reqs)
+    ContinuousScheduler(eng, policy=policy, max_slots=2,
+                        kv_pool=page_pool(eng)).serve(reqs)
     counts0 = eng.compiled_step_counts()
 
     tr = Tracer(clock=FakeClock(dt=1e-4))
-    sched = ContinuousScheduler(eng, policy=policy, max_slots=2, tracer=tr)
+    sched = ContinuousScheduler(eng, policy=policy, max_slots=2, tracer=tr,
+                                kv_pool=page_pool(eng))
     out = sched.serve(reqs)
     assert all(isinstance(r, ServeResult) for r in out)
     assert eng.compiled_step_counts() == counts0    # tracing is host-side
@@ -583,29 +585,6 @@ def test_pool_reclaim_span_nests_under_its_allocation(trained):
         sched.kv_pool.radix.evictions
 
 
-def test_decode_stream_span_tree(trained):
-    """The unpaged ``DecodeStream`` has the same tree minus what it has no
-    pool for: no radix match, no page growth."""
-    cfg, _, _, corpus, _ = trained
-    eng = _engine(trained)
-    tr = Tracer(clock=FakeClock(dt=1e-4))
-    reqs = [ServeRequest(prompt=p, max_new=3)
-            for p in corpus.sample_batch(3, 6, seed=67)]
-    out = ContinuousScheduler(eng, policy=StaticPolicy("screened"),
-                              max_slots=2, tracer=tr).serve(reqs)
-    assert all(isinstance(r, ServeResult) for r in out)
-    kids = _kids(tr)
-    joins = _by_name(tr, "serve.join")
-    assert len(joins) == len(reqs)
-    for j in joins:
-        assert sorted(c["name"] for c in kids[j["args"]["id"]]) == [
-            n for n in JOIN_KIDS if n != "serve.join.match"]
-        assert j["args"]["cached_tokens"] == 0 and j["args"]["chunks"] == 1
-    for st in _by_name(tr, "serve.step"):
-        assert [c["name"] for c in kids[st["args"]["id"]]] == [
-            "serve.step.wait"]
-
-
 def test_join_instant_unchanged(trained):
     """The ``join`` instant keeps its shape and place: on the request's
     lane, stamped after its ``serve.join`` scope closes, with ``join_s``
@@ -648,7 +627,7 @@ def test_tracing_compiles_nothing_and_keeps_tokens(trained):
         np.testing.assert_array_equal(a.tokens, b.tokens)
 
 
-def test_scheduler_traces_reject_and_fault_paths(trained):
+def test_scheduler_traces_reject_and_fault_paths(trained, page_pool):
     """Terminal spans cover the non-happy outcomes too: an admission
     reject retires on its own lane, and injected faults leave fault +
     retry instants (transient) or a fallback instant (permanent) with the
@@ -666,7 +645,8 @@ def test_scheduler_traces_reject_and_fault_paths(trained):
     sched = ContinuousScheduler(
         eng, policy=StaticPolicy("screened"), max_slots=2, clock=clk,
         fault_injector=inj, max_retries=3, tracer=tr,
-        breaker=CircuitBreaker(failure_threshold=5, clock=clk))
+        breaker=CircuitBreaker(failure_threshold=5, clock=clk),
+        kv_pool=page_pool(eng))
     out = sched.serve([ServeRequest(prompt=p[0], max_new=4)])
     assert isinstance(out[0], ServeResult)
     faults = _by_name(tr, "fault")
@@ -682,7 +662,7 @@ def test_scheduler_traces_reject_and_fault_paths(trained):
         eng, policy=StaticPolicy("svd"), max_slots=2, clock=clk2,
         fault_injector=inj, tracer=tr2,
         breaker=CircuitBreaker(failure_threshold=3, cooldown_s=100.0,
-                               clock=clk2))
+                               clock=clk2), kv_pool=page_pool(eng))
     out2 = sched2.serve([ServeRequest(prompt=p[1], max_new=4)])
     assert isinstance(out2[0], ServeResult) and out2[0].head == "exact"
     fb = _by_name(tr2, "fallback")
@@ -720,7 +700,7 @@ def test_compiled_step_counts_paged_kind_and_redrain_flat():
         np.testing.assert_array_equal(a.tokens, b.tokens)
 
 
-def test_compiled_step_counts_spec_verify_kind(trained):
+def test_compiled_step_counts_spec_verify_kind(trained, page_pool):
     """A speculative stream adds the draft's "greedy" step AND the
     verifier's "spec-verify" step to the cache, both flat on re-drain."""
     cfg, _, _, corpus, _ = trained
@@ -730,17 +710,18 @@ def test_compiled_step_counts_spec_verify_kind(trained):
     reqs = [ServeRequest(prompt=p, max_new=4)
             for p in corpus.sample_batch(2, 6, seed=51)]
     out = ContinuousScheduler(eng, policy=StaticPolicy("exact"),
-                              max_slots=2, spec=pol).serve(reqs)
+                              max_slots=2, spec=pol,
+                              kv_pool=page_pool(eng)).serve(reqs)
     assert all(isinstance(r, ServeResult) for r in out)
     counts = eng.compiled_step_counts()
     assert ("exact", "spec-verify") in counts
     assert ("screened", "greedy") in counts
     ContinuousScheduler(eng, policy=StaticPolicy("exact"), max_slots=2,
-                        spec=pol).serve(reqs)
+                        spec=pol, kv_pool=page_pool(eng)).serve(reqs)
     assert eng.compiled_step_counts() == counts
 
 
-def test_compiled_step_counts_flat_under_retries(trained):
+def test_compiled_step_counts_flat_under_retries(trained, page_pool):
     """The resilience path reuses the identical compiled step on retry: a
     faulted-and-retried drain adds zero executables over a clean one."""
     cfg, _, _, corpus, _ = trained
@@ -748,14 +729,15 @@ def test_compiled_step_counts_flat_under_retries(trained):
     req = ServeRequest(prompt=corpus.sample_batch(1, 6, seed=53)[0],
                        max_new=4)
     ContinuousScheduler(eng, policy=StaticPolicy("screened"),
-                        max_slots=2).serve([req])
+                        max_slots=2, kv_pool=page_pool(eng)).serve([req])
     counts0 = eng.compiled_step_counts()
     inj = FaultInjector(seed=0)
     inj.arm("step", "transient", head="screened", count=2)
     sched = ContinuousScheduler(
         eng, policy=StaticPolicy("screened"), max_slots=2,
         fault_injector=inj, max_retries=3,
-        breaker=CircuitBreaker(failure_threshold=5, clock=LogicalClock()))
+        breaker=CircuitBreaker(failure_threshold=5, clock=LogicalClock()),
+        kv_pool=page_pool(eng))
     out = sched.serve([req])
     assert isinstance(out[0], ServeResult)
     assert sched.stats.retries == 2

@@ -291,7 +291,7 @@ def _engine(trained, max_len=36):
                                          n_top=cfg.vocab_size))
 
 
-def test_transient_fault_retries_bit_identical(trained):
+def test_transient_fault_retries_bit_identical(trained, page_pool):
     """One injected transient step fault: the scheduler retries the SAME
     stream after backoff and — because the streams commit key/cache only
     after the guard passes — the greedy decode is bit-identical to the
@@ -301,7 +301,8 @@ def test_transient_fault_retries_bit_identical(trained):
     req = ServeRequest(prompt=corpus.sample_batch(1, 6, seed=41)[0],
                        max_new=6)
     ref = ContinuousScheduler(
-        eng, policy=StaticPolicy("screened"), max_slots=2).serve([req])[0]
+        eng, policy=StaticPolicy("screened"), max_slots=2,
+        kv_pool=page_pool(eng)).serve([req])[0]
     assert isinstance(ref, ServeResult) and ref.head == "screened"
 
     inj = FaultInjector(seed=0)
@@ -310,7 +311,7 @@ def test_transient_fault_retries_bit_identical(trained):
                                 max_slots=2, fault_injector=inj,
                                 breaker=CircuitBreaker(failure_threshold=5,
                                                        clock=LogicalClock()),
-                                max_retries=3)
+                                max_retries=3, kv_pool=page_pool(eng))
     out = sched.serve([req])[0]
     assert isinstance(out, ServeResult) and out.head == "screened"
     np.testing.assert_array_equal(out.tokens, ref.tokens)
@@ -320,7 +321,7 @@ def test_transient_fault_retries_bit_identical(trained):
     assert 0 in sched.fault_rids            # parity excludes touched rids
 
 
-def test_permanent_fault_trips_breaker_and_falls_back(trained):
+def test_permanent_fault_trips_breaker_and_falls_back(trained, page_pool):
     """A hard fault on the routed head: instant breaker trip, the running
     request re-routes to a healthy head (exact is the universal last
     resort) and completes there — output equals exact's solo decode."""
@@ -333,7 +334,8 @@ def test_permanent_fault_trips_breaker_and_falls_back(trained):
     br = CircuitBreaker(failure_threshold=3, cooldown_s=100.0,
                         clock=LogicalClock())
     sched = ContinuousScheduler(eng, policy=StaticPolicy("svd"), max_slots=2,
-                                fault_injector=inj, breaker=br)
+                                fault_injector=inj, breaker=br,
+                                kv_pool=page_pool(eng))
     out = sched.serve([req])[0]
     assert isinstance(out, ServeResult) and out.head == "exact"
     ref = eng.generate(req.prompt[None], req.max_new).tokens[0]
@@ -347,7 +349,7 @@ def test_permanent_fault_trips_breaker_and_falls_back(trained):
                for _, h, _, n in rz["breaker_transitions"])
 
 
-def test_breaker_open_vetoes_placement_until_half_open(trained):
+def test_breaker_open_vetoes_placement_until_half_open(trained, page_pool):
     """While a head's breaker is open, NEW requests route around it (the
     ``breaker_open`` stamp in head_eligible); after cooldown the half-open
     probe lets traffic place again and a success closes the breaker."""
@@ -357,7 +359,7 @@ def test_breaker_open_vetoes_placement_until_half_open(trained):
     br = CircuitBreaker(failure_threshold=1, cooldown_s=0.5, clock=clk)
     policy = TierPolicy({"realtime": "screened"}, default="screened")
     sched = ContinuousScheduler(eng, policy=policy, max_slots=2, clock=clk,
-                                breaker=br)
+                                breaker=br, kv_pool=page_pool(eng))
     br.record_failure("screened", hard=True)        # trip it out-of-band
     p = corpus.sample_batch(2, 6, seed=47)
     out = sched.serve([ServeRequest(prompt=p[0], max_new=4)])[0]
@@ -371,7 +373,7 @@ def test_breaker_open_vetoes_placement_until_half_open(trained):
     assert rz["breaker_half_opens"] >= 1 and rz["breaker_closes"] >= 1
 
 
-def test_request_timeout_returns_typed_partial(trained):
+def test_request_timeout_returns_typed_partial(trained, page_pool):
     """timeout_s elapses mid-decode on the scheduler's clock: the request
     terminates as AdmissionRejected(stage="timeout") carrying the partial
     tokens; everything else completes untouched."""
@@ -381,7 +383,8 @@ def test_request_timeout_returns_typed_partial(trained):
     p = corpus.sample_batch(2, 6, seed=53)
     slow = ServeRequest(prompt=p[0], max_new=24, timeout_s=0.02)
     fine = ServeRequest(prompt=p[1], max_new=4)
-    sched = ContinuousScheduler(eng, max_slots=2, clock=clk)
+    sched = ContinuousScheduler(eng, max_slots=2, clock=clk,
+                                kv_pool=page_pool(eng))
     res = sched.serve([slow, fine])
     assert isinstance(res[0], AdmissionRejected)
     assert res[0].stage == "timeout" and "timeout" in res[0].reason
@@ -391,7 +394,7 @@ def test_request_timeout_returns_typed_partial(trained):
     assert sched.stats.snapshot()["resilience"]["timed_out"] == 1
 
 
-def test_watchdog_evicts_stalled_request_to_fallback(trained):
+def test_watchdog_evicts_stalled_request_to_fallback(trained, page_pool):
     """An endless injected stall on the routed head: the watchdog notices
     zero token progress, evicts the request, and the fallback path serves
     it to completion on a healthy head."""
@@ -403,7 +406,7 @@ def test_watchdog_evicts_stalled_request_to_fallback(trained):
     sched = ContinuousScheduler(
         eng, policy=StaticPolicy("screened"), max_slots=2, clock=clk,
         fault_injector=inj, breaker=CircuitBreaker(clock=clk),
-        watchdog=StreamWatchdog(stall_timeout_s=5e-3))
+        watchdog=StreamWatchdog(stall_timeout_s=5e-3), kv_pool=page_pool(eng))
     req = ServeRequest(prompt=corpus.sample_batch(1, 6, seed=59)[0],
                        max_new=5)
     out = sched.serve([req])[0]
@@ -413,7 +416,7 @@ def test_watchdog_evicts_stalled_request_to_fallback(trained):
     assert 0 in sched.fault_rids
 
 
-def test_drain_stall_raises_typed_scheduler_stalled(trained):
+def test_drain_stall_raises_typed_scheduler_stalled(trained, page_pool):
     """With NO watchdog and every head stalled, drain() cannot progress —
     it must raise the typed SchedulerStalled naming the stuck rids, not
     spin forever or return a short result list."""
@@ -422,7 +425,8 @@ def test_drain_stall_raises_typed_scheduler_stalled(trained):
     inj = FaultInjector(seed=0)
     inj.arm("step", "stall")                        # any head, forever
     sched = ContinuousScheduler(eng, policy=StaticPolicy("exact"),
-                                max_slots=2, fault_injector=inj)
+                                max_slots=2, fault_injector=inj,
+                                kv_pool=page_pool(eng))
     sched.submit(ServeRequest(prompt=corpus.sample_batch(1, 6, seed=61)[0],
                               max_new=4))
     with pytest.raises(SchedulerStalled) as ei:
@@ -430,10 +434,10 @@ def test_drain_stall_raises_typed_scheduler_stalled(trained):
     assert ei.value.rids and ei.value.stats["ticks"] > 0
 
 
-def test_drain_max_ticks_exhaustion_raises(trained):
+def test_drain_max_ticks_exhaustion_raises(trained, page_pool):
     cfg, _, _, corpus, _ = trained
     eng = _engine(trained)
-    sched = ContinuousScheduler(eng, max_slots=2)
+    sched = ContinuousScheduler(eng, max_slots=2, kv_pool=page_pool(eng))
     sched.submit(ServeRequest(prompt=corpus.sample_batch(1, 6, seed=67)[0],
                               max_new=20))
     with pytest.raises(SchedulerStalled, match="max_ticks"):
@@ -557,7 +561,8 @@ def test_fault_paths_leak_no_kv_pages(trained, seed):
     assert pool.live_pages() == {}
 
 
-def test_chaos_54_requests_funnel_parity_breakers_recompiles(trained):
+def test_chaos_54_requests_funnel_parity_breakers_recompiles(trained,
+                                                             page_pool):
     """THE acceptance test: 54 requests across screened/svd/exact on one
     LogicalClock, under transient + permanent + NaN + stall + tick-delay
     fire with breaker, watchdog, retries and timeouts all armed. drain()
@@ -582,7 +587,8 @@ def test_chaos_54_requests_funnel_parity_breakers_recompiles(trained):
     warm = [ServeRequest(prompt=prompts[0], max_new=2, head=name)
             for name in catalog]
     ContinuousScheduler(eng, policy=policy, max_slots=3,
-                        max_streams=len(catalog) + 1).serve(warm)
+                        max_streams=len(catalog) + 1,
+                        kv_pool=page_pool(eng)).serve(warm)
     counts0 = eng.compiled_step_counts()
 
     clock = LogicalClock(0.0, dt_per_read=1e-3)
@@ -599,7 +605,8 @@ def test_chaos_54_requests_funnel_parity_breakers_recompiles(trained):
         clock=clock, fault_injector=inj,
         breaker=CircuitBreaker(failure_threshold=3, cooldown_s=0.05,
                                clock=clock),
-        watchdog=StreamWatchdog(stall_timeout_s=5e-3), max_retries=2)
+        watchdog=StreamWatchdog(stall_timeout_s=5e-3), max_retries=2,
+        kv_pool=page_pool(eng))
     for r in requests:
         sched.submit(r)
     results = sched.drain(max_ticks=5000)   # terminates cleanly or raises

@@ -1,4 +1,4 @@
-"""Continuous-batching scheduler subsystem: DecodeStream join-at-step
+"""Continuous-batching scheduler subsystem: PagedDecodeStream join-at-step
 parity (LSTM + transformer KV-cache, single- and multi-device), scheduler
 drain bit-parity vs serve_batch with zero recompiles after warmup,
 admission control against flops budgets (reject / downgrade, typed
@@ -19,8 +19,9 @@ from repro.launch.steps import make_train_step
 from repro.models import build_model
 from repro.optim import adamw_init
 from repro.serving import (AdmissionRejected, BudgetAdmission,
-                           ContinuousScheduler, DecodeEngine, ServeRequest,
-                           ServeResult, StaticPolicy, TierPolicy)
+                           ContinuousScheduler, DecodeEngine, PagePool,
+                           ServeRequest, ServeResult, StaticPolicy,
+                           TierPolicy)
 from repro.serving.scheduler import (AdmissionDecision, RequestQueue,
                                      SchedulerLoad, TIER_DEADLINES)
 
@@ -75,14 +76,14 @@ def _reqs(corpus, n, tiers=("realtime", "standard", "batch"),
     return out
 
 
-# -- DecodeStream: join-at-step, bit-parity, fixed shapes ---------------------
+# -- PagedDecodeStream: join-at-step, bit-parity, fixed shapes ----------------
 
 @pytest.mark.parametrize("arch", ["ptb-small-lstm", "smollm-360m"])
-def test_stream_join_mid_decode_matches_solo_generate(arch):
+def test_stream_join_mid_decode_matches_solo_generate(arch, page_pool):
     """Requests joining a RUNNING stream — at different ticks, with
     different prompt lengths — decode bit-identically to solo generate.
     Covers both the position-free LSTM state cache and the transformer
-    KV cache through the vector-pos attn_decode branch."""
+    KV cache through the per-row positions of the paged decode step."""
     cfg = get_config(arch).reduced()
     m = build_model(cfg)
     params = m.init(jax.random.key(0), dtype=jnp.float32)
@@ -92,7 +93,7 @@ def test_stream_join_mid_decode_matches_solo_generate(arch):
         prompt=rng.integers(0, cfg.vocab_size, tp).astype(np.int32),
         max_new=n)
     a, b, c = mk(6, 8), mk(9, 5), mk(6, 1)
-    stream = eng.open_stream("exact", width=3)
+    stream = eng.open_paged_stream(page_pool(eng), "exact", width=3)
     stream.join(a, tag="a")
     done = stream.step() + stream.step()        # a is 2 ticks deep
     stream.join(b, tag="b")                     # join-at-step, longer prompt
@@ -108,29 +109,45 @@ def test_stream_join_mid_decode_matches_solo_generate(arch):
         np.testing.assert_array_equal(got[tag], solo)
 
 
-def test_stream_width1_sampled_reproduces_solo_generate(trained):
+@pytest.mark.parametrize("case", ["cold", "prefix-hits"])
+def test_stream_width1_sampled_reproduces_solo_generate(trained, page_pool,
+                                                        case):
     """The documented sampling contract: an isolated width-1 sampled stream
-    advances the same PRNG chain as generate(seed), so its draws match."""
+    advances the same PRNG chain as generate(seed), so its draws match.
+    ``prefix-hits``: requests sharing a two-page prefix, each on a fresh
+    stream over one pool, so the later joins resume from the radix cache's
+    state snapshots — and still draw what generate draws."""
     cfg, m, params, corpus, st = trained
     eng = DecodeEngine(m, params, screen=st.screen, max_len=30)
-    req = ServeRequest(prompt=corpus.sample_batch(1, 6, seed=4)[0],
-                       max_new=5, temperature=0.9, top_p=0.95, seed=11)
-    stream = eng.open_stream("screened", width=1, temperature=0.9,
-                             top_p=0.95, seed=11)
-    stream.join(req, tag=0)
-    done = []
-    while stream.n_active:
-        done += stream.step()
-    solo = eng.generate(req.prompt[None], 5, head="screened",
-                        temperature=0.9, top_p=0.95,
-                        key=jax.random.key(11)).tokens[0]
-    np.testing.assert_array_equal(done[0][2], solo)
+    if case == "cold":
+        head, temperature, pool = "screened", 0.9, page_pool(eng)
+        reqs = [ServeRequest(prompt=corpus.sample_batch(1, 6, seed=4)[0],
+                             max_new=5)]
+    else:
+        head, temperature, pool = "exact", 0.8, PagePool(64, 5)
+        tmpl = corpus.sample_batch(1, 10, seed=13)[0]
+        reqs = [ServeRequest(prompt=np.concatenate([tmpl, sfx]), max_new=4)
+                for sfx in corpus.sample_batch(3, 3, seed=14)]
+    for req in reqs:
+        stream = eng.open_paged_stream(pool, head, width=1,
+                                       temperature=temperature, top_p=0.95,
+                                       seed=11)
+        stream.join(req, tag=0)
+        done = []
+        while stream.n_active:
+            done += stream.step()
+        solo = eng.generate(req.prompt[None], req.max_new, head=head,
+                            temperature=temperature, top_p=0.95,
+                            key=jax.random.key(11)).tokens[0]
+        np.testing.assert_array_equal(done[0][2], solo)
+    if case == "prefix-hits":
+        assert pool.radix.tokens_hit == 2 * 10     # both later joins resumed
 
 
-def test_stream_capacity_and_guards(trained):
+def test_stream_capacity_and_guards(trained, page_pool):
     cfg, m, params, corpus, st = trained
     eng = DecodeEngine(m, params, max_len=16)
-    stream = eng.open_stream("exact", width=2)
+    stream = eng.open_paged_stream(page_pool(eng), "exact", width=2)
     p = corpus.sample_batch(1, 6, seed=1)[0]
     stream.join(ServeRequest(prompt=p, max_new=3))
     stream.join(ServeRequest(prompt=p, max_new=3))
@@ -138,15 +155,32 @@ def test_stream_capacity_and_guards(trained):
     with pytest.raises(RuntimeError):
         stream.join(ServeRequest(prompt=p, max_new=3))
     with pytest.raises(ValueError):          # 6 + 20 > max_len 16
-        eng.open_stream("exact", width=1).join(
+        eng.open_paged_stream(page_pool(eng), "exact", width=1).join(
             ServeRequest(prompt=p, max_new=20))
     with pytest.raises(ValueError):
-        eng.open_stream("exact", width=0)
+        eng.open_paged_stream(page_pool(eng), "exact", width=0)
+
+
+def test_unpageable_family_is_refused(page_pool):
+    """The scheduler always serves over its page pool: on a family the pool
+    cannot page (mamba2's recurrent SSM state) the first request it serves
+    raises the pool's NotImplementedError naming the family — it is never
+    served on a dense cache instead."""
+    cfg = get_config("mamba2-1.3b").reduced()
+    m = build_model(cfg)
+    params = m.init(jax.random.key(0), dtype=jnp.float32)
+    eng = DecodeEngine(m, params, max_len=16)
+    sched = ContinuousScheduler(eng, kv_pool=page_pool(eng))
+    sched.submit(ServeRequest(prompt=np.arange(4, dtype=np.int32),
+                              max_new=2))
+    with pytest.raises(NotImplementedError, match=cfg.family):
+        sched.step()
+    assert sched.results() == [] and sched.stats.tokens == 0
 
 
 # -- ContinuousScheduler: drain parity + compile discipline -------------------
 
-def test_scheduler_drain_matches_serve_batch(trained):
+def test_scheduler_drain_matches_serve_batch(trained, page_pool):
     """The acceptance bar: draining a fixed request set through the
     scheduler yields greedy results bit-identical to one serve_batch call,
     and a second drain adds ZERO step executables (compiled_step_counts)."""
@@ -159,7 +193,8 @@ def test_scheduler_drain_matches_serve_batch(trained):
     reqs = _reqs(corpus, 7)
     ref = eng.serve_batch(reqs, policy=policy)
 
-    sched = ContinuousScheduler(eng, policy=policy, max_slots=3)
+    sched = ContinuousScheduler(eng, policy=policy, max_slots=3,
+                                kv_pool=page_pool(eng))
     out = sched.serve(reqs)
     assert len(out) == len(reqs)
     assert {r.head for r in out} == {"screened", "svd", "exact"}
@@ -170,7 +205,8 @@ def test_scheduler_drain_matches_serve_batch(trained):
         np.testing.assert_array_equal(r.tokens, e.tokens)
 
     counts0 = eng.compiled_step_counts()
-    out2 = ContinuousScheduler(eng, policy=policy, max_slots=3).serve(reqs)
+    out2 = ContinuousScheduler(eng, policy=policy, max_slots=3,
+                               kv_pool=page_pool(eng)).serve(reqs)
     assert eng.compiled_step_counts() == counts0      # zero recompiles
     for r, e in zip(out2, ref):
         np.testing.assert_array_equal(r.tokens, e.tokens)
@@ -179,7 +215,8 @@ def test_scheduler_drain_matches_serve_batch(trained):
 
 
 @pytest.mark.multidevice
-def test_scheduler_drain_parity_with_sharded_head(trained, multidevice):
+def test_scheduler_drain_parity_with_sharded_head(trained, multidevice,
+                                                  page_pool):
     """The multidevice acceptance case: a *-sharded head in the scheduler
     mix — join-at-step over the mesh-aware cached step, bit-identical to
     serve_batch, zero recompiles on the second drain."""
@@ -191,38 +228,46 @@ def test_scheduler_drain_parity_with_sharded_head(trained, multidevice):
                          "batch": "exact"}, default="exact")
     reqs = _reqs(corpus, 6)
     ref = eng.serve_batch(reqs, policy=policy)
-    out = ContinuousScheduler(eng, policy=policy, max_slots=2).serve(reqs)
+    out = ContinuousScheduler(eng, policy=policy, max_slots=2,
+                              kv_pool=page_pool(eng)).serve(reqs)
     assert {r.head for r in out} == {"screened", "screened-sharded", "exact"}
     assert eng.resolve_head("screened-sharded").n_shards == 8
     for r, e in zip(out, ref):
         assert r.head == e.head
         np.testing.assert_array_equal(r.tokens, e.tokens)
     counts0 = eng.compiled_step_counts()
-    out2 = ContinuousScheduler(eng, policy=policy, max_slots=2).serve(reqs)
+    out2 = ContinuousScheduler(eng, policy=policy, max_slots=2,
+                               kv_pool=page_pool(eng)).serve(reqs)
     assert eng.compiled_step_counts() == counts0
     for r, e in zip(out2, ref):
         np.testing.assert_array_equal(r.tokens, e.tokens)
 
 
-def test_compiled_step_counts_under_mixed_scheduler_traffic(trained):
+def test_compiled_step_counts_under_mixed_scheduler_traffic(trained,
+                                                            page_pool):
     """The telemetry satellite: mixed greedy + sampled scheduler traffic
     across heads surfaces exactly one (head, kind) entry per combination
-    in compiled_step_counts, and repeat drains leave every count flat."""
+    in compiled_step_counts — each head's paged LSTM join executable is a
+    cached step of kind "join" beside its decode steps — and repeat drains
+    leave every count flat."""
     cfg, m, params, corpus, st = trained
     eng = DecodeEngine(m, params, screen=st.screen, max_len=30)
     policy = TierPolicy({"realtime": "screened"}, default="exact")
     reqs = _reqs(corpus, 6, tiers=("realtime", "standard"),
                  sampled_idx=(5,))
-    ContinuousScheduler(eng, policy=policy, max_slots=2).serve(reqs)
+    ContinuousScheduler(eng, policy=policy, max_slots=2,
+                        kv_pool=page_pool(eng)).serve(reqs)
     counts = eng.compiled_step_counts()
     assert set(counts) == {("screened", "greedy"), ("exact", "greedy"),
-                           ("exact", "sample")}
+                           ("exact", "sample"), ("screened", "join"),
+                           ("exact", "join")}
     assert all(n >= 1 for n in counts.values())
-    ContinuousScheduler(eng, policy=policy, max_slots=2).serve(reqs)
+    ContinuousScheduler(eng, policy=policy, max_slots=2,
+                        kv_pool=page_pool(eng)).serve(reqs)
     assert eng.compiled_step_counts() == counts
 
 
-def test_scheduler_interleaves_mixed_prompt_lengths(trained):
+def test_scheduler_interleaves_mixed_prompt_lengths(trained, page_pool):
     """Streams prefill per request, so one lane serves mixed prompt
     lengths — which serve_batch would split into separate groups."""
     cfg, m, params, corpus, st = trained
@@ -230,7 +275,8 @@ def test_scheduler_interleaves_mixed_prompt_lengths(trained):
     long = corpus.sample_batch(2, 9, seed=5)
     short = corpus.sample_batch(2, 5, seed=6)
     reqs = [ServeRequest(prompt=p, max_new=4) for p in (*long, *short)]
-    out = ContinuousScheduler(eng, max_slots=4).serve(reqs)
+    out = ContinuousScheduler(eng, max_slots=4,
+                              kv_pool=page_pool(eng)).serve(reqs)
     assert all(r.group_size == 4 for r in out)
     for r in out:
         solo = eng.generate(r.request.prompt[None], 4).tokens[0]
@@ -239,7 +285,7 @@ def test_scheduler_interleaves_mixed_prompt_lengths(trained):
 
 # -- admission control --------------------------------------------------------
 
-def test_budget_admission_rejects_over_budget_typed(trained):
+def test_budget_admission_rejects_over_budget_typed(trained, page_pool):
     """Traffic past the flops budget: over-budget submissions come back as
     typed AdmissionRejected with the budget in the reason, while admitted
     traffic completes within its tier deadline."""
@@ -250,7 +296,7 @@ def test_budget_admission_rejects_over_budget_typed(trained):
     sched = ContinuousScheduler(
         eng, policy=StaticPolicy("exact"),
         admission=BudgetAdmission(flops_budget=2.5 * flops),
-        max_slots=4, clock=clk)
+        max_slots=4, clock=clk, kv_pool=page_pool(eng))
     reqs = [ServeRequest(prompt=p, max_new=3, latency_tier="standard")
             for p in corpus.sample_batch(5, 6, seed=8)]
     out = sched.serve(reqs)
@@ -327,7 +373,7 @@ def test_budget_admission_never_admits_nan_cost_heads():
     assert (d.action, d.head) == ("accept", "nan-head")
 
 
-def test_budget_admission_downgrade_end_to_end(trained):
+def test_budget_admission_downgrade_end_to_end(trained, page_pool):
     """Integration: the policy routes everything to exact but lists
     screened as a candidate; a budget sized for one exact + change
     reroutes the overflow onto the (much cheaper) screened head, and the
@@ -342,7 +388,7 @@ def test_budget_admission_downgrade_end_to_end(trained):
         eng, policy=TierPolicy({"never": "screened"}, default="exact"),
         admission=BudgetAdmission(
             flops_budget=1.5 * cat["exact"]["flops_per_query"]),
-        max_slots=4)
+        max_slots=4, kv_pool=page_pool(eng))
     reqs = [ServeRequest(prompt=p, max_new=3)
             for p in corpus.sample_batch(3, 6, seed=12)]
     out = sched.serve(reqs)
@@ -371,7 +417,8 @@ def test_memory_budget_excludes_heads_from_admission():
 
 # -- preemption ---------------------------------------------------------------
 
-def test_preempts_over_deadline_low_tier_for_waiting_realtime(trained):
+def test_preempts_over_deadline_low_tier_for_waiting_realtime(trained,
+                                                              page_pool):
     """Two batch-tier hogs fill the only stream; once their deadline lapses
     and a realtime request is starving, exactly ONE hog is preempted (typed
     result, partial tokens) and the realtime request completes."""
@@ -381,7 +428,7 @@ def test_preempts_over_deadline_low_tier_for_waiting_realtime(trained):
     sched = ContinuousScheduler(
         eng, policy=StaticPolicy("exact"), max_slots=2, max_streams=1,
         deadlines={"batch": 0.5, "realtime": 10.0, "standard": 1.0},
-        clock=clk)
+        clock=clk, kv_pool=page_pool(eng))
     prompts = corpus.sample_batch(3, 6, seed=2)
     sched.submit(ServeRequest(prompt=prompts[0], max_new=20,
                               latency_tier="batch"))
@@ -405,7 +452,7 @@ def test_preempts_over_deadline_low_tier_for_waiting_realtime(trained):
     np.testing.assert_array_equal(pre.tokens, solo[:len(pre.tokens)])
 
 
-def test_preempts_deadline_less_batch_tier_by_default(trained):
+def test_preempts_deadline_less_batch_tier_by_default(trained, page_pool):
     """Default TIER_DEADLINES: "batch" work has NO deadline — that means
     best-effort, not immune. A starving realtime request displaces it
     without any clock advance."""
@@ -413,7 +460,7 @@ def test_preempts_deadline_less_batch_tier_by_default(trained):
     eng = DecodeEngine(m, params, max_len=40)
     sched = ContinuousScheduler(eng, policy=StaticPolicy("exact"),
                                 max_slots=2, max_streams=1,
-                                clock=FakeClock())
+                                clock=FakeClock(), kv_pool=page_pool(eng))
     prompts = corpus.sample_batch(3, 6, seed=9)
     sched.submit(ServeRequest(prompt=prompts[0], max_new=20,
                               latency_tier="batch"))
@@ -428,7 +475,7 @@ def test_preempts_deadline_less_batch_tier_by_default(trained):
     assert len(out[1].tokens) == 20 and len(out[2].tokens) == 3
 
 
-def test_no_useless_preemption_on_signature_mismatch(trained):
+def test_no_useless_preemption_on_signature_mismatch(trained, page_pool):
     """Eviction must HELP the waiter: a sampled request that can never join
     the greedy stream (and whose eviction would not idle the lane — a
     non-preemptable realtime job shares it) must not cost the over-deadline
@@ -439,7 +486,7 @@ def test_no_useless_preemption_on_signature_mismatch(trained):
     sched = ContinuousScheduler(
         eng, policy=StaticPolicy("exact"), max_slots=2, max_streams=1,
         deadlines={"standard": 0.5, "realtime": 100.0, "batch": 100.0},
-        clock=clk)
+        clock=clk, kv_pool=page_pool(eng))
     prompts = corpus.sample_batch(3, 6, seed=14)
     sched.submit(ServeRequest(prompt=prompts[0], max_new=12,
                               latency_tier="standard"))
@@ -456,7 +503,7 @@ def test_no_useless_preemption_on_signature_mismatch(trained):
     assert [len(r.tokens) for r in out] == [12, 12, 2]
 
 
-def test_preemption_fires_despite_unrelated_placements(trained):
+def test_preemption_fires_despite_unrelated_placements(trained, page_pool):
     """Per-waiter gating: a placement in some OTHER lane the same tick must
     not suppress preemption for a request starving on a full lane."""
     cfg, m, params, corpus, st = trained
@@ -465,7 +512,7 @@ def test_preemption_fires_despite_unrelated_placements(trained):
     sched = ContinuousScheduler(
         eng, max_slots=1, max_streams=4,
         deadlines={"standard": 0.5, "realtime": 100.0, "batch": 100.0},
-        clock=clk)
+        clock=clk, kv_pool=page_pool(eng))
     prompts = corpus.sample_batch(3, 6, seed=15)
     # hog fills the engine-default (exact) greedy lane
     sched.submit(ServeRequest(prompt=prompts[0], max_new=15,
@@ -485,7 +532,7 @@ def test_preemption_fires_despite_unrelated_placements(trained):
     assert len(out[2].tokens) == 2
 
 
-def test_preemption_freed_slot_goes_to_the_starving_waiter(trained):
+def test_preemption_freed_slot_goes_to_the_starving_waiter(trained, page_pool):
     """No cascade: with [batchA, batchB, realtime] queued on one width-1
     lane, exactly ONE batch request is preempted — the freed slot goes to
     the realtime waiter (priority placement), not FIFO to batchB for
@@ -494,7 +541,7 @@ def test_preemption_freed_slot_goes_to_the_starving_waiter(trained):
     eng = DecodeEngine(m, params, max_len=40)
     sched = ContinuousScheduler(eng, policy=StaticPolicy("exact"),
                                 max_slots=1, max_streams=1,
-                                clock=FakeClock())
+                                clock=FakeClock(), kv_pool=page_pool(eng))
     prompts = corpus.sample_batch(3, 6, seed=16)
     sched.submit(ServeRequest(prompt=prompts[0], max_new=20,
                               latency_tier="batch"))
@@ -510,7 +557,8 @@ def test_preemption_freed_slot_goes_to_the_starving_waiter(trained):
     assert isinstance(out[2], ServeResult) and len(out[2].tokens) == 3
 
 
-def test_admission_downgrade_is_submission_order_independent(trained):
+def test_admission_downgrade_is_submission_order_independent(trained,
+                                                             page_pool):
     """The downgrade universe is the policy's full candidate list, loaded
     before the FIRST admission — an explicit-head request submitted first
     must reach the same decision as one submitted after routed traffic."""
@@ -525,13 +573,13 @@ def test_admission_downgrade_is_submission_order_independent(trained):
         eng, policy=policy,
         admission=BudgetAdmission(
             flops_budget=0.5 * cat["exact"]["flops_per_query"]),
-        max_slots=2)
+        max_slots=2, kv_pool=page_pool(eng))
     out = sched.serve([ServeRequest(prompt=p, max_new=2, head="exact")])
     assert isinstance(out[0], ServeResult) and out[0].head == "screened"
     assert sched.stats.downgraded == 1
 
 
-def test_preemption_picks_lowest_tier_victim_first(trained):
+def test_preemption_picks_lowest_tier_victim_first(trained, page_pool):
     """In one full lane holding an over-deadline standard request AND a
     deadline-less batch request, the batch work (no completion promise)
     yields — the merely-late standard request keeps its decode."""
@@ -541,7 +589,7 @@ def test_preemption_picks_lowest_tier_victim_first(trained):
     sched = ContinuousScheduler(
         eng, policy=StaticPolicy("exact"), max_slots=2, max_streams=1,
         deadlines={"standard": 0.5, "realtime": 100.0,
-                   "batch": math.inf}, clock=clk)
+                   "batch": math.inf}, clock=clk, kv_pool=page_pool(eng))
     prompts = corpus.sample_batch(3, 6, seed=18)
     sched.submit(ServeRequest(prompt=prompts[0], max_new=15,
                               latency_tier="standard"))
@@ -558,13 +606,13 @@ def test_preemption_picks_lowest_tier_victim_first(trained):
     assert len(out[2].tokens) == 3
 
 
-def test_one_eviction_per_signature_per_tick(trained):
+def test_one_eviction_per_signature_per_tick(trained, page_pool):
     """Two same-signature waiters needing a new lane trigger ONE eviction —
     the recycled lane serves both; the second lane's occupant survives."""
     cfg, m, params, corpus, st = trained
     eng = DecodeEngine(m, params, screen=st.screen, max_len=40)
     sched = ContinuousScheduler(eng, max_slots=1, max_streams=2,
-                                clock=FakeClock())
+                                clock=FakeClock(), kv_pool=page_pool(eng))
     prompts = corpus.sample_batch(4, 6, seed=19)
     # two lanes, each a width-1 batch hog on a distinct signature
     sched.submit(ServeRequest(prompt=prompts[0], max_new=15,
@@ -583,10 +631,10 @@ def test_one_eviction_per_signature_per_tick(trained):
     assert len(done) == 3                     # surviving hog + both sampled
 
 
-def test_pop_results_consumes_and_ids_stay_monotonic(trained):
+def test_pop_results_consumes_and_ids_stay_monotonic(trained, page_pool):
     cfg, m, params, corpus, st = trained
     eng = DecodeEngine(m, params, max_len=30)
-    sched = ContinuousScheduler(eng, max_slots=2)
+    sched = ContinuousScheduler(eng, max_slots=2, kv_pool=page_pool(eng))
     prompts = corpus.sample_batch(4, 6, seed=20)
     sched.serve([ServeRequest(prompt=p, max_new=2) for p in prompts[:2]])
     first = sched.pop_results()
@@ -622,10 +670,10 @@ def test_request_queue_stamps_arrival_and_tier_deadline():
     assert len(q) == 1 and q.flops_pending == 3.0
 
 
-def test_scheduler_rejects_oversized_request_at_submit(trained):
+def test_scheduler_rejects_oversized_request_at_submit(trained, page_pool):
     cfg, m, params, corpus, st = trained
     eng = DecodeEngine(m, params, max_len=10)
-    sched = ContinuousScheduler(eng)
+    sched = ContinuousScheduler(eng, kv_pool=page_pool(eng))
     with pytest.raises(ValueError):
         sched.submit(ServeRequest(prompt=corpus.sample_batch(1, 6, seed=1)[0],
                                   max_new=20))

@@ -339,7 +339,7 @@ def test_dist_logits_matches_sampling_support(trained):
 
 # -- SpecDecodeStream ---------------------------------------------------------
 
-def test_spec_stream_greedy_parity_lstm(trained):
+def test_spec_stream_greedy_parity_lstm(trained, page_pool):
     """Tentpole acceptance: greedy spec tokens are BIT-identical to solo
     exact-head generate on the LSTM (snapshot-restore rollback), with zero
     new step executables once warm."""
@@ -349,7 +349,8 @@ def test_spec_stream_greedy_parity_lstm(trained):
     reqs = [ServeRequest(prompt=p, max_new=10) for p in prompts]
     base = eng.generate(prompts, 10, head="exact")
 
-    s1 = eng.open_spec_stream("screened", "exact", width=4, draft_len=4)
+    s1 = eng.open_spec_stream("screened", "exact", width=4, draft_len=4,
+                              kv_pool=page_pool(eng))
     done = _run_stream(s1, reqs)
     for i in range(3):
         np.testing.assert_array_equal(done[i], base.tokens[i])
@@ -360,20 +361,23 @@ def test_spec_stream_greedy_parity_lstm(trained):
     warm = eng.compiled_step_counts()
 
     # a second stream of the same shape adds ZERO executables
-    s2 = eng.open_spec_stream("screened", "exact", width=4, draft_len=4)
+    s2 = eng.open_spec_stream("screened", "exact", width=4, draft_len=4,
+                              kv_pool=page_pool(eng))
     done = _run_stream(s2, [ServeRequest(prompt=p, max_new=8)
                             for p in corpus.sample_batch(2, 6, seed=9)])
     assert eng.compiled_step_counts() == warm
 
 
-def test_spec_stream_transformer_rollback_parity(transformer_engine):
+def test_spec_stream_transformer_rollback_parity(transformer_engine,
+                                                 page_pool):
     """Attention-family rollback is pure position masking — parity must
     hold under HEAVY rejection (untrained model, junk screen)."""
     cfg, eng = transformer_engine
     rng = np.random.default_rng(5)
     prompts = rng.integers(0, cfg.vocab_size, (3, 6)).astype(np.int32)
     base = eng.generate(prompts, 10, head="exact")
-    st = eng.open_spec_stream("screened", "exact", width=4, draft_len=3)
+    st = eng.open_spec_stream("screened", "exact", width=4, draft_len=3,
+                              kv_pool=page_pool(eng))
     done = _run_stream(st, [ServeRequest(prompt=p, max_new=10)
                             for p in prompts])
     for i in range(3):
@@ -382,25 +386,28 @@ def test_spec_stream_transformer_rollback_parity(transformer_engine):
     assert c["accepted"] < c["drafted"]          # rejections really happened
 
 
-def test_spec_stream_adaptive_controller_shrinks(transformer_engine):
+def test_spec_stream_adaptive_controller_shrinks(transformer_engine,
+                                                 page_pool):
     """Junk-screen acceptance collapses → the controller walks the live
     draft length down to 1 without re-tracing (counted via draft_steps)."""
     cfg, eng = transformer_engine
     rng = np.random.default_rng(6)
-    st = eng.open_spec_stream("screened", "exact", width=2, draft_len=4)
+    st = eng.open_spec_stream("screened", "exact", width=2, draft_len=4,
+                              kv_pool=page_pool(eng))
     _run_stream(st, [ServeRequest(prompt=p, max_new=12) for p in
                      rng.integers(0, cfg.vocab_size, (2, 5)).astype(np.int32)])
     assert st.controller is not None and st.controller.n < 4
 
 
-def test_spec_stream_sampled_smoke(trained):
+def test_spec_stream_sampled_smoke(trained, page_pool):
     """Sampled spec: runs to completion, emits in-vocab tokens, and the
     guards reject configurations the rejection rule cannot serve."""
     cfg, m, params, corpus, st = trained
     eng = _engine(trained)
     prompts = corpus.sample_batch(2, 6, seed=11)
     stream = eng.open_spec_stream("screened", "exact", width=2, draft_len=3,
-                                  temperature=0.8, top_p=0.9, seed=3)
+                                  temperature=0.8, top_p=0.9, seed=3,
+                                  kv_pool=page_pool(eng))
     done = _run_stream(stream, [
         ServeRequest(prompt=p, max_new=8, temperature=0.8, top_p=0.9,
                      seed=3) for p in prompts])
@@ -409,15 +416,16 @@ def test_spec_stream_sampled_smoke(trained):
         assert 0 <= done[i].min() and done[i].max() < cfg.vocab_size
     # guard: draft == verify
     with pytest.raises(ValueError, match="DISTINCT"):
-        eng.open_spec_stream("exact", "exact")
+        eng.open_spec_stream("exact", "exact", kv_pool=page_pool(eng))
     # guard: sampled needs dist_logits on both heads (svd has none)
     svd = heads_registry.get("svd", W=eng.W, b=eng.b, screen=None,
                              rho=cfg.d_model, n_top=cfg.vocab_size)
     with pytest.raises(ValueError, match="dist_logits"):
-        eng.open_spec_stream(svd, "exact", temperature=0.8)
+        eng.open_spec_stream(svd, "exact", temperature=0.8,
+                             kv_pool=page_pool(eng))
 
 
-def test_spec_stream_join_headroom_and_width():
+def test_spec_stream_join_headroom_and_width(page_pool):
     cfg = get_config("ptb-small-lstm").reduced()
     m = build_model(cfg)
     params = m.init(jax.random.key(0), dtype=jnp.float32)
@@ -428,13 +436,15 @@ def test_spec_stream_join_headroom_and_width():
                  L2SConfig(num_clusters=4, budget=32, outer_iters=1,
                            sgd_steps=10))
     eng = DecodeEngine(m, params, screen=st.screen, max_len=16)
-    stream = eng.open_spec_stream("screened", "exact", width=2, draft_len=4)
+    stream = eng.open_spec_stream("screened", "exact", width=2, draft_len=4,
+                                  kv_pool=page_pool(eng))
     # 8 + 6 + (4-1) = 17 > 16: the draft overshoot must be priced in
     with pytest.raises(ValueError, match="overshoot"):
         stream.join(ServeRequest(prompt=np.zeros(8, np.int32), max_new=6))
     stream.join(ServeRequest(prompt=np.zeros(8, np.int32), max_new=5))
     with pytest.raises(ValueError, match="width"):
-        eng.open_spec_stream("screened", "exact", width=0)
+        eng.open_spec_stream("screened", "exact", width=0,
+                             kv_pool=page_pool(eng))
 
 
 def test_spec_stream_kv_pool_reservations(trained):
@@ -465,7 +475,7 @@ def test_spec_stream_kv_pool_reservations(trained):
 
 # -- scheduler integration ----------------------------------------------------
 
-def test_scheduler_spec_parity_and_stats(trained):
+def test_scheduler_spec_parity_and_stats(trained, page_pool):
     """ContinuousScheduler(spec=...) serves exact-routed traffic on spec
     lanes: results bit-match plain serve_batch, the composite head name is
     reported, and ServerStats grows a populated "spec" section."""
@@ -477,7 +487,8 @@ def test_scheduler_spec_parity_and_stats(trained):
     base = eng.serve_batch(reqs, policy=StaticPolicy("exact"))
     sched = ContinuousScheduler(
         eng, policy=StaticPolicy("exact"),
-        spec=SpecPolicy(drafts=("screened",), draft_len=4))
+        spec=SpecPolicy(drafts=("screened",), draft_len=4),
+        kv_pool=page_pool(eng))
     res = sched.serve(reqs)
     for r, b in zip(res, base):
         assert isinstance(r, ServeResult)
@@ -492,7 +503,7 @@ def test_scheduler_spec_parity_and_stats(trained):
     assert sched.stats.tokens == sum(len(b.tokens) for b in base)
 
 
-def test_scheduler_drops_draft_before_head(trained):
+def test_scheduler_drops_draft_before_head(trained, page_pool):
     """Admission prices the draft's extra flops; when the routed head fits
     only WITHOUT it, the spec assignment is dropped — never the head."""
     cfg, m, params, corpus, st = trained
@@ -502,7 +513,7 @@ def test_scheduler_drops_draft_before_head(trained):
     sched = ContinuousScheduler(
         eng, policy=StaticPolicy("exact"),
         admission=BudgetAdmission(flops_budget=tight),
-        spec=SpecPolicy(drafts=("screened",)))
+        spec=SpecPolicy(drafts=("screened",)), kv_pool=page_pool(eng))
     sched.submit(ServeRequest(prompt=corpus.sample_batch(1, 6, seed=2)[0],
                               max_new=4))
     qr = next(iter(sched.queue))
@@ -513,7 +524,8 @@ def test_scheduler_drops_draft_before_head(trained):
     roomy = ContinuousScheduler(
         eng, policy=StaticPolicy("exact"),
         admission=BudgetAdmission(flops_budget=10 * tight),
-        spec=SpecPolicy(drafts=("screened",), draft_len=4))
+        spec=SpecPolicy(drafts=("screened",), draft_len=4),
+        kv_pool=page_pool(eng))
     roomy.submit(ServeRequest(prompt=corpus.sample_batch(1, 6, seed=2)[0],
                               max_new=4))
     qr = next(iter(roomy.queue))
@@ -522,14 +534,15 @@ def test_scheduler_drops_draft_before_head(trained):
                                                     "exact"))
 
 
-def test_scheduler_spec_lane_signature(trained):
+def test_scheduler_spec_lane_signature(trained, page_pool):
     """Spec and plain requests never share a stream lane: the draft rides
     the stream signature."""
     cfg, m, params, corpus, st = trained
     eng = _engine(trained)
     sched = ContinuousScheduler(
         eng, policy=StaticPolicy("exact"),
-        spec=SpecPolicy(drafts=("screened",), draft_len=4))
+        spec=SpecPolicy(drafts=("screened",), draft_len=4),
+        kv_pool=page_pool(eng))
     p = corpus.sample_batch(2, 6, seed=5)
     sched.submit(ServeRequest(prompt=p[0], max_new=4))
     # draft_len=1 → draft_len_for < 2 → plain lane for this request
@@ -545,7 +558,8 @@ def test_scheduler_spec_lane_signature(trained):
 
 @pytest.mark.multidevice
 @pytest.mark.parametrize("n_shards", [2, 8])
-def test_sharded_verify_greedy_parity(trained, multidevice, n_shards):
+def test_sharded_verify_greedy_parity(trained, multidevice, n_shards,
+                                      page_pool):
     """Greedy spec with an exact-SHARDED verify head: one mesh-aware
     batched verify executable, tokens bit-identical to unsharded exact."""
     cfg, m, params, corpus, st = trained
@@ -554,7 +568,8 @@ def test_sharded_verify_greedy_parity(trained, multidevice, n_shards):
     base = eng.generate(prompts, 8, head="exact")
     sharded = heads_registry.get("exact-sharded", W=eng.W, b=eng.b,
                                  n_shards=n_shards)
-    stream = eng.open_spec_stream("screened", sharded, width=4, draft_len=4)
+    stream = eng.open_spec_stream("screened", sharded, width=4, draft_len=4,
+                                  kv_pool=page_pool(eng))
     done = _run_stream(stream, [ServeRequest(prompt=p, max_new=8)
                                 for p in prompts])
     for i in range(3):
@@ -564,13 +579,14 @@ def test_sharded_verify_greedy_parity(trained, multidevice, n_shards):
 
 
 @pytest.mark.multidevice
-def test_sharded_verify_refuses_sampled(trained, multidevice):
+def test_sharded_verify_refuses_sampled(trained, multidevice, page_pool):
     cfg, m, params, corpus, st = trained
     eng = _engine(trained)
     sharded = heads_registry.get("exact-sharded", W=eng.W, b=eng.b,
                                  n_shards=2)
     with pytest.raises(ValueError, match="unsharded"):
-        eng.open_spec_stream("screened", sharded, temperature=0.8)
+        eng.open_spec_stream("screened", sharded, temperature=0.8,
+                             kv_pool=page_pool(eng))
 
 
 # -- launcher fail-fast -------------------------------------------------------
